@@ -47,6 +47,7 @@ import jax
 from repro.core.campaign import (CampaignSpec, Constraint, certify_front,
                                  run_campaign)
 from repro.core.sweep import SweepEngine
+from repro.launch.compile_cache import configure_compile_cache
 
 # same grid family as tests/golden/campaign_front.csv: big enough that
 # the chunked run streams >= 2 chunks, small enough for a CI job
@@ -179,6 +180,7 @@ def campaign_speed(write_json: bool = True):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     _, block = campaign_speed()
     print(json.dumps(block, indent=1))
     # CI runs this module directly: a determinism or parity break must

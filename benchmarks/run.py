@@ -15,6 +15,7 @@ from . import llm_planner_bench as L
 from . import sweep_bench as S
 from . import serve_gating_bench as G
 from . import campaign_bench as C
+from repro.launch.compile_cache import configure_compile_cache
 
 BENCHES = [
     ("fig2_gemm_landscape", P.fig2_gemm_landscape),
@@ -32,6 +33,7 @@ BENCHES = [
 
 
 def main() -> None:
+    configure_compile_cache()
     outdir = os.path.join("results", "bench")
     os.makedirs(outdir, exist_ok=True)
     print("name,us_per_call,derived")

@@ -43,6 +43,7 @@ import jax
 
 from repro.configs import ARCHS, RunConfig, reduced
 from repro.core.plan_service import BucketLattice, PlanService
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import init
 from repro.serving import (ContinuousBatchingEngine, DecodeCore,
                            synthetic_requests)
@@ -221,6 +222,7 @@ if __name__ == "__main__":
     ap.add_argument("--requests", type=int, default=N_REQUESTS,
                     help="requests per scenario")
     cli = ap.parse_args()
+    configure_compile_cache()
     adaptive = serve_adaptive(n_requests=cli.requests)
     print(json.dumps(adaptive, indent=1))
     gates = adaptive["gates"]

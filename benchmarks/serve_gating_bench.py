@@ -28,14 +28,13 @@ Like sweep_bench, a run failing any gate is quarantined to
 BENCH_serve.json.failed instead of replacing the trusted trajectory
 entry, and running the module directly (as CI does) then exits nonzero.
 
-Each arch is measured in its **own subprocess** (``--arch ... --emit-row``
-child mode): measuring several archs in one process depresses the
-later-measured ones by 10-45% — XLA:CPU allocator/cache state left by
-the earlier sessions, not anything about the arch — which is enough to
-flip the trend gate on pure measurement artifact.  Fresh-process
-isolation makes every arch's number order-independent.  Set
-SERVE_GATING_INPROC=1 to force the old single-process sweep (or as the
-automatic fallback when spawning fails).
+Every arch is measured in this one process: a chip belongs to one
+process at a time, so a parent that has initialised JAX cannot hand the
+device to per-arch children.  Sessions alternate their timed samples
+(repro.launch.serve.steady_decode_tokens_per_s), so contention hits the
+gated and ungated programs of one arch alike; numbers taken on the CPU
+after another arch are still order-sensitive and are not device
+measurements.
 
 Run directly:  PYTHONPATH=src python -m benchmarks.serve_gating_bench
 (--new-tokens/--repeats/--warmup tune the shared timing helper,
@@ -46,14 +45,14 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHS, RunConfig, reduced
-from repro.launch.serve import steady_decode_tokens_per_s
+from repro.launch.compile_cache import configure_compile_cache
+from repro.launch.serve import PARITY_ATOL, steady_decode_tokens_per_s
 from repro.models import init
 from repro.serving import ServeSession, cim_fraction
 
@@ -69,9 +68,6 @@ BENCH_ARCHS = (("mamba2-780m", 8), ("mistral-nemo-12b", 8),
                ("qwen2-moe-a2.7b", 8))
 PROMPT_LEN = 6
 NEW_TOKENS = 16
-# gated vs ungated differ only by kernel (Pallas f32-accum vs XLA bf16
-# dequant matmul); logits are O(1) scale in the smoke models
-PARITY_ATOL = 0.05
 # gated-not-slower noise band: when the true gated/ungated difference is
 # ~0 (the paper's answer on the attention archs IS "don't CiM at decode",
 # so the programs are near-identical), CPU smoke timing jitters +-1-2%
@@ -82,8 +78,7 @@ GATED_NOT_SLOWER_RTOL = 0.02
 
 def _measure_arch(arch: str, batch: int, new_tokens: int,
                   repeats: int, warmup: int) -> dict:
-    """One arch's gated-vs-ungated measurement (runs in-process; the
-    parent normally invokes it in a fresh subprocess via --emit-row)."""
+    """One arch's gated-vs-ungated measurement."""
     rc = RunConfig(attn_impl="naive", remat=False)
     cfg = reduced(ARCHS[arch])
     params = init(jax.random.PRNGKey(0), cfg)
@@ -120,46 +115,12 @@ def _measure_arch(arch: str, batch: int, new_tokens: int,
         "decode_executables": gated.decode_executables}
 
 
-_ROW_MARK = "GATING_ROW_JSON:"
-
-
-def _measure_arch_isolated(arch: str, batch: int, new_tokens: int,
-                           repeats: int, warmup: int) -> dict:
-    """Measure one arch in a fresh python process so its timing never
-    sees another arch's allocator/cache residue (10-45% depression when
-    measured after other archs in-process).  Falls back to in-process on
-    spawn failure or SERVE_GATING_INPROC=1."""
-    if os.environ.get("SERVE_GATING_INPROC"):
-        return _measure_arch(arch, batch, new_tokens, repeats, warmup)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(root, "src"),
-                    env.get("PYTHONPATH", "")) if p)
-    cmd = [sys.executable, "-m", "benchmarks.serve_gating_bench",
-           "--arch", arch, "--batch", str(batch), "--emit-row",
-           "--new-tokens", str(new_tokens), "--repeats", str(repeats),
-           "--warmup", str(warmup)]
-    try:
-        proc = subprocess.run(cmd, cwd=root, env=env, text=True,
-                              capture_output=True, timeout=1800)
-        for line in proc.stdout.splitlines():
-            if line.startswith(_ROW_MARK):
-                return json.loads(line[len(_ROW_MARK):])
-        raise RuntimeError(proc.stderr[-500:] or "no row emitted")
-    except Exception as e:                        # noqa: BLE001
-        print(f"serve_gating_bench: subprocess measurement of {arch} "
-              f"failed ({e}); measuring in-process", file=sys.stderr)
-        return _measure_arch(arch, batch, new_tokens, repeats, warmup)
-
-
 def serve_gating_speed(write_json: bool = True, new_tokens: int = NEW_TOKENS,
                        repeats: int = 3, warmup: int = 0):
     rows, per_arch = [], {}
     all_parity_ok = True
     for arch, batch in BENCH_ARCHS:
-        entry = _measure_arch_isolated(arch, batch, new_tokens,
-                                       repeats, warmup)
+        entry = _measure_arch(arch, batch, new_tokens, repeats, warmup)
         all_parity_ok &= entry["parity_ok"]
         rows.append({k: entry[k] for k in
                      ("arch", "batch", "tokens_per_s_gated",
@@ -232,19 +193,8 @@ if __name__ == "__main__":
                     help="timed samples per session (best is kept)")
     ap.add_argument("--warmup", type=int, default=0,
                     help="untimed decode steps per session after prefill")
-    ap.add_argument("--arch", default=None,
-                    help="child mode: measure just this arch")
-    ap.add_argument("--batch", type=int, default=8,
-                    help="child mode: decode batch for --arch")
-    ap.add_argument("--emit-row", action="store_true",
-                    help="child mode: print the arch row as JSON and exit")
     cli = ap.parse_args()
-    if cli.emit_row:
-        # fresh-process measurement child spawned by serve_gating_speed
-        entry = _measure_arch(cli.arch, cli.batch, cli.new_tokens,
-                              cli.repeats, cli.warmup)
-        print(_ROW_MARK + json.dumps(entry))
-        sys.exit(0)
+    configure_compile_cache()
     _, derived = serve_gating_speed(new_tokens=cli.new_tokens,
                                     repeats=cli.repeats, warmup=cli.warmup)
     print(json.dumps(derived, indent=1))

@@ -15,7 +15,7 @@ Three gate families protect the numbers:
     continuous engine must produce exactly the tokens the legacy
     fixed-batch `ServeSession(batch=1)` produces for it alone, and the
     first-token logits must match within kernel-numerics tolerance
-    (PARITY_ATOL shared with serve_gating_bench).  mamba2-780m is the
+    (PARITY_ATOL shared with serve_gating_bench via launch.serve).  mamba2-780m is the
     mixed-verdict gated case; mistral-nemo-12b exercises the paged KV
     path across block boundaries.
   * **no-retrace** — after all traffic at all rates,
@@ -46,13 +46,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, RunConfig, reduced
-from repro.launch.serve import steady_decode_tokens_per_s
+from repro.launch.compile_cache import configure_compile_cache
+from repro.launch.serve import PARITY_ATOL, steady_decode_tokens_per_s
 from repro.models import init
 from repro.serving import (ContinuousBatchingEngine, DecodeCore,
                            ServeSession, poisson_arrivals,
                            synthetic_requests)
 
-from .serve_gating_bench import PARITY_ATOL
 from .sweep_bench import _provenance
 from .trend import (committed_baseline, emit_job_summary, render_markdown,
                     trend_report)
@@ -132,8 +132,7 @@ def serve_traffic(write_json: bool = True, rates=RATES,
     # lockstep session at batch=N_SLOTS on the same weights, timed by
     # the shared helper (warmed, best-of).  Measured after the engine
     # curves it inherits their allocator/cache drag and reads up to 35%
-    # low — the same in-process interference the gating bench dodges
-    # with per-arch subprocesses.
+    # low (CPU runs; in-process allocator/cache interference).
     ref_sess = ServeSession(cfg, rc, params, max_len=max_len,
                             batch=N_SLOTS, quantize=True)
     ref_prompt = jax.random.randint(jax.random.PRNGKey(1),
@@ -254,6 +253,7 @@ if __name__ == "__main__":
     ap.add_argument("--rates", type=float, nargs="+", default=list(RATES),
                     help="open-loop Poisson arrival rates (req/s)")
     cli = ap.parse_args()
+    configure_compile_cache()
     traffic = serve_traffic(rates=tuple(cli.rates),
                             n_requests=cli.requests)
     print(json.dumps(traffic, indent=1))

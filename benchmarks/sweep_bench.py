@@ -34,8 +34,9 @@ parity.  The headline numbers:
                     kernel compiles natively (mode == "compiled"); in CPU
                     interpret mode (CI) the timing is recorded for the
                     trajectory but slower-than-XLA is expected and not an
-                    error.  Platforms without any Pallas lowering record
-                    the fallback reason instead,
+                    error.  A platform that cannot compile the kernel
+                    fails the run (kernels.sweep_eval.pallas_status
+                    raises),
   * precision     — the full workload re-planned at INT4 and FP8 (the
                     widened What axis), vectorized timing plus a
                     pallas-vs-vectorized verdict-parity gate per
@@ -75,24 +76,16 @@ from datetime import datetime, timezone
 import jax
 import numpy as np
 
-from repro.configs import ARCHS, SHAPES
 from repro.core import GEMM
-from repro.core.llm_workloads import gemms_of_model
+from repro.core.llm_workloads import llm_gemm_set
 from repro.core.planner import plan_workload, standard_configs
 from repro.core.sweep import (SweepEngine, cache_clear, cache_info,
                               jit_cache_clear, plan_workload_batched)
 from repro.core.vectorized import (MAP_FIELDS, config_row, enumerate_space,
                                    evaluate_flat, precision_row)
 from repro.kernels.sweep_eval import pallas_status, sweep_eval
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import row_mesh
-
-
-def full_llm_gemm_set():
-    gemms = []
-    for mc in ARCHS.values():
-        for sname in ("train_4k", "decode_32k"):
-            gemms += gemms_of_model(mc, SHAPES[sname])
-    return gemms
 
 
 def _provenance() -> dict:
@@ -143,7 +136,7 @@ def _large_flat_batch(n_rows: int = LARGE_BATCH_ROWS):
 
 
 def planner_sweep_speed(write_json: bool = True, repeats: int = 3):
-    gemms = full_llm_gemm_set()
+    gemms = llm_gemm_set()
 
     # honest cold-jit: drop both the compiled kernels and the result
     # cache, so "cold" is cold even when earlier benches in this process
@@ -233,45 +226,37 @@ def planner_sweep_speed(write_json: bool = True, repeats: int = 3):
         a.use_cim != b.use_cim or a.best_energy != b.best_energy
         for a, b in zip(pallas_plan, batched))
 
-    if status["mode"] == "unavailable":
-        # the planner path above already fell back to the XLA kernel; a
-        # direct jit(sweep_eval) here would re-raise the lowering error
-        # the probe caught — record the reason instead of crashing
-        large_batch_block = {"skipped": status["reason"]}
-        pallas_sanity_ok = True
-        large_rows = []
-    else:
-        big_batch, big_rows = _large_flat_batch()
-        xla_fn = jax.jit(evaluate_flat)
-        pallas_fn = jax.jit(sweep_eval)
-        for fn in (xla_fn, pallas_fn):              # warm the executables
-            jax.block_until_ready(fn(big_batch)["energy_pj"])
-        xla_large_s, _ = _best_of(
-            repeats, lambda: jax.block_until_ready(
-                xla_fn(big_batch)["energy_pj"]))
-        pallas_large_s, _ = _best_of(
-            repeats, lambda: jax.block_until_ready(
-                pallas_fn(big_batch)["energy_pj"]))
-        # slower-than-XLA is only an error where the kernel compiles
-        # natively; interpret mode (CPU CI) records the ratio w/o gating
-        pallas_sanity_ok = (status["mode"] != "compiled"
-                            or pallas_large_s <= xla_large_s)
-        if not pallas_sanity_ok:
-            print(f"WARNING: compiled pallas sweep kernel slower than XLA "
-                  f"fusion at {big_rows} rows ({pallas_large_s:.4f}s vs "
-                  f"{xla_large_s:.4f}s) — hand-written kernel regression",
-                  file=sys.stderr)
-        large_batch_block = {
-            "rows": big_rows,
-            "xla_s": round(xla_large_s, 4),
-            "pallas_s": round(pallas_large_s, 4),
-            "pallas_speedup_x": round(xla_large_s / pallas_large_s, 2),
-        }
-        large_rows = [
-            {"backend": f"xla_large_batch_{big_rows}rows",
-             "seconds": round(xla_large_s, 4)},
-            {"backend": f"pallas_large_batch_{big_rows}rows",
-             "seconds": round(pallas_large_s, 4)}]
+    big_batch, big_rows = _large_flat_batch()
+    xla_fn = jax.jit(evaluate_flat)
+    pallas_fn = jax.jit(sweep_eval)
+    for fn in (xla_fn, pallas_fn):              # warm the executables
+        jax.block_until_ready(fn(big_batch)["energy_pj"])
+    xla_large_s, _ = _best_of(
+        repeats, lambda: jax.block_until_ready(
+            xla_fn(big_batch)["energy_pj"]))
+    pallas_large_s, _ = _best_of(
+        repeats, lambda: jax.block_until_ready(
+            pallas_fn(big_batch)["energy_pj"]))
+    # slower-than-XLA is only an error where the kernel compiles
+    # natively; interpret mode (CPU CI) records the ratio w/o gating
+    pallas_sanity_ok = (status["mode"] != "compiled"
+                        or pallas_large_s <= xla_large_s)
+    if not pallas_sanity_ok:
+        print(f"WARNING: compiled pallas sweep kernel slower than XLA "
+              f"fusion at {big_rows} rows ({pallas_large_s:.4f}s vs "
+              f"{xla_large_s:.4f}s) — hand-written kernel regression",
+              file=sys.stderr)
+    large_batch_block = {
+        "rows": big_rows,
+        "xla_s": round(xla_large_s, 4),
+        "pallas_s": round(pallas_large_s, 4),
+        "pallas_speedup_x": round(xla_large_s / pallas_large_s, 2),
+    }
+    large_rows = [
+        {"backend": f"xla_large_batch_{big_rows}rows",
+         "seconds": round(xla_large_s, 4)},
+        {"backend": f"pallas_large_batch_{big_rows}rows",
+         "seconds": round(pallas_large_s, 4)}]
 
     # --- precision axis: the full workload re-planned at every non-default
     # precision of the widened What axis (INT4 packed weights, FP8
@@ -334,11 +319,6 @@ def planner_sweep_speed(write_json: bool = True, repeats: int = 3):
         },
         "pallas": {
             "mode": status["mode"],
-            # only a real fallback (mode == "unavailable") is a fallback;
-            # interpret mode still runs the kernel on every query
-            "fallback_reason": (status["reason"]
-                                if status["mode"] == "unavailable"
-                                else None),
             "plan_s": round(pallas_s, 3),
             "verdict_mismatches": pallas_mismatches,
             "large_batch": large_batch_block,
@@ -392,6 +372,7 @@ def planner_sweep_speed(write_json: bool = True, repeats: int = 3):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     _, derived = planner_sweep_speed()
     print(json.dumps(derived, indent=1))
     # CI runs this module directly: a parity regression or a mismeasured

@@ -122,6 +122,16 @@ def gemms_of_model(cfg: ModelConfig, shape: ShapeConfig) -> list[GEMM]:
     return out
 
 
+def llm_gemm_set() -> list[GEMM]:
+    """The planner's full LLM workload: every registered arch at
+    train_4k and decode_32k (223 GEMMs) — the set the planner benchmark
+    and the chip smoke plan end to end."""
+    from ..configs import ARCHS, SHAPES
+    return [g for cfg in ARCHS.values()
+            for shape in ("train_4k", "decode_32k")
+            for g in gemms_of_model(cfg, SHAPES[shape])]
+
+
 def phase_gemms_of_model(cfg: ModelConfig, seq_len: int,
                          batch: int) -> dict[str, list[GEMM]]:
     """The serving phases of one model as separate GEMM sets.

@@ -28,10 +28,10 @@ Two CiM row kernels score those batches: the default XLA-fused path
 (vectorized.evaluate_flat) and backend="pallas", a fused hand-written
 kernel (repro.kernels.sweep_eval) consuming the same backend-shared cost
 spec.  Pallas results live in their own result-cache keyspace, so parity
-suites exercise the kernel rather than the LRU; on platforms whose
-Pallas lowering is unavailable the engine transparently falls back to
-the XLA kernel and records the reason in `cache_info()["pallas_fallback"]`
-(which also carries a per-backend hit/miss breakdown).
+suites exercise the kernel rather than the LRU (`cache_info()` carries
+a per-backend hit/miss breakdown).  An accelerator that cannot compile
+the Pallas kernel raises (kernels.sweep_eval.pallas_status); it is
+never silently replaced by the XLA kernel.
 
 Multi-device and multi-host scaling: an engine given a 1-D row mesh
 (launch.mesh.row_mesh) shards every flattened row batch across the mesh
@@ -125,16 +125,15 @@ def _jit_kernel(kind: str, order_mode: str = "exact", mesh=None,
             else:
                 base = evaluate_baseline_flat
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec
                 axis = mesh.axis_names[0]
-                # pallas_call has no shard_map replication rule; rows are
+                # pallas_call has no varying-manual-axes rule; rows are
                 # a pure data split (no cross-shard collectives), so
-                # skipping the replication check is sound
-                base = shard_map(base, mesh=mesh,
-                                 in_specs=(PartitionSpec(axis),),
-                                 out_specs=PartitionSpec(axis),
-                                 check_rep=(kernel != "pallas"))
+                # skipping the check is sound
+                base = jax.shard_map(base, mesh=mesh,
+                                     in_specs=(PartitionSpec(axis),),
+                                     out_specs=PartitionSpec(axis),
+                                     check_vma=(kernel != "pallas"))
             fn = jax.jit(base)
             _KERNELS[key] = fn
     return fn
@@ -296,10 +295,8 @@ class SweepEngine:
         self.hits = 0
         self.misses = 0
         # per-backend keyspace breakdown ("vectorized" / "pallas" /
-        # "baseline") + the recorded reason if a pallas request ever fell
-        # back to the XLA kernel on this engine
+        # "baseline")
         self._backend_counts: dict = {}
-        self._pallas_fallback: str | None = None
         # streaming-enumerator accounting (cache_info()["chunks"])
         self._chunks_evaluated = 0
         self._rows_evaluated = 0
@@ -351,9 +348,7 @@ class SweepEngine:
     def cache_info(self) -> dict:
         """Size + hit/miss totals, the per-backend breakdown (which
         keyspace — vectorized / pallas / baseline — each lookup resolved
-        to), `pallas_fallback` (None normally, the recorded lowering
-        error if a backend="pallas" request ever fell back to the XLA
-        kernel), the streaming-enumerator accounting under "chunks"
+        to), the streaming-enumerator accounting under "chunks"
         (tiles evaluated / real vs padding rows), and — on a multi-host
         mesh — a "distributed" block with the process topology and the
         cumulative per-process row shard balance.  Serve/dryrun telemetry
@@ -363,7 +358,6 @@ class SweepEngine:
                     "hits": self.hits, "misses": self.misses,
                     "backends": {b: dict(c) for b, c in
                                  self._backend_counts.items()},
-                    "pallas_fallback": self._pallas_fallback,
                     "chunks": {"chunk_rows": self.chunk_rows,
                                "evaluated": self._chunks_evaluated,
                                "rows": self._rows_evaluated,
@@ -380,8 +374,6 @@ class SweepEngine:
         return info
 
     def cache_clear(self) -> None:
-        # _pallas_fallback survives on purpose: it records a platform
-        # property of this process, not cache state
         with self._lock:
             self._cache.clear()
             self.hits = self.misses = 0
@@ -417,21 +409,15 @@ class SweepEngine:
     def _resolve_cim_backend(self, backend: str) -> tuple[str, str]:
         """(kernel, bucket) for a CiM query: `kernel` in {"xla","pallas"}
         picks the jitted entry point, `bucket` names the result-cache
-        keyspace (and per-backend counters).  A "pallas" request on a
-        platform whose Pallas lowering is unavailable falls back to the
-        XLA kernel — and to the shared "vectorized" keyspace, since the
-        results are then literally the vectorized backend's — recording
-        the reason for cache_info()/telemetry."""
+        keyspace (and per-backend counters).  A "pallas" request first
+        runs the platform probe, which raises where an accelerator
+        cannot compile the kernel."""
         if backend not in CIM_BACKENDS:
             raise ValueError(f"unknown sweep backend {backend!r}; "
                              f"expected one of {CIM_BACKENDS}")
         if backend == "pallas":
             from ..kernels.sweep_eval import pallas_status
-            status = pallas_status()
-            if status["mode"] == "unavailable":
-                with self._lock:
-                    self._pallas_fallback = status["reason"]
-                return "xla", "vectorized"
+            pallas_status()
             return "pallas", "pallas"
         return "xla", "vectorized"
 
@@ -444,9 +430,7 @@ class SweepEngine:
         orders, "greedy" selects each row's smallest-factor-outermost
         order (no scalar fallback).  backend="pallas" routes the batch
         through the fused Pallas kernel (distinct result-cache keyspace,
-        so backend parity tests measure the kernel, not the LRU); when
-        its lowering is unavailable the query falls back to the XLA
-        kernel with the reason recorded in cache_info()."""
+        so backend parity tests measure the kernel, not the LRU)."""
         check_order_mode(order_mode)
         kernel, bucket = self._resolve_cim_backend(backend)
         keys = [("cim", bucket, _gemm_key(g), _cfg_key(c), order_mode)

@@ -5,7 +5,7 @@ its capacity and Rp/Cp/Rh/Ch geometry.  On TPU the analogous decision is
 the Pallas BlockSpec: how large a (bk x bn) INT8 weight tile to hold
 resident in VMEM while activations stream through the MXU.
 
-Mapping of concepts (DESIGN.md §3):
+Mapping of concepts:
   CiM array capacity    -> VMEM weight-tile budget
   Rp (parallel rows)    -> MXU contraction extent (128 sublanes)
   Cp (parallel cols)    -> MXU lane extent (128)
@@ -22,7 +22,8 @@ from __future__ import annotations
 
 from .loopnest import ceil_div
 
-MXU = 128                       # MXU systolic extent
+MXU = 128                       # MXU systolic extent = lane width
+SUBLANE = 8                     # f32 sublane tile height
 VMEM_BUDGET = 8 * 1024 * 1024   # bytes we allow a kernel instance to claim
 PSUM_BYTES = 4                  # f32 accumulator
 
@@ -57,19 +58,25 @@ def choose_blocks(M: int, N: int, K: int, vmem: int = VMEM_BUDGET,
     per_row = bk * act_bytes + bn * PSUM_BYTES
     bm = max(8, min(512, rem // per_row))
     bm = min(bm, M)
-    # legalize: divisibility with the true dims
-    bm = _largest_divisor_leq(M, bm)
-    bn = _largest_divisor_leq(N, bn)
-    bk = _largest_divisor_leq(K, bk)
-    return bm, bn, bk
+    # legalize to the TPU tiling: lane dims (bn, bk) a multiple of 128,
+    # the sublane dim bm a multiple of 8, or the full dim
+    return (legal_block(M, bm, SUBLANE), legal_block(N, bn, MXU),
+            legal_block(K, bk, MXU))
 
 
-def _largest_divisor_leq(x: int, cap: int) -> int:
-    cap = max(1, min(x, cap))
-    for d in range(cap, 0, -1):
-        if x % d == 0:
-            return d
-    return 1
+def legal_block(dim: int, cap: int, align: int) -> int:
+    """Largest block <= cap along one array dim that Mosaic can tile: the
+    whole dim when it fits, else a multiple of `align` (128 on a lane
+    axis, 8 on a sublane axis).  A multiple that divides `dim` is
+    preferred; when none exists the largest aligned block is returned
+    and the kernel pads (M, K) or leaves a ragged last block (N)."""
+    if dim <= cap:
+        return dim
+    top = max(align, cap // align * align)
+    for b in range(top, 0, -align):
+        if dim % b == 0:
+            return b
+    return top
 
 
 def grid_steps(M: int, N: int, K: int, blocks: tuple[int, int, int]) -> int:

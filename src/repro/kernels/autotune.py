@@ -8,9 +8,10 @@ prefill-scale GEMMs — because the analytic choice optimizes the weight
 tile in isolation while the measured winners also balance grid-step
 count (interpret-mode cost on CPU, DMA/compute overlap on TPU).
 
-Every table entry is a *cap*, not a demand: it is legalized down to
-divisors of the true dims (the Pallas BlockSpec divisibility contract)
-and the whole configuration is checked against the VMEM budget before
+Every table entry is a *cap*, not a demand: it is legalized to the TPU
+tiling (`core.tpu_adapter.legal_block`: the full dim, or a multiple of
+128 on lanes and 8 on sublanes, dividing the dim where one does) and
+the whole configuration is checked against the VMEM budget before
 use.  A shape no entry matches — or whose pinned entry would bust the
 budget — falls back to the analytic `choose_blocks`, so the table can
 only ever replace a config with another *valid* one.
@@ -23,11 +24,11 @@ batches.
 """
 from __future__ import annotations
 
-from ..core.tpu_adapter import (PSUM_BYTES, VMEM_BUDGET,
-                                _largest_divisor_leq, choose_blocks)
+from ..core.tpu_adapter import (MXU, PSUM_BYTES, SUBLANE, VMEM_BUDGET,
+                                choose_blocks, legal_block)
 
 # (name, predicate(M, N, K), (block_m, block_n, block_k)) — first match
-# wins; values are caps, legalized + VMEM-checked before use.
+# wins; values are caps, tiling-legalized + VMEM-checked before use.
 INT8_GEMM_TABLE = (
     # decode GEMV / micro-batch: M is tiny — keep all of M resident and
     # maximize the stationary weight tile, K-deep first (the paper's
@@ -57,9 +58,9 @@ def int8_gemm_blocks(M: int, N: int, K: int,
     autotune table, analytic `choose_blocks` as the fallback."""
     for _name, pred, (bm, bn, bk) in INT8_GEMM_TABLE:
         if pred(M, N, K):
-            bm = _largest_divisor_leq(M, min(bm, M))
-            bn = _largest_divisor_leq(N, min(bn, N))
-            bk = _largest_divisor_leq(K, min(bk, K))
+            bm = legal_block(M, bm, SUBLANE)
+            bn = legal_block(N, bn, MXU)
+            bk = legal_block(K, bk, MXU)
             if int8_gemm_vmem_bytes(bm, bn, bk) <= vmem:
                 return bm, bn, bk
             break       # pinned entry busts the budget on this shape

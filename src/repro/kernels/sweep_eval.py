@@ -20,9 +20,8 @@ carried as 0/1 float32 through the kernel and re-boolified outside).
 
 Platform handling mirrors kernels/ops.py: interpret mode on CPU (tests,
 CI containers), compiled Mosaic on TPU.  `pallas_status()` probes the
-lowering once per process; platforms where neither works report
-mode="unavailable" with the lowering error, and the sweep engine falls
-back to the XLA kernel, recording the reason in `cache_info()`.
+lowering once per process and raises where an accelerator cannot
+compile the kernel.
 """
 from __future__ import annotations
 
@@ -116,16 +115,16 @@ _STATUS: dict | None = None
 
 
 def pallas_status() -> dict:
-    """How this process can run the sweep kernel, probed once:
+    """How this process runs the sweep kernel, probed once:
 
-      {"mode": "interpret" | "compiled" | "unavailable", "reason": ...}
+      {"mode": "interpret" | "compiled", "reason": ...}
 
     CPU always takes interpret mode (the repo-wide Pallas convention, see
     kernels/ops.py — the kernel logic is exercised, execution is emulated).
-    Accelerators probe an 8-row compiled lowering; a platform whose Pallas
-    pipeline cannot lower the kernel reports "unavailable" with the error,
-    and the sweep engine falls back to the XLA backend, recording the
-    reason in its cache telemetry (`SweepEngine.cache_info()`).
+    Accelerators compile and run an 8-row probe; a Mosaic lowering or
+    runtime failure there raises RuntimeError carrying the error, so a
+    broken kernel is never replaced by the XLA path behind the caller's
+    back.
     """
     global _STATUS
     if _STATUS is None:
@@ -136,16 +135,16 @@ def pallas_status() -> dict:
                                  "TPU-only; kernel runs via interpret "
                                  "mode"}
         else:
+            probe = {f: np.ones(8, np.float32) for f in FLAT_FIELDS}
             try:
-                probe = {f: np.ones(8, np.float32) for f in FLAT_FIELDS}
                 out = jax.jit(functools.partial(
                     sweep_eval, interpret=False))(probe)
                 jax.block_until_ready(out["energy_pj"])
-                _STATUS = {"mode": "compiled", "reason": None}
-            except Exception as e:  # lowering/runtime failure -> XLA path
-                _STATUS = {"mode": "unavailable",
-                           "reason": f"{platform}: {type(e).__name__}: "
-                                     f"{e}"[:300]}
+            except Exception as e:
+                raise RuntimeError(
+                    f"sweep_eval does not compile on {platform}: "
+                    f"{type(e).__name__}: {e}") from e
+            _STATUS = {"mode": "compiled", "reason": None}
     return _STATUS
 
 

@@ -7,10 +7,6 @@ memory_analysis (fits?) and cost_analysis (FLOPs/bytes for §Roofline),
 parses the partitioned HLO for collective bytes, and writes one JSON per
 cell so an interrupted sweep resumes where it stopped.
 
-Compatibility: Compiled.cost_analysis() returns a flat dict on older jax
-and a list of per-computation dicts on newer jax; _normalize_cost_analysis
-folds both shapes into one dict before any key lookup.
-
 Cost accounting: XLA's cost_analysis counts a while-loop body once, so the
 scanned layer stack under-reports FLOPs/bytes/collectives.  Each cell
 therefore gets (a) the official scanned compile — the deployment program,
@@ -24,7 +20,12 @@ Usage:
 """
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# 512 placeholder host devices for the production meshes, appended to
+# whatever XLA_FLAGS the caller set (read when the backend starts)
+_HOST_DEVICES = "--xla_force_host_platform_device_count=512"
+if _HOST_DEVICES not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (os.environ.get("XLA_FLAGS"), _HOST_DEVICES) if f)
 
 import argparse
 import dataclasses
@@ -163,39 +164,11 @@ def _extrapolate(measures: list, L: int) -> dict:
     return out
 
 
-def _normalize_cost_analysis(cost):
-    """Normalize Compiled.cost_analysis() across jax versions.
-
-    Older jax returns one flat dict; newer jax returns a list of
-    per-computation dicts (usually length 1 — the entry computation).
-    Returns a single dict: a lone entry is taken as-is, multiple entries
-    are merged by summing numeric values per key (each computation's cost
-    contributes to the program total).
-    """
-    if not cost:
-        return {}
-    if isinstance(cost, dict):
-        return cost
-    dicts = [c for c in cost if c]
-    if not dicts:
-        return {}
-    if len(dicts) == 1:
-        return dict(dicts[0])
-    merged: dict = {}
-    for c in dicts:
-        for k, v in c.items():
-            if isinstance(v, (int, float)):
-                merged[k] = merged.get(k, 0.0) + v
-            else:
-                merged.setdefault(k, v)
-    return merged
-
-
 def _compile_costs(cfg, shape, mesh, rc):
     jitted, args = _build(cfg, shape, mesh, rc)
     with mesh:
         compiled = jitted.lower(*args).compile()
-    cost = _normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = collective_stats(hlo)
     return {

@@ -19,17 +19,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def abstract_mesh(shape, axes):
-    """Device-less mesh for sharding-spec legality checks.
-
-    jax <= 0.4.x takes AbstractMesh(((name, size), ...)); newer releases
-    take AbstractMesh(shape, axis_names).  Normalize here so callers (and
-    tests) work on either.
-    """
+    """Device-less mesh for sharding-spec legality checks."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_mesh_from_devices(devices, shape, axes):

@@ -93,13 +93,10 @@ def planner_cache_table(cells: list[dict]) -> str:
         # predate the routing block)
         routed = (f"{p['cim_routed_fraction']:.2f}"
                   if "cim_routed_fraction" in p else "-")
-        # per-backend keyspace breakdown + pallas fallback marker (older
-        # cell JSONs predate both fields)
+        # per-backend keyspace breakdown (older cell JSONs predate it)
         backends = " ".join(f"{b}:{v['hits']}h/{v['misses']}m"
                             for b, v in sorted(
                                 (eng.get("backends") or {}).items()))
-        if eng.get("pallas_fallback"):
-            backends = (backends + " pallas→xla").strip()
         engine_cell = f"{eng['hits']}h/{eng['misses']}m size={eng['size']}"
         if backends:
             engine_cell += f" [{backends}]"

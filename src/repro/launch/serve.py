@@ -41,6 +41,14 @@ from ..serving import (CIM_ROUTE, ContinuousBatchingEngine, DecodeCore,
                        ServeSession, cim_fraction, poisson_arrivals,
                        synthetic_requests)
 from ..serving.engine import _token_struct
+from .compile_cache import configure_compile_cache
+
+# gated vs ungated logits differ only by kernel numerics (Pallas vs XLA
+# f32 accumulation order); logits are O(1) scale.  Calibrated on the
+# reduced (4-layer) configs: at full width the bf16 activations of the
+# model itself move logits further (chip_smoke.py bounds the gated
+# route's error against an f32 reference by the ungated route's + this)
+PARITY_ATOL = 0.05
 
 
 def steady_decode_tokens_per_s(sessions, prompt, n_tokens: int,
@@ -144,7 +152,10 @@ def run_traffic(cfg, rc, params, args) -> dict:
     return report
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The serve CLI's options; `serve(build_parser().parse_args(argv))`
+    is what `main` runs, so an in-process caller (chip_smoke.py) drives
+    exactly the CLI's path."""
     ap = argparse.ArgumentParser(
         description="Serve a model: fixed-batch demo (default) or "
                     "continuous-batching synthetic traffic "
@@ -199,25 +210,21 @@ def main():
     ap.add_argument("--refresh-every", type=int, default=0,
                     help="adaptive: background re-plan a bucket after "
                          "every N lookups (0 = never refresh)")
-    args = ap.parse_args()
-    if args.adaptive and args.requests <= 0:
-        ap.error("--adaptive needs traffic mode (--requests N)")
+    return ap
 
-    cfg = ARCHS[args.arch]
-    if args.smoke:
-        cfg = reduced(cfg)
-    rc = RunConfig(attn_impl="naive", remat=False,
-                   kv_cache_dtype=args.kv_cache_dtype)
-    key = jax.random.PRNGKey(args.seed)
-    params = init(key, cfg)
-    if args.requests > 0:
-        print(json.dumps(run_traffic(cfg, rc, params, args), indent=1))
-        return
+
+def run_fixed_batch(cfg, rc, params, args) -> dict:
+    """Fixed-batch mode: one lockstep `ServeSession`; returns the serve
+    report dict.  With --quantize the report's "gating" block carries
+    the per-label executed routes, gated-vs-ungated logits parity and
+    decode tokens/s, and how many Mosaic kernels the gated decode step
+    lowered to."""
     nimg = cfg.vision.n_image_tokens if cfg.family == "vlm" else 0
     max_len = args.prompt_len + args.new_tokens + 1
     sess = ServeSession(cfg, rc, params, max_len=max_len,
                         batch=args.batch, n_image_tokens=nimg,
                         quantize=args.quantize)
+    key = jax.random.PRNGKey(args.seed)
     if cfg.family == "audio":
         prompt = jax.random.randint(
             key, (args.batch, args.prompt_len, cfg.audio.n_codebooks),
@@ -228,13 +235,14 @@ def main():
     t0 = time.perf_counter()
     out = sess.generate(prompt, n_new=args.new_tokens,
                         temperature=args.temperature, seed=args.seed)
+    out = jax.device_get(out)
     dt = time.perf_counter() - t0
     plan = sess.kernel_plan
     report = {
         "arch": cfg.name, "generated_shape": list(out.shape),
         "tokens_per_s": args.batch * args.new_tokens / dt,
-        "sample_row": [int(x) for x in
-                       jax.device_get(out[0]).reshape(-1)[:16]],
+        "tokens_in_vocab": bool(((out >= 0) & (out < cfg.vocab)).all()),
+        "sample_row": [int(x) for x in out[0].reshape(-1)[:16]],
         # what/when/where gates + planner-cache hit/miss telemetry (LRU
         # sizing is driven by these counters under production traffic).
         # The engine block inside carries the streaming-chunk accounting
@@ -248,14 +256,21 @@ def main():
         from . import distributed as dist
         report["distributed"] = dist.distributed_info()
     if args.quantize:
-        # per-label executed routes + gated-vs-ungated decode throughput:
-        # the ungated session keeps the same INT8 weights, so the
-        # steady-state delta is purely the verdict-driven kernel routing
-        # (both sessions are warmed; jit compile is excluded)
+        # per-label executed routes + gated-vs-ungated parity and decode
+        # throughput: the ungated session keeps the same INT8 weights,
+        # so any delta is purely the verdict-driven kernel routing (both
+        # sessions are warmed; jit compile is excluded from tokens/s)
         routes = sess.route_report()
         ungated = ServeSession(cfg, rc, params, max_len=max_len,
                                batch=args.batch, n_image_tokens=nimg,
                                quantize=True, gated=False)
+        sess.reset()
+        lg = sess.prefill(prompt).astype(jnp.float32)
+        lu = ungated.prefill(prompt).astype(jnp.float32)
+        parity = float(jnp.max(jnp.abs(lg - lu)))
+        finite = bool(jnp.isfinite(lg).all() & jnp.isfinite(lu).all())
+        lowered = sess.core._step.lower(
+            sess.params, sess.cache, prompt[:, :1], jnp.int32(sess.pos))
         tps_g, tps_u = steady_decode_tokens_per_s(
             (sess, ungated), prompt, args.new_tokens)
         report["gating"] = {
@@ -263,10 +278,42 @@ def main():
             "cim_routed": sum(r["route"] == CIM_ROUTE
                               for r in routes.values()),
             "cim_routed_fraction": cim_fraction(routes),
+            # Mosaic kernels in the gated decode step (0 in CPU
+            # interpret mode, where Pallas lowers to plain HLO)
+            "decode_step_tpu_custom_calls":
+                lowered.as_text().count("tpu_custom_call"),
+            "parity_max_abs_diff": parity,
+            "logits_finite": finite,
+            "decode_executables": sess.decode_executables,
             "tokens_per_s_gated": tps_g,
             "tokens_per_s_ungated": tps_u,
         }
-    print(json.dumps(report, indent=1))
+    return report
+
+
+def serve(args) -> dict:
+    """The serve CLI from parsed options to its report dict: builds the
+    model from --arch (--smoke: `reduced` widths) with weights drawn
+    from --seed, then runs traffic mode (--requests N) or the
+    fixed-batch mode."""
+    cfg = ARCHS[args.arch]
+    if args.smoke:
+        cfg = reduced(cfg)
+    rc = RunConfig(attn_impl="naive", remat=False,
+                   kv_cache_dtype=args.kv_cache_dtype)
+    params = init(jax.random.PRNGKey(args.seed), cfg)
+    if args.requests > 0:
+        return run_traffic(cfg, rc, params, args)
+    return run_fixed_batch(cfg, rc, params, args)
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    if args.adaptive and args.requests <= 0:
+        ap.error("--adaptive needs traffic mode (--requests N)")
+    configure_compile_cache()
+    print(json.dumps(serve(args), indent=1))
 
 
 if __name__ == "__main__":

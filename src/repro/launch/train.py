@@ -17,6 +17,7 @@ from ..configs import ARCHS, RunConfig, reduced
 from ..data import DataConfig
 from ..train import train
 from ..train.fault_tolerance import FailureInjector
+from .compile_cache import configure_compile_cache
 
 
 def main():
@@ -36,6 +37,7 @@ def main():
                     help="inject a failure at this step (FT demo)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.smoke:

@@ -56,7 +56,9 @@ def route_trace():
     projection label without any compute — this backs
     `ServeSession.route_report`, the dry-run routing block, and the
     label-coverage test.  Yields a list of
-    {"label", "route", "callsite"} records.
+    {"label", "route", "shape", "callsite"} records; "shape" is the
+    executed (M, N, K) of a plain 2-D projection (M = all leading rows of
+    x), None for a batched `spec` contraction.
     """
     prev = getattr(_ROUTE_TRACE, "records", None)
     _ROUTE_TRACE.records = []
@@ -66,12 +68,12 @@ def route_trace():
         _ROUTE_TRACE.records = prev
 
 
-def _record_route(label: str, route: str) -> None:
+def _record_route(label: str, route: str, mnk) -> None:
     records = getattr(_ROUTE_TRACE, "records", None)
     if records is not None:
         f = sys._getframe(2)        # the frame that called linear()
         records.append({
-            "label": label, "route": route,
+            "label": label, "route": route, "shape": mnk,
             "callsite": f"{os.path.basename(f.f_code.co_filename)}"
                         f":{f.f_lineno}"})
 
@@ -97,27 +99,30 @@ def linear(w, x, label: str, plan=None, spec: str | None = None):
     """
     quantized = isinstance(w, dict)
     use_cim = bool(plan is not None and quantized and plan.use_cim(label))
+    wt = w[next(k for k in ("q", "q4", "qf8") if k in w)] if quantized else w
+    mnk = ((math.prod(x.shape[:-1]), wt.shape[-1], x.shape[-1])
+           if spec is None and wt.ndim == 2 else None)
     if quantized:
         # the present key is the jit-static format discriminator
         # (quant.lowbit): "q" int8 / "q4" packed int4 / "qf8" scaled fp8
         if "q4" in w:
             if use_cim and spec is None and w["q4"].ndim == 2:
-                _record_route(label, CIM_INT4_ROUTE)
+                _record_route(label, CIM_INT4_ROUTE, mnk)
                 return planned_linear_int4(x, w["q4"], w["scale"])
-            _record_route(label, DEQUANT_INT4_ROUTE)
+            _record_route(label, DEQUANT_INT4_ROUTE, mnk)
             return dequant_contract_int4(x, w["q4"], w["scale"], spec)
         if "qf8" in w:
             if use_cim and spec is None and w["qf8"].ndim == 2:
-                _record_route(label, CIM_FP8_ROUTE)
+                _record_route(label, CIM_FP8_ROUTE, mnk)
                 return planned_linear_fp8(x, w["qf8"], w["scale"])
-            _record_route(label, DEQUANT_FP8_ROUTE)
+            _record_route(label, DEQUANT_FP8_ROUTE, mnk)
             return dequant_contract_fp8(x, w["qf8"], w["scale"], spec)
         if use_cim and spec is None and w["q"].ndim == 2:
-            _record_route(label, CIM_ROUTE)
+            _record_route(label, CIM_ROUTE, mnk)
             return planned_linear(x, w["q"], w["scale"], use_cim_path=True)
-        _record_route(label, DEQUANT_ROUTE)
+        _record_route(label, DEQUANT_ROUTE, mnk)
         return dequant_contract(x, w["q"], w["scale"], spec)
-    _record_route(label, FLOAT_ROUTE)
+    _record_route(label, FLOAT_ROUTE, mnk)
     if w.dtype != x.dtype:
         w = w.astype(x.dtype)
     return jnp.einsum(spec, x, w) if spec else x @ w

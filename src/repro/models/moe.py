@@ -12,8 +12,10 @@ Decode exception: when the token count fits expert capacity (T <= C —
 always true for a decode micro-batch) capacity dropping is impossible,
 so `moe_apply` skips the dispatch machinery and runs every expert over
 every token with a plain batched einsum, then selects each token's
-top-k outputs.  Same math (the fast-path FLOP count E*T rows is <= the
-buffer's E*C), far fewer ops on the hot path — the scatter/cumsum/
+top-k outputs.  Same math up to float reassociation (the two paths
+reduce in different orders, so they agree to f32 rounding, not bit for
+bit; the fast-path FLOP count E*T rows is <= the buffer's E*C), far
+fewer ops on the hot path — the scatter/cumsum/
 segment-sum chain is the dominant per-step cost at decode shapes.
 """
 from __future__ import annotations
@@ -79,9 +81,10 @@ def moe_apply(params, x, cfg: ModelConfig, plan=None, *,
         # avoids.  Run every expert over every token outright (E*T rows
         # vs the buffer's E*C, T <= C) and select each token's top-k
         # outputs.  The per-(expert, token) dot products and the
-        # k-ascending weighted sum are the same contractions in the
-        # same order as the buffered path: identical semantics, fewer
-        # ops.
+        # k-weighted sum are the buffered path's contractions, reduced
+        # in a different order (`etf` einsums + sum over k here,
+        # `ecf` einsums + segment_sum there): the same semantics to f32
+        # rounding, fewer ops.
         g = jax.nn.silu(linear(params["w_gate"], xt, "expert-gate",
                                plan, spec="td,edf->etf"))
         u = linear(params["w_up"], xt, "expert-up", plan,

@@ -62,18 +62,38 @@ def dequant_contract(x, q, scale, spec: str | None = None, *,
     Mathematically identical to the canonical expression up to float
     reassociation: sum_k x_k·(q_kj·s_j) == (sum_k x_k·q_kj)·s_j.
 
+    A plain matmul accumulates and is scaled in f32 and rounds to
+    x.dtype once, as the Pallas route does (`planned_linear`): rounding
+    the bf16 product and then the bf16 scaled product again put 0.18 of
+    logit difference between the two routes of full-width mamba2-780m
+    on a TPU v5e.
+
     `materialize=True` keeps the canonical `dequantize_weight` expression
     — the parity reference the fused path is tested against."""
     if not materialize:
-        qx = q.astype(x.dtype)
-        if spec is None:
-            s = scale.astype(x.dtype)
-            return (x @ qx) * (s if q.ndim == 2 else s[..., None, :])
-        s = _epilogue_scale(spec, scale)
-        if s is not None:
-            return jnp.einsum(spec, x, qx) * s.astype(x.dtype)
+        return scaled_contract(x, q.astype(x.dtype), scale, spec)
     w = dequantize_weight(q, scale, x.dtype)
     return jnp.einsum(spec, x, w) if spec else x @ w
+
+
+def scaled_contract(x, q, scale, spec: str | None = None):
+    """(x · q) · scale for a weight `q` already cast to x.dtype (exact
+    for the int8/int4/fp8 values it carries).  A plain matmul accumulates
+    and scales in f32 and rounds to x.dtype once, like the Pallas route
+    it is compared with; a batched `spec` contraction (no Pallas
+    counterpart, and XLA:CPU has no bf16 x bf16 -> f32 batched dot)
+    scales its x.dtype product.  A spec whose scale axis is summed out
+    of the output materializes q · scale instead."""
+    if spec is None:
+        f32 = jnp.float32
+        y = jnp.matmul(x, q, preferred_element_type=f32)
+        s = scale.astype(f32)
+        return (y * (s if q.ndim == 2 else s[..., None, :])
+                ).astype(x.dtype)
+    se = _epilogue_scale(spec, scale)
+    if se is not None:
+        return jnp.einsum(spec, x, q) * se.astype(x.dtype)
+    return jnp.einsum(spec, x, q * scale.astype(x.dtype)[..., None, :])
 
 
 def quantize_tree(params, min_size: int = 1 << 16):

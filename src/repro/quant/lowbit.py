@@ -21,6 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .int8 import scaled_contract
+
 FP8_DTYPE = jnp.float8_e4m3fn
 FP8_MAX = 448.0          # e4m3 finite max
 
@@ -90,27 +92,12 @@ def dequant_contract_int4(x, packed, scale, spec: str | None = None):
     Unpacks the nibbles (O(K·N) int8, transient) and contracts in x.dtype
     — exact for int4 magnitudes in every float dtype in use."""
     q = unpack_int4(packed, x.shape[-1]).astype(x.dtype)
-    s = scale.astype(x.dtype)
-    if spec is None:
-        return (x @ q) * (s if q.ndim == 2 else s[..., None, :])
-    from .int8 import _epilogue_scale
-    se = _epilogue_scale(spec, scale)
-    if se is not None:
-        return jnp.einsum(spec, x, q) * se.astype(x.dtype)
-    return jnp.einsum(spec, x, q * s[..., None, :])
+    return scaled_contract(x, q, scale, spec)
 
 
 def dequant_contract_fp8(x, qf, scale, spec: str | None = None):
     """x · dequant(fp8) with the scale fused into the output epilogue."""
-    q = qf.astype(x.dtype)
-    s = scale.astype(x.dtype)
-    if spec is None:
-        return (x @ q) * (s if qf.ndim == 2 else s[..., None, :])
-    from .int8 import _epilogue_scale
-    se = _epilogue_scale(spec, scale)
-    if se is not None:
-        return jnp.einsum(spec, x, q) * se.astype(x.dtype)
-    return jnp.einsum(spec, x, q * s[..., None, :])
+    return scaled_contract(x, qf.astype(x.dtype), scale, spec)
 
 
 # --- Pallas GEMM routes -----------------------------------------------------

@@ -333,6 +333,14 @@ class DecodeCore:
         the private jax jit-cache probe is unavailable."""
         return self._executables(self._prefill_step)
 
+    def plan_executables(self, plan) -> int | None:
+        """Programs compiled for one plan table's batch-step variant (0
+        when that variant never ran).  None if the private jax jit-cache
+        probe is unavailable."""
+        with self._exec_lock:
+            fn = self._batch_steps.get(plan)
+        return 0 if fn is None else self._executables(fn)
+
     @property
     def batch_decode_executables(self) -> int | None:
         """Total programs compiled across every cached batch-step variant
@@ -351,8 +359,10 @@ class DecodeCore:
 
     def route_report(self, batch: int, max_len: int,
                      n_image_tokens: int = 0) -> dict:
-        """label -> {route, use_cim, what, where} as actually lowered by
-        the jitted decode step (abstract trace, no compute)."""
+        """label -> {route, use_cim, what, where, shapes} as actually
+        lowered by the jitted decode step (abstract trace, no compute);
+        "shapes" lists the executed (M, N, K) of each plain projection
+        call under the label."""
         from ..models import init_cache
         cache = jax.eval_shape(
             lambda: init_cache(self.cfg, self.rc, batch, max_len,
@@ -368,9 +378,13 @@ class DecodeCore:
         for r in records:
             entry = (self.plan_table.entry(r["label"])
                      if self.plan_table is not None else None)
+            shapes = report.get(r["label"], {}).get("shapes", [])
+            if r["shape"] is not None and list(r["shape"]) not in shapes:
+                shapes = shapes + [list(r["shape"])]
             report[r["label"]] = {
                 "route": r["route"],
                 "use_cim": entry.use_cim if entry else False,
                 "what": entry.what if entry else "baseline",
-                "where": entry.where if entry else "PE"}
+                "where": entry.where if entry else "PE",
+                "shapes": shapes}
         return report

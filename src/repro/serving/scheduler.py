@@ -702,6 +702,12 @@ class ContinuousBatchingEngine:
                             and self._phase_tables["prefill"] is not None),
                 "phase_switches": self.phase_switches,
                 "phase_steps": dict(self.phase_steps),
+                # programs compiled per phase plan: 1 each when nothing
+                # retraced (the phases share one when their plans agree)
+                "executables": {
+                    ph: self.core.plan_executables(t)
+                    for ph, t in self._phase_tables.items()
+                    if t is not None},
             },
             "decode_step_breakdown": self._step_breakdown(),
         }

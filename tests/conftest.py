@@ -13,15 +13,3 @@ def engine():
     re-evaluating identical (GEMM, config) pairs per test."""
     from repro.core.sweep import SweepEngine
     return SweepEngine(mesh=None)
-
-# Property tests use `hypothesis`; offline environments (no wheel baked into
-# the image) fall back to the deterministic stub in _hypothesis_stub.py.
-# CI installs the real package via the `test` extra and skips this branch.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies

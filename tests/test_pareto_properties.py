@@ -12,20 +12,14 @@ Properties:
     under arbitrary chunk-boundary placement through
     `ParetoAccumulator` — the identity the campaign's cross-chunk
     merging rests on.
-
-Runs under real hypothesis when installed, else the deterministic
-`_hypothesis_stub` registered by conftest.py.
 """
 from __future__ import annotations
 
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_stub import given, settings, strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.pareto import (ParetoAccumulator, dominates, pareto_mask,
                                pareto_mask_np, pareto_mask_ref)

@@ -9,9 +9,6 @@ one cost spec (vectorized.cim_*) but lower through entirely different
 compilation pipelines, so agreement here is evidence about the kernels,
 not about shared code paths; the scalar model is the independent
 reference implementation.
-
-Offline tier-1 runs these through tests/_hypothesis_stub.py (boundary
-values first, deterministic draws); CI runs them under real hypothesis.
 """
 import functools
 
@@ -32,21 +29,20 @@ CONFIG_NAMES = sorted(CONFIGS)
 
 # One engine for the whole module: vectorized and pallas results live in
 # separate result-cache keyspaces, so every pallas query really runs the
-# Pallas kernel (module-level instead of the conftest fixture — the stub's
-# @given wrapper takes no pytest fixtures).
+# Pallas kernel (module-level instead of the conftest fixture: hypothesis
+# warns about function-scoped fixtures under @given).
 ENGINE = SweepEngine(mesh=None)
 
 # Shape pool: the degenerate GEMV corner (1), awkward primes/non-pow2
 # sizes (3, 17, 31, 100, 257, 300), and pow2 paper-scale dims.  The low
-# boundary corner is the all-ones GEMM, generated first by both real
-# hypothesis (shrink target) and the stub (boundary-first).
+# boundary corner is the all-ones GEMM (hypothesis's shrink target).
 DIMS = (1, 3, 17, 31, 64, 100, 257, 300, 1024, 4096)
 dim = st.sampled_from(DIMS)
 gemm_shape = st.tuples(dim, dim, dim)
 
 # the widened What axis: every precision the cost model supports, as
 # (bits, fp) pairs.  INT8 first: it is the Table-IV calibration identity
-# and the boundary case both real hypothesis and the stub emit first.
+# and the boundary case hypothesis shrinks toward.
 PRECISIONS = ((8, False), (4, False), (8, True))
 precision = st.sampled_from(PRECISIONS)
 

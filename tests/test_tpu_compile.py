@@ -1,0 +1,161 @@
+"""Compiles of the main-path Pallas kernels for a described TPU v5e.
+
+No chip is attached: `jax.experimental.topologies` describes a v5e:2x2
+host and the TPU compiler (Mosaic for Pallas) compiles for one of its
+chips.  That refuses what interpret mode accepts — block shapes off the
+(8, 128) tiling, operand layouts Mosaic cannot match, more VMEM than a
+kernel may claim — so these tests guard the kernels a "use CiM" verdict
+routes mamba2-780m's full-width projections to, and the fused planner
+sweep kernel, at no chip time.  Nothing runs; results are checked on
+the chip (chip_smoke.py).
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU library.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import ARCHS, SHAPES
+from repro.core.llm_workloads import gemms_of_model, is_projection_label
+from repro.kernels.autotune import int8_gemm_blocks, int8_gemm_vmem_bytes
+from repro.kernels.int8_gemm import int8_gemm
+
+_M2 = ARCHS["mamba2-780m"]
+_D = _M2.d_model
+_DI = _M2.ssm.d_inner(_D)
+# (N, K) of every projection the planner gates onto the Pallas route at
+# full width, as the model executes it (B, C and dt are separate calls)
+ROUTED_NK = {
+    "ssm-z/x": (_DI, _D),
+    "ssm-B/C": (_M2.ssm.n_groups * _M2.ssm.d_state, _D),
+    "ssm-dt": (_M2.ssm.n_ssm_heads(_D), _D),
+    "ssm-out": (_D, _DI),
+    "lm_head": (_M2.vocab, _D),
+}
+# N with no 128-multiple divisor (ragged last N block), and K with none
+# (zero-padded K)
+EDGE_NK = {"ragged-n": (6448, 1536), "padded-k": (1000, 1601)}
+DECODE_M, PREFILL_M = 8, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_int8_gemm(one_chip, m, n, k, dataflow, blocks=None):
+    bm, bn, bk = blocks or int8_gemm_blocks(m, n, k)
+    fn = jax.jit(functools.partial(
+        int8_gemm, block_m=bm, block_n=bn, block_k=bk, dataflow=dataflow,
+        interpret=False))
+    args = (jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((k, n), jnp.int8, sharding=one_chip),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip))
+    return fn.lower(*args).compile()
+
+
+@pytest.mark.parametrize("dataflow", ["os", "ws"])
+@pytest.mark.parametrize("m", [DECODE_M, PREFILL_M])
+@pytest.mark.parametrize("label", sorted(ROUTED_NK) + sorted(EDGE_NK))
+def test_int8_gemm_compiles_for_v5e(one_chip, label, m, dataflow):
+    n, k = {**ROUTED_NK, **EDGE_NK}[label]
+    compiled = _compile_int8_gemm(one_chip, m, n, k, dataflow)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_int8_gemm_ws_multiblock_compiles_for_v5e(one_chip):
+    """ws with several M and K blocks: the case whose psums must stay in
+    the VMEM-resident output window across the M stream."""
+    compiled = _compile_int8_gemm(one_chip, PREFILL_M, _DI, _D, "ws",
+                                  blocks=(32, 512, 512))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _decode_projection_shapes():
+    shapes = set()
+    for cfg in ARCHS.values():
+        for shape in ("decode_32k", "long_500k"):
+            shapes |= {(g.M, g.N, g.K)
+                       for g in gemms_of_model(cfg, SHAPES[shape])
+                       if is_projection_label(g.label)}
+    return sorted(shapes)
+
+
+def test_int8_gemm_compiles_every_decode_projection(one_chip):
+    """The serving route's blocks compile for every decode projection
+    of the ten archs (M = 128 and M = 1)."""
+    for m, n, k in _decode_projection_shapes():
+        compiled = _compile_int8_gemm(one_chip, m, n, k, "os")
+        assert "tpu_custom_call" in compiled.as_text(), (m, n, k)
+
+
+def test_sweep_eval_compiles_for_v5e(one_chip):
+    from repro.core.vectorized import FLAT_FIELDS
+    from repro.kernels.sweep_eval import sweep_eval
+    batch = {f: jax.ShapeDtypeStruct((8192,), jnp.float32,
+                                     sharding=one_chip)
+             for f in FLAT_FIELDS}
+    compiled = jax.jit(functools.partial(
+        sweep_eval, interpret=False)).lower(batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _all_gemm_shapes():
+    return sorted({(g.M, g.N, g.K) for cfg in ARCHS.values()
+                   for shape in SHAPES.values()
+                   for g in gemms_of_model(cfg, shape)})
+
+
+def test_int8_gemm_blocks_tiling_legal_all_archs():
+    """Pure Python: for every GEMM of the ten archs at every shape, the
+    chosen blocks are the full dim or a multiple of the TPU tile (8 on
+    the sublane axis M, 128 on the lane axes N and K), divide every dim
+    that has such a divisor, and fit the VMEM budget."""
+    from repro.core.tpu_adapter import VMEM_BUDGET
+    shapes = _all_gemm_shapes()
+    assert len(shapes) > 200
+    for m, n, k in shapes:
+        bm, bn, bk = int8_gemm_blocks(m, n, k)
+        for dim, b, align in ((m, bm, 8), (n, bn, 128), (k, bk, 128)):
+            assert b == dim or (b % align == 0 and b < dim), (m, n, k)
+            if b != dim and any(dim % d == 0
+                                for d in range(align, b + 1, align)):
+                assert dim % b == 0, (m, n, k, b)
+        assert int8_gemm_vmem_bytes(bm, bn, bk) <= VMEM_BUDGET, (m, n, k)
+
+
+def test_int8_gemm_ragged_and_padded_blocks_match_reference():
+    """The ragged last N block and the zero-padded K tail give the
+    reference result (interpret mode here; compiled on the chip by
+    chip_smoke.py), in both dataflows."""
+    from repro.kernels import ops, ref
+    key = jax.random.PRNGKey(0)
+    for n, k in EDGE_NK.values():
+        k1, k2, k3, key = jax.random.split(key, 4)
+        x = jax.random.normal(k1, (24, k), jnp.float32)
+        w = jax.random.randint(k2, (k, n), -127, 128, jnp.int8)
+        s = jax.random.uniform(k3, (n,), jnp.float32, 0.01, 0.1)
+        want = np.asarray(ref.int8_gemm_ref(x, w, s))
+        for dataflow in ("os", "ws"):
+            got = np.asarray(ops.int8_matmul(x, w, s, dataflow=dataflow,
+                                             block_m=16, block_n=512,
+                                             block_k=512))
+            # f32 sums of ~1.6k products of magnitude up to ~400:
+            # accumulation order alone moves them by ~1e-6 of max|y|
+            np.testing.assert_allclose(
+                got, want, rtol=0, atol=1e-5 * np.abs(want).max())
