@@ -46,6 +46,25 @@ CIM_FP8_ROUTE = "cim-fp8-pallas"
 DEQUANT_FP8_ROUTE = "fp8-dequant-xla"
 FLOAT_ROUTE = "xla"
 
+# Device-side scopes (`jax.named_scope`) of the decode step's sublayers.
+# Each op of the compiled step carries the innermost one in its op_name
+# metadata, so a profiler trace attributes device time to sublayers:
+#   embed       token embedding lookup
+#   norm        RMSNorm (pre-mixer, pre-FFN, final)
+#   ffn_act     SwiGLU's elementwise silu(gate) * up
+#   attn_core   RoPE, the paged KV write and gather, decode attention
+#   ssd         Mamba-2's causal conv, SSD state update, gated norm
+#   cache_mask  per-slot select of the updated cache rows
+#   lm_head     the LM head (its matmul is a projection, below)
+#   layer_scan  the scan over layers itself: slicing each layer's
+#               weights and cache out of the stacked arrays, writing the
+#               new cache back, the residual adds
+# and every projection, inside `linear`, "proj/<route>/<label>" with the
+# route `route_trace` records.
+SCOPES = ("embed", "norm", "ffn_act", "attn_core", "ssd", "cache_mask",
+          "lm_head", "layer_scan")
+PROJ_SCOPE = "proj"
+
 
 @contextlib.contextmanager
 def route_trace():
@@ -96,36 +115,42 @@ def linear(w, x, label: str, plan=None, spec: str | None = None):
     lowered program contains exactly one implementation per label — no
     runtime branch, no retrace.  Unknown labels raise KeyError from the
     plan table: model-side label drift must not silently disable gating.
+    The projection's ops carry the scope "proj/<route>/<label>".
     """
     quantized = isinstance(w, dict)
     use_cim = bool(plan is not None and quantized and plan.use_cim(label))
-    wt = w[next(k for k in ("q", "q4", "qf8") if k in w)] if quantized else w
+    # the present key is the jit-static format discriminator
+    # (quant.lowbit): "q" int8 / "q4" packed int4 / "qf8" scaled fp8
+    key = next(k for k in ("q", "q4", "qf8") if k in w) if quantized else None
+    wt = w[key] if quantized else w
     mnk = ((math.prod(x.shape[:-1]), wt.shape[-1], x.shape[-1])
            if spec is None and wt.ndim == 2 else None)
+    cim = use_cim and spec is None and wt.ndim == 2
     if quantized:
-        # the present key is the jit-static format discriminator
-        # (quant.lowbit): "q" int8 / "q4" packed int4 / "qf8" scaled fp8
-        if "q4" in w:
-            if use_cim and spec is None and w["q4"].ndim == 2:
-                _record_route(label, CIM_INT4_ROUTE, mnk)
-                return planned_linear_int4(x, w["q4"], w["scale"])
-            _record_route(label, DEQUANT_INT4_ROUTE, mnk)
-            return dequant_contract_int4(x, w["q4"], w["scale"], spec)
-        if "qf8" in w:
-            if use_cim and spec is None and w["qf8"].ndim == 2:
-                _record_route(label, CIM_FP8_ROUTE, mnk)
-                return planned_linear_fp8(x, w["qf8"], w["scale"])
-            _record_route(label, DEQUANT_FP8_ROUTE, mnk)
-            return dequant_contract_fp8(x, w["qf8"], w["scale"], spec)
-        if use_cim and spec is None and w["q"].ndim == 2:
-            _record_route(label, CIM_ROUTE, mnk)
-            return planned_linear(x, w["q"], w["scale"], use_cim_path=True)
-        _record_route(label, DEQUANT_ROUTE, mnk)
-        return dequant_contract(x, w["q"], w["scale"], spec)
-    _record_route(label, FLOAT_ROUTE, mnk)
-    if w.dtype != x.dtype:
-        w = w.astype(x.dtype)
-    return jnp.einsum(spec, x, w) if spec else x @ w
+        (cim_route, kernel), (xla_route, contract) = _FORMATS[key]
+        route = cim_route if cim else xla_route
+    else:
+        route = FLOAT_ROUTE
+    _record_route(label, route, mnk)
+    with jax.named_scope(f"{PROJ_SCOPE}/{route}/{label}"):
+        if cim:
+            return kernel(x, wt, w["scale"])
+        if quantized:
+            return contract(x, wt, w["scale"], spec)
+        if w.dtype != x.dtype:
+            w = w.astype(x.dtype)
+        return jnp.einsum(spec, x, w) if spec else x @ w
+
+
+# weight key -> (Pallas route, kernel), (XLA route, epilogue contraction)
+_FORMATS = {
+    "q": ((CIM_ROUTE, partial(planned_linear, use_cim_path=True)),
+          (DEQUANT_ROUTE, dequant_contract)),
+    "q4": ((CIM_INT4_ROUTE, planned_linear_int4),
+           (DEQUANT_INT4_ROUTE, dequant_contract_int4)),
+    "qf8": ((CIM_FP8_ROUTE, planned_linear_fp8),
+            (DEQUANT_FP8_ROUTE, dequant_contract_fp8)),
+}
 
 
 # --- initializers -----------------------------------------------------------
@@ -148,11 +173,12 @@ def rmsnorm_init(d: int, dtype):
 
 
 def rmsnorm(params, x, eps: float = 1e-5):
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    x = x * jax.lax.rsqrt(var + eps)
-    return (x * params["scale"].astype(jnp.float32)).astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        x = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        x = x * jax.lax.rsqrt(var + eps)
+        return (x * params["scale"].astype(jnp.float32)).astype(dt)
 
 
 # --- rotary embeddings --------------------------------------------------------
@@ -187,10 +213,11 @@ def swiglu_init(key, d: int, d_ff: int, dtype):
 def swiglu(params, x, plan=None, label_prefix: str = "mlp"):
     """Gated MLP; label_prefix distinguishes dense "mlp-*" from the MoE
     "shared-*" expert (matching gemms_of_model labels)."""
-    g = jax.nn.silu(linear(params["w_gate"], x, f"{label_prefix}-gate",
-                           plan))
+    g = linear(params["w_gate"], x, f"{label_prefix}-gate", plan)
     u = linear(params["w_up"], x, f"{label_prefix}-up", plan)
-    return linear(params["w_down"], g * u, f"{label_prefix}-down", plan)
+    with jax.named_scope("ffn_act"):
+        a = jax.nn.silu(g) * u
+    return linear(params["w_down"], a, f"{label_prefix}-down", plan)
 
 
 # --- attention projections ------------------------------------------------------

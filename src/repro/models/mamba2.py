@@ -165,42 +165,44 @@ def mamba_apply(params, x, cfg: ModelConfig, state=None, conv_carry=None,
     # three weights, one verdict, one call site
     B, C, dt = (linear(params[w], x, "ssm-BCdt", plan)
                 for w in ("w_B", "w_C", "w_dt"))
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + params["dt_bias"])              # (b, l, nh)
-    A = -jnp.exp(params["A_log"])                          # (nh,)
+    with jax.named_scope("ssd"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + params["dt_bias"])          # (b, l, nh)
+        A = -jnp.exp(params["A_log"])                      # (nh,)
 
-    # depthwise causal conv on x / B / C separately (carry is concat)
-    if conv_carry is not None:
-        cx, cB, cC = (conv_carry[..., :di],
-                      conv_carry[..., di:di + gdim],
-                      conv_carry[..., di + gdim:])
-    else:
-        cx = cB = cC = None
-    xs, nx = _causal_conv(xs, params["conv_x"], cx)
-    B, nB = _causal_conv(B, params["conv_B"], cB)
-    C, nC = _causal_conv(C, params["conv_C"], cC)
-    new_conv = (jnp.concatenate([nx, nB, nC], axis=-1)
-                if nx is not None else None)
-    p = s.headdim
-    xh = xs.reshape(b, l, nh, p)
-    Bh = B.reshape(b, l, s.n_groups, s.d_state)
-    Ch = C.reshape(b, l, s.n_groups, s.d_state)
+        # depthwise causal conv on x / B / C separately (carry is concat)
+        if conv_carry is not None:
+            cx, cB, cC = (conv_carry[..., :di],
+                          conv_carry[..., di:di + gdim],
+                          conv_carry[..., di + gdim:])
+        else:
+            cx = cB = cC = None
+        xs, nx = _causal_conv(xs, params["conv_x"], cx)
+        B, nB = _causal_conv(B, params["conv_B"], cB)
+        C, nC = _causal_conv(C, params["conv_C"], cC)
+        new_conv = (jnp.concatenate([nx, nB, nC], axis=-1)
+                    if nx is not None else None)
+        p = s.headdim
+        xh = xs.reshape(b, l, nh, p)
+        Bh = B.reshape(b, l, s.n_groups, s.d_state)
+        Ch = C.reshape(b, l, s.n_groups, s.d_state)
 
-    if decode:
-        y_t, new_state = ssd_decode_step(
-            state, xh[:, 0], dt[:, 0], A, Bh[:, 0], Ch[:, 0])
-        y = y_t[:, None]                                   # (b, 1, nh, p)
-    else:
-        y, new_state = ssd_chunked(xh, dt, A, Bh, Ch,
-                                   chunk=min(s.chunk, l), init_state=state)
-    y = y + xh * params["D"][None, None, :, None]
-    y = y.reshape(b, l, di)
-    # gated RMSNorm (mamba2 norm-before-gate)
-    yf = y.astype(jnp.float32)
-    yf = yf * jax.lax.rsqrt(jnp.mean(yf ** 2, -1, keepdims=True)
-                            + cfg.rmsnorm_eps)
-    y = (yf * params["norm_scale"].astype(jnp.float32)).astype(x.dtype)
-    y = y * jax.nn.silu(z)
+        if decode:
+            y_t, new_state = ssd_decode_step(
+                state, xh[:, 0], dt[:, 0], A, Bh[:, 0], Ch[:, 0])
+            y = y_t[:, None]                               # (b, 1, nh, p)
+        else:
+            y, new_state = ssd_chunked(xh, dt, A, Bh, Ch,
+                                       chunk=min(s.chunk, l),
+                                       init_state=state)
+        y = y + xh * params["D"][None, None, :, None]
+        y = y.reshape(b, l, di)
+        # gated RMSNorm (mamba2 norm-before-gate)
+        yf = y.astype(jnp.float32)
+        yf = yf * jax.lax.rsqrt(jnp.mean(yf ** 2, -1, keepdims=True)
+                                + cfg.rmsnorm_eps)
+        y = (yf * params["norm_scale"].astype(jnp.float32)).astype(x.dtype)
+        y = y * jax.nn.silu(z)
     return linear(params["out_proj"], y, "ssm-out", plan), \
         (new_state, new_conv)
 

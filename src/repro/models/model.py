@@ -139,10 +139,11 @@ def _lm_logits(params, x, cfg: ModelConfig, plan=None):
     per-codebook (nb, d, vocab) and contract via einsum; tied embeddings
     reuse the (float) embedding matrix transposed."""
     spec = "bld,ndv->blnv" if cfg.family == "audio" else None
-    head = (params["embed"].T
-            if cfg.tie_embeddings and cfg.family != "audio"
-            else params["lm_head"])
-    return linear(head, x, "lm_head", plan, spec=spec)
+    with jax.named_scope("lm_head"):
+        head = (params["embed"].T
+                if cfg.tie_embeddings and cfg.family != "audio"
+                else params["lm_head"])
+        return linear(head, x, "lm_head", plan, spec=spec)
 
 
 def _apply_mixer_full(slot: Slot, sp, x, cfg: ModelConfig, rc: RunConfig,
@@ -410,8 +411,75 @@ def _mask_rows(new, old, active):
     free slots keep (frozen) state so garbage tokens can't corrupt them."""
     if active is None:
         return new
-    m = active.reshape((-1,) + (1,) * (new.ndim - 1))
-    return jnp.where(m, new.astype(old.dtype), old)
+    with jax.named_scope("cache_mask"):
+        m = active.reshape((-1,) + (1,) * (new.ndim - 1))
+        return jnp.where(m, new.astype(old.dtype), old)
+
+
+def _decode_attention(q, k, v, cache_s, pos, cfg: ModelConfig,
+                      rc: RunConfig, active, block_tables):
+    """RoPE, this token's KV write and attention over the slot's cache:
+    (o, new cache entry).  Paged when `block_tables` is given: the row is
+    scattered into the slot's current block and its logical strip is
+    gathered back; otherwise the contiguous cache is updated in place."""
+    b = q.shape[0]
+    ragged = jnp.ndim(pos) == 1
+    pvec = pos[:, None] if ragged else jnp.full((b, 1), pos, jnp.int32)
+    q = apply_rope(q, pvec, cfg.rope_theta)
+    k = apply_rope(k, pvec, cfg.rope_theta)
+    int8_kv = rc.kv_cache_dtype == "int8"
+    if block_tables is not None:
+        if int8_kv:
+            kq, ks = _quantize_kv(k)
+            vq, vs = _quantize_kv(v)
+            ck = _paged_write(cache_s["k"], kq[:, 0], pos, block_tables,
+                              active)
+            cv = _paged_write(cache_s["v"], vq[:, 0], pos, block_tables,
+                              active)
+            cks = _paged_write(cache_s["k_scale"], ks[:, 0], pos,
+                               block_tables, active)
+            cvs = _paged_write(cache_s["v_scale"], vs[:, 0], pos,
+                               block_tables, active)
+            kd = _dequantize_kv(_paged_view(ck, block_tables),
+                                _paged_view(cks, block_tables))
+            vd = _dequantize_kv(_paged_view(cv, block_tables),
+                                _paged_view(cvs, block_tables))
+            entry = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
+        else:
+            ck = _paged_write(cache_s["k"], k[:, 0], pos, block_tables,
+                              active)
+            cv = _paged_write(cache_s["v"], v[:, 0], pos, block_tables,
+                              active)
+            kd = _paged_view(ck, block_tables)
+            vd = _paged_view(cv, block_tables)
+            entry = {"k": ck, "v": cv}
+        lens = pos + 1 if ragged else jnp.full((b,), pos + 1, jnp.int32)
+    else:
+        if int8_kv:
+            kq, ks = _quantize_kv(k)
+            vq, vs = _quantize_kv(v)
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                cache_s["k"], kq, pos, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cache_s["v"], vq, pos, axis=1)
+            cks = jax.lax.dynamic_update_slice_in_dim(
+                cache_s["k_scale"], ks, pos, axis=1)
+            cvs = jax.lax.dynamic_update_slice_in_dim(
+                cache_s["v_scale"], vs, pos, axis=1)
+            kd = _dequantize_kv(ck, cks)
+            vd = _dequantize_kv(cv, cvs)
+            entry = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
+        else:
+            ck = jax.lax.dynamic_update_slice_in_dim(
+                cache_s["k"], k.astype(cache_s["k"].dtype), pos, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(
+                cache_s["v"], v.astype(cache_s["v"].dtype), pos, axis=1)
+            kd, vd = ck, cv
+            entry = {"k": ck, "v": cv}
+        lens = jnp.full((b,), pos + 1, jnp.int32)
+    o = decode_attend(q, kd, vd, lens, window=cfg.sliding_window,
+                      grouped=rc.gqa_einsum)
+    return o, entry
 
 
 # --- decode -----------------------------------------------------------------------
@@ -442,12 +510,14 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
         raise ValueError(
             "ragged per-slot positions need a paged KV cache: pass "
             "block_tables (see init_paged_cache) for attention archs")
-    if cfg.family == "audio":
-        x = jnp.sum(jax.vmap(lambda e, t: e[t], in_axes=(0, 2),
-                             out_axes=2)(params["embed"], tokens), axis=2)
-    else:
-        x = params["embed"][tokens]
-    x = x.astype(dtype_of(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        if cfg.family == "audio":
+            x = jnp.sum(jax.vmap(lambda e, t: e[t], in_axes=(0, 2),
+                                 out_axes=2)(params["embed"], tokens),
+                        axis=2)
+        else:
+            x = params["embed"][tokens]
+        x = x.astype(dtype_of(cfg.compute_dtype))
     nh, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim()
 
     def period_body(x, scanned):
@@ -465,84 +535,19 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
                      "conv": _mask_rows(cv, cache_s["conv"], active)})
             elif slot.mixer == "cross":
                 q = _cross_q_proj(sp, h, b, 1, nh, dh, plan)
-                o = decode_attend(
-                    q, cache_s["k"], cache_s["v"],
-                    jnp.full((b,), cache_s["k"].shape[1], jnp.int32))
+                with jax.named_scope("attn_core"):
+                    o = decode_attend(
+                        q, cache_s["k"], cache_s["v"],
+                        jnp.full((b,), cache_s["k"].shape[1], jnp.int32))
                 y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh),
                                   plan, label="xattn-out")
                 new_cache.append(cache_s)
             else:
                 q, k, v = qkv_proj(sp["attn"], h, nh, kvh, dh, plan)
-                pvec = (pos[:, None] if ragged
-                        else jnp.full((b, 1), pos, jnp.int32))
-                q = apply_rope(q, pvec, cfg.rope_theta)
-                k = apply_rope(k, pvec, cfg.rope_theta)
-                if block_tables is not None:
-                    # paged path: scatter this token's KV row into the
-                    # slot's current block, then gather its logical strip
-                    if rc.kv_cache_dtype == "int8":
-                        kq, ks = _quantize_kv(k)
-                        vq, vs = _quantize_kv(v)
-                        ck = _paged_write(cache_s["k"], kq[:, 0], pos,
-                                          block_tables, active)
-                        cv = _paged_write(cache_s["v"], vq[:, 0], pos,
-                                          block_tables, active)
-                        cks = _paged_write(cache_s["k_scale"], ks[:, 0],
-                                           pos, block_tables, active)
-                        cvs = _paged_write(cache_s["v_scale"], vs[:, 0],
-                                           pos, block_tables, active)
-                        kd = _dequantize_kv(_paged_view(ck, block_tables),
-                                            _paged_view(cks, block_tables))
-                        vd = _dequantize_kv(_paged_view(cv, block_tables),
-                                            _paged_view(cvs, block_tables))
-                        new_cache.append({"k": ck, "v": cv,
-                                          "k_scale": cks, "v_scale": cvs})
-                    else:
-                        ck = _paged_write(cache_s["k"], k[:, 0], pos,
-                                          block_tables, active)
-                        cv = _paged_write(cache_s["v"], v[:, 0], pos,
-                                          block_tables, active)
-                        kd = _paged_view(ck, block_tables)
-                        vd = _paged_view(cv, block_tables)
-                        new_cache.append({"k": ck, "v": cv})
-                    lens = (pos + 1 if ragged
-                            else jnp.full((b,), pos + 1, jnp.int32))
-                    o = decode_attend(q, kd, vd, lens,
-                                      window=cfg.sliding_window,
-                                      grouped=rc.gqa_einsum)
-                    y = attn_out_proj(sp["attn"],
-                                      o.reshape(b, 1, nh * dh), plan)
-                    x = x + y
-                    x, _ = _apply_ffn(slot, sp, x, cfg, plan)
-                    continue
-                if rc.kv_cache_dtype == "int8":
-                    kq, ks = _quantize_kv(k)
-                    vq, vs = _quantize_kv(v)
-                    ck = jax.lax.dynamic_update_slice_in_dim(
-                        cache_s["k"], kq, pos, axis=1)
-                    cv = jax.lax.dynamic_update_slice_in_dim(
-                        cache_s["v"], vq, pos, axis=1)
-                    cks = jax.lax.dynamic_update_slice_in_dim(
-                        cache_s["k_scale"], ks, pos, axis=1)
-                    cvs = jax.lax.dynamic_update_slice_in_dim(
-                        cache_s["v_scale"], vs, pos, axis=1)
-                    kd = _dequantize_kv(ck, cks)
-                    vd = _dequantize_kv(cv, cvs)
-                    new_cache.append({"k": ck, "v": cv, "k_scale": cks,
-                                      "v_scale": cvs})
-                else:
-                    ck = jax.lax.dynamic_update_slice_in_dim(
-                        cache_s["k"], k.astype(cache_s["k"].dtype), pos,
-                        axis=1)
-                    cv = jax.lax.dynamic_update_slice_in_dim(
-                        cache_s["v"], v.astype(cache_s["v"].dtype), pos,
-                        axis=1)
-                    kd, vd = ck, cv
-                    new_cache.append({"k": ck, "v": cv})
-                lens = jnp.full((b,), pos + 1, jnp.int32)
-                o = decode_attend(q, kd, vd, lens,
-                                  window=cfg.sliding_window,
-                                  grouped=rc.gqa_einsum)
+                with jax.named_scope("attn_core"):
+                    o, entry = _decode_attention(q, k, v, cache_s, pos, cfg,
+                                                 rc, active, block_tables)
+                new_cache.append(entry)
                 y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh),
                                   plan)
             x = x + y
@@ -550,8 +555,9 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
         return x, new_cache
 
     # scan over periods, threading per-period cache slices
-    x, new_caches = jax.lax.scan(
-        period_body, x, (params["slots"], cache),
-        unroll=max(1, min(rc.scan_unroll, n_periods(cfg))))
+    with jax.named_scope("layer_scan"):
+        x, new_caches = jax.lax.scan(
+            period_body, x, (params["slots"], cache),
+            unroll=max(1, min(rc.scan_unroll, n_periods(cfg))))
     x = rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
     return _lm_logits(params, x, cfg, plan), new_caches

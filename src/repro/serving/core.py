@@ -153,10 +153,15 @@ class DecodeCore:
         # Callers must rebind (`logits, cache = step(params, cache,
         # ...)`) and never touch the donated input again — every in-repo
         # caller does.
-        self._step = jax.jit(
-            lambda params, cache, tokens, pos:
-            decode_step(params, cache, tokens, pos, cfg, rc, plan=plan),
-            donate_argnums=(1,) if self.donate else ())
+        # named functions: the programs are "jit_serve_step",
+        # "jit_serve_prefill_step" and "jit_serve_batch_step" in HLO dumps
+        # and profiler traces
+        def serve_step(params, cache, tokens, pos, _plan=plan):
+            return decode_step(params, cache, tokens, pos, cfg, rc,
+                               plan=_plan)
+
+        self._step = jax.jit(serve_step,
+                             donate_argnums=(1,) if self.donate else ())
         # the prefill-phase step: same per-token decode fn closed over
         # the prefill table.  When the phases agree (or the core is
         # unquantized/ungated: both plans identical) the decode program
@@ -166,10 +171,11 @@ class DecodeCore:
         if pplan == plan:
             self._prefill_step = self._step
         else:
+            def serve_prefill_step(params, cache, tokens, pos):
+                return serve_step(params, cache, tokens, pos, _plan=pplan)
+
             self._prefill_step = jax.jit(
-                lambda params, cache, tokens, pos:
-                decode_step(params, cache, tokens, pos, cfg, rc,
-                            plan=pplan),
+                serve_prefill_step,
                 donate_argnums=(1,) if self.donate else ())
 
     # --- planner plumbing (the session-level API, now core-owned) ------
@@ -287,13 +293,14 @@ class DecodeCore:
                 # paged KV block pools, int8-kv scale pools and per-slot
                 # mamba state update in place across steps (no per-token
                 # pool copy)
-                fn = jax.jit(
-                    lambda params, cache, tokens, pos, active,
-                    block_tables, _plan=plan:
-                    decode_step(params, cache, tokens, pos, cfg, rc,
-                                plan=_plan, active=active,
-                                block_tables=block_tables),
-                    donate_argnums=(1,) if self.donate else ())
+                def serve_batch_step(params, cache, tokens, pos, active,
+                                     block_tables, _plan=plan):
+                    return decode_step(params, cache, tokens, pos, cfg, rc,
+                                       plan=_plan, active=active,
+                                       block_tables=block_tables)
+
+                fn = jax.jit(serve_batch_step,
+                             donate_argnums=(1,) if self.donate else ())
                 self._batch_steps[plan] = fn
             self._batch_steps.move_to_end(plan)
             while len(self._batch_steps) > self.max_plan_variants:

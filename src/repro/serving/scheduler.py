@@ -30,8 +30,15 @@ server:
     synchronous retire;
   * **per-request telemetry**: TTFT, queue wait, decode tokens/s, plus
     engine-level queue depth / slot occupancy / block usage samples and
-    a `decode_step_breakdown` (dispatch vs host-fetch vs telemetry time
-    per step);
+    a `decode_step_breakdown` (admission, plan selection, dispatch,
+    host-fetch and telemetry time per step);
+  * **spans on the profiler's clock**: every `step()` is a
+    `jax.profiler.StepTraceAnnotation` "engine.step" holding one
+    "engine.<phase>" `TraceAnnotation` per phase (admit, with a nested
+    "engine.reset_slot" per joining slot; plan; dispatch; retire;
+    telemetry), opened and closed where the phase's counter reads the
+    clock.  With no profiler running, a span costs about a
+    microsecond of host time;
   * **adaptive planning** (optional): an engine given a
     `repro.core.plan_service.PlanService` consults it every step at the
     live operating point (active-slot count, deepest position); when the
@@ -198,7 +205,6 @@ class ContinuousBatchingEngine:
                  block_size: int = 8, n_kv_blocks: int | None = None,
                  seed: int = 0, record_logits: bool = False,
                  plan_service=None, pipeline: bool = True,
-                 telemetry_every: int = 1,
                  clock: Callable[[], float] = time.perf_counter):
         if core.cfg.family == "vlm":
             raise NotImplementedError(
@@ -235,7 +241,6 @@ class ContinuousBatchingEngine:
         # next feed, so any temperature>0 submit flips the engine to
         # synchronous retire (pipeline=False forces it outright).
         self.pipeline = pipeline
-        self.telemetry_every = max(1, telemetry_every)
         self._sync = False
         self._inflight: _InFlight | None = None
         self._device_toks = None      # prev step's greedy (device)
@@ -248,7 +253,11 @@ class ContinuousBatchingEngine:
         self.steps = 0
         self.queue_depth_samples: list[int] = []
         self.occupancy_samples: list[float] = []
-        # decode_step_breakdown accumulators (seconds)
+        # decode_step_breakdown accumulators (seconds): telemetry_s
+        # holds admission (admit_s) and the samples
+        self.admissions = 0
+        self.admit_s = 0.0
+        self.plan_s = 0.0
         self.dispatch_s = 0.0
         self.host_fetch_s = 0.0
         self.telemetry_s = 0.0
@@ -333,8 +342,11 @@ class ContinuousBatchingEngine:
             self.block_tables[i, :] = 0
             if blocks:
                 self.block_tables[i, :len(blocks)] = blocks
-            self._reset_slot_state(i)
+            with jax.profiler.TraceAnnotation("engine.reset_slot", slot=i,
+                                              rid=req.rid):
+                self._reset_slot_state(i)
             self.slots[i] = _Slot(req, blocks)
+            self.admissions += 1
             req.state = "running"
             req.t_admit = self._now()
 
@@ -443,11 +455,17 @@ class ContinuousBatchingEngine:
         fetch of one step overlaps the device compute of the next.
         Synchronous (temperature traffic / pipeline=False): dispatch and
         retire the same step, the pre-pipeline behavior."""
+        with jax.profiler.StepTraceAnnotation("engine.step",
+                                              step_num=self.steps):
+            return self._step()
+
+    def _step(self) -> bool:
         if not self._pipelined and self._inflight is not None:
             self._retire(self._inflight)    # mode flipped: flush first
         t0 = self.clock()
-        self._admit()
-        if self.steps % self.telemetry_every == 0:
+        with _Span(self, "admit", "admit_s"):
+            self._admit()
+        with _Span(self, "telemetry"):
             self.queue_depth_samples.append(len(self.queue))
             self.occupancy_samples.append(self.active_slots / self.n_slots)
         self.telemetry_s += self.clock() - t0
@@ -456,14 +474,16 @@ class ContinuousBatchingEngine:
                 self._retire(self._inflight)
                 return True
             return False
-        if self.plan_service is not None:
-            self._consult_plan_service()
-        elif self._phase_tables["prefill"] is not None:
-            self._select_phase_table()
-        if self._step_fn is None:
-            self._step_fn = self.core.batch_step_for(self._plan)
+        with _Span(self, "plan", "plan_s"):
+            if self.plan_service is not None:
+                self._consult_plan_service()
+            elif self._phase_tables["prefill"] is not None:
+                self._select_phase_table()
+            if self._step_fn is None:
+                self._step_fn = self.core.batch_step_for(self._plan)
         prev = self._inflight
-        self._inflight = self._dispatch()
+        with _Span(self, "dispatch", "dispatch_s"):
+            self._inflight = self._dispatch()
         if prev is not None:
             self._retire(prev, keep_inflight=True)
         if not self._pipelined:
@@ -479,7 +499,6 @@ class ContinuousBatchingEngine:
         lanes take host tokens.  All per-slot bookkeeping (pos / fed /
         generated counts, max-token draining) happens here, at dispatch;
         `_retire` only attributes the finished tokens to requests."""
-        t0 = self.clock()
         host_toks = self._token_batch()
         pos = np.array([0 if s is None else s.pos for s in self.slots],
                        np.int32)
@@ -503,8 +522,11 @@ class ContinuousBatchingEngine:
             self.donation_ok = bool(probe.is_deleted())
         if self._greedy_fn is None:
             cfg = self.cfg
-            self._greedy_fn = jax.jit(
-                lambda lg: sample_token(cfg, lg, 0.0, None))
+
+            def greedy_tokens(logits):
+                return sample_token(cfg, logits, 0.0, None)
+
+            self._greedy_fn = jax.jit(greedy_tokens)
         greedy = self._greedy_fn(logits)
         self._device_toks = greedy
         self.steps += 1
@@ -528,7 +550,6 @@ class ContinuousBatchingEngine:
                 # this step retires
                 st.draining = True
             recs.append((i, st, st.n_gen == 1, final))
-        self.dispatch_s += self.clock() - t0
         return _InFlight(logits, greedy, recs)
 
     def _retire(self, inf: _InFlight, keep_inflight: bool = False) -> None:
@@ -536,6 +557,10 @@ class ContinuousBatchingEngine:
         append to requests, stamp TTFT, record first-logits (one batched
         transfer for exactly the lanes that produced their first token),
         and evict EOS / max-token slots."""
+        with _Span(self, "retire"):
+            self._retire_step(inf, keep_inflight)
+
+    def _retire_step(self, inf: _InFlight, keep_inflight: bool) -> None:
         if not keep_inflight:
             self._inflight = None
         elif self._inflight is inf:
@@ -715,25 +740,23 @@ class ContinuousBatchingEngine:
                 "adaptive": self._adaptive_telemetry()}
 
     def _step_breakdown(self) -> dict:
-        """Where the per-step host budget goes: device dispatch (token
-        select + step call + bookkeeping), blocking host fetches
-        (tokens / first-logits at retire), and telemetry sampling.
-        Pipelined engines overlap the fetch of step t with the compute
-        of step t+1, so fetch time here is host *blocked* time, not
-        device time."""
+        """Where the per-step host budget goes: admission (slot-state
+        resets included), plan selection (phase table or plan-service
+        lookup, swaps included), device dispatch (token select + step
+        call + bookkeeping), blocking host fetches (tokens /
+        first-logits at retire), and telemetry, which counts admission
+        and the samples (telemetry_s >= admit_s).  Pipelined engines
+        overlap the fetch of step t with the compute of step t+1, so
+        fetch time here is host *blocked* time, not device time."""
         n = max(1, self.steps)
-        return {
-            "steps": self.steps,
-            "pipelined": self._pipelined,
-            "dispatch_s": round(self.dispatch_s, 6),
-            "host_fetch_s": round(self.host_fetch_s, 6),
-            "telemetry_s": round(self.telemetry_s, 6),
-            "dispatch_ms_per_step": round(1e3 * self.dispatch_s / n, 4),
-            "host_fetch_ms_per_step": round(1e3 * self.host_fetch_s / n,
-                                            4),
-            "telemetry_ms_per_step": round(1e3 * self.telemetry_s / n,
-                                           4),
-        }
+        out = {"steps": self.steps, "pipelined": self._pipelined,
+               "admissions": self.admissions}
+        for name in ("admit", "plan", "dispatch", "host_fetch",
+                     "telemetry"):
+            secs = getattr(self, f"{name}_s")
+            out[f"{name}_s"] = round(secs, 6)
+            out[f"{name}_ms_per_step"] = round(1e3 * secs / n, 4)
+        return out
 
     def _adaptive_telemetry(self) -> dict | None:
         """The telemetry()["adaptive"] block: bucket transitions, plan
@@ -758,6 +781,32 @@ class ContinuousBatchingEngine:
                                    if self._plan is not None else None),
             "service": self.plan_service.telemetry(),
         }
+
+
+class _Span:
+    """One engine phase: a `jax.profiler.TraceAnnotation`
+    "engine.<phase>" on the profiler's clock, and, when `counter` names
+    one, the phase's seconds on the engine clock added to that engine
+    counter.  The clock is read just inside the span's edges, so the
+    counter and the span cover the same work."""
+
+    __slots__ = ("engine", "counter", "ann", "t0")
+
+    def __init__(self, engine, phase: str, counter: str | None = None):
+        self.engine, self.counter = engine, counter
+        self.ann = jax.profiler.TraceAnnotation(f"engine.{phase}")
+
+    def __enter__(self):
+        self.ann.__enter__()
+        if self.counter is not None:
+            self.t0 = self.engine.clock()
+
+    def __exit__(self, *exc):
+        e = self.engine
+        if self.counter is not None:
+            setattr(e, self.counter,
+                    getattr(e, self.counter) + e.clock() - self.t0)
+        self.ann.__exit__(*exc)
 
 
 # --- synthetic open-loop traffic ------------------------------------------
