@@ -57,8 +57,8 @@ FLOAT_ROUTE = "xla"
 #   cache_mask  per-slot select of the updated cache rows
 #   lm_head     the LM head (its matmul is a projection, below)
 #   layer_scan  the scan over layers itself: slicing each layer's
-#               weights and cache out of the stacked arrays, writing the
-#               new cache back, the residual adds
+#               weights out of the stacked arrays, reading and writing
+#               its index of the carried cache, the residual adds
 # and every projection, inside `linear`, "proj/<route>/<label>" with the
 # route `route_trace` records.
 SCOPES = ("embed", "norm", "ffn_act", "attn_core", "ssd", "cache_mask",

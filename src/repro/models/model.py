@@ -381,28 +381,28 @@ def _dequantize_kv(q, scale):
     return q.astype(jnp.bfloat16) * scale[..., None]
 
 
-def _paged_write(pool, new, pos, block_tables, active):
-    """Scatter one row per slot into a block pool.
+def _paged_write(pool, i, new, pos, block_tables, active):
+    """Scatter one row per slot into layer `i` of a stacked block pool,
+    in place.
 
-    pool: (n_blocks, block_size, ...); new: (b, ...); pos: (b,) logical
-    positions; block_tables: (b, max_blocks) physical block ids.
-    Inactive slots write out-of-bounds and are dropped (their KV must not
+    pool: (periods, n_blocks, block_size, ...); new: (b, ...); pos: (b,)
+    logical positions; block_tables: (b, max_blocks) physical block ids.
+    Inactive slots write out of bounds and are dropped (their KV must not
     clobber live blocks)."""
-    n_blocks, bs = pool.shape[0], pool.shape[1]
+    bs = pool.shape[2]
     blk = jnp.take_along_axis(block_tables, (pos // bs)[:, None],
                               axis=1)[:, 0]
-    phys = blk * bs + pos % bs
     if active is not None:
-        phys = jnp.where(active, phys, n_blocks * bs)     # OOB -> drop
-    flat = pool.reshape((n_blocks * bs,) + pool.shape[2:])
-    flat = flat.at[phys].set(new.astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
+        blk = jnp.where(active, blk, pool.shape[1])       # OOB -> drop
+    return pool.at[i, blk, pos % bs].set(new.astype(pool.dtype),
+                                         mode="drop")
 
 
-def _paged_view(pool, block_tables):
-    """Gather each slot's logical KV strip from the pool:
-    (n_blocks, bs, ...) + (b, max_blocks) -> (b, max_blocks * bs, ...)."""
-    v = pool[block_tables]
+def _paged_view(pool, i, block_tables):
+    """Gather each slot's logical KV strip from layer `i` of a stacked
+    pool: (periods, n_blocks, bs, ...) + (b, max_blocks) ->
+    (b, max_blocks * bs, ...)."""
+    v = pool[i, block_tables]
     return v.reshape((v.shape[0], v.shape[1] * v.shape[2]) + v.shape[3:])
 
 
@@ -416,67 +416,44 @@ def _mask_rows(new, old, active):
         return jnp.where(m, new.astype(old.dtype), old)
 
 
-def _decode_attention(q, k, v, cache_s, pos, cfg: ModelConfig,
+def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
                       rc: RunConfig, active, block_tables):
-    """RoPE, this token's KV write and attention over the slot's cache:
-    (o, new cache entry).  Paged when `block_tables` is given: the row is
-    scattered into the slot's current block and its logical strip is
-    gathered back; otherwise the contiguous cache is updated in place."""
+    """RoPE, this token's KV write into layer `i` of the stacked cache
+    entry and attention over the slot's cache: (o, updated stacked entry).
+    Paged when `block_tables` is given: the row is scattered into the
+    slot's current block and its logical strip is gathered back;
+    otherwise the row goes to `pos` of the contiguous cache.  Both write
+    into the stack in place: no layer is sliced out and written back."""
     b = q.shape[0]
     ragged = jnp.ndim(pos) == 1
     pvec = pos[:, None] if ragged else jnp.full((b, 1), pos, jnp.int32)
     q = apply_rope(q, pvec, cfg.rope_theta)
     k = apply_rope(k, pvec, cfg.rope_theta)
     int8_kv = rc.kv_cache_dtype == "int8"
+    if int8_kv:
+        (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)
+        rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    else:
+        rows = {"k": k, "v": v}
     if block_tables is not None:
-        if int8_kv:
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            ck = _paged_write(cache_s["k"], kq[:, 0], pos, block_tables,
-                              active)
-            cv = _paged_write(cache_s["v"], vq[:, 0], pos, block_tables,
-                              active)
-            cks = _paged_write(cache_s["k_scale"], ks[:, 0], pos,
-                               block_tables, active)
-            cvs = _paged_write(cache_s["v_scale"], vs[:, 0], pos,
-                               block_tables, active)
-            kd = _dequantize_kv(_paged_view(ck, block_tables),
-                                _paged_view(cks, block_tables))
-            vd = _dequantize_kv(_paged_view(cv, block_tables),
-                                _paged_view(cvs, block_tables))
-            entry = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-        else:
-            ck = _paged_write(cache_s["k"], k[:, 0], pos, block_tables,
-                              active)
-            cv = _paged_write(cache_s["v"], v[:, 0], pos, block_tables,
-                              active)
-            kd = _paged_view(ck, block_tables)
-            vd = _paged_view(cv, block_tables)
-            entry = {"k": ck, "v": cv}
+        entry = {n: _paged_write(cache_s[n], i, r[:, 0], pos, block_tables,
+                                 active) for n, r in rows.items()}
+        view = {n: _paged_view(c, i, block_tables)
+                for n, c in entry.items()}
         lens = pos + 1 if ragged else jnp.full((b,), pos + 1, jnp.int32)
     else:
-        if int8_kv:
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache_s["k"], kq, pos, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache_s["v"], vq, pos, axis=1)
-            cks = jax.lax.dynamic_update_slice_in_dim(
-                cache_s["k_scale"], ks, pos, axis=1)
-            cvs = jax.lax.dynamic_update_slice_in_dim(
-                cache_s["v_scale"], vs, pos, axis=1)
-            kd = _dequantize_kv(ck, cks)
-            vd = _dequantize_kv(cv, cvs)
-            entry = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache_s["k"], k.astype(cache_s["k"].dtype), pos, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache_s["v"], v.astype(cache_s["v"].dtype), pos, axis=1)
-            kd, vd = ck, cv
-            entry = {"k": ck, "v": cv}
+        entry = {n: jax.lax.dynamic_update_slice(
+                     cache_s[n], r[None].astype(cache_s[n].dtype),
+                     (i, 0, pos) + (0,) * (r.ndim - 2))
+                 for n, r in rows.items()}
+        view = {n: jax.lax.dynamic_index_in_dim(c, i, keepdims=False)
+                for n, c in entry.items()}
         lens = jnp.full((b,), pos + 1, jnp.int32)
+    if int8_kv:
+        kd = _dequantize_kv(view["k"], view["k_scale"])
+        vd = _dequantize_kv(view["v"], view["v_scale"])
+    else:
+        kd, vd = view["k"], view["v"]
     o = decode_attend(q, kd, vd, lens, window=cfg.sliding_window,
                       grouped=rc.gqa_einsum)
     return o, entry
@@ -520,44 +497,56 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
         x = x.astype(dtype_of(cfg.compute_dtype))
     nh, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim()
 
-    def period_body(x, scanned):
-        period_params, period_cache = scanned
-        new_cache = []
+    # The stacked cache rides in the scan's carry and each layer reads and
+    # writes its own index of it in place.  Donation aliases the
+    # program's input and output, not a scan's xs and ys: passed as those,
+    # the cache is copied per layer and again whole after the loop.
+    def period_body(carry, scanned):
+        x, cache = carry
+        period_params, i = scanned
+        cache = list(cache)
         for si, slot in enumerate(slots):
-            sp, cache_s = period_params[si], period_cache[si]
+            sp, cache_s = period_params[si], cache[si]
             h = rmsnorm(sp["norm1"], x, cfg.rmsnorm_eps)
             if slot.mixer == "mamba":
-                y, (st, cv) = mamba_apply(
-                    sp["mamba"], h, cfg, state=cache_s["state"],
-                    conv_carry=cache_s["conv"], decode=True, plan=plan)
-                new_cache.append(
-                    {"state": _mask_rows(st, cache_s["state"], active),
-                     "conv": _mask_rows(cv, cache_s["conv"], active)})
+                old = {n: jax.lax.dynamic_index_in_dim(c, i, keepdims=False)
+                       for n, c in cache_s.items()}
+                y, new = mamba_apply(
+                    sp["mamba"], h, cfg, state=old["state"],
+                    conv_carry=old["conv"], decode=True, plan=plan)
+                cache[si] = {
+                    n: jax.lax.dynamic_update_index_in_dim(
+                        cache_s[n],
+                        _mask_rows(t, old[n], active).astype(old[n].dtype),
+                        i, 0)
+                    for n, t in zip(("state", "conv"), new)}
             elif slot.mixer == "cross":
                 q = _cross_q_proj(sp, h, b, 1, nh, dh, plan)
+                kx, vx = (jax.lax.dynamic_index_in_dim(cache_s[n], i,
+                                                       keepdims=False)
+                          for n in ("k", "v"))
                 with jax.named_scope("attn_core"):
-                    o = decode_attend(
-                        q, cache_s["k"], cache_s["v"],
-                        jnp.full((b,), cache_s["k"].shape[1], jnp.int32))
+                    o = decode_attend(q, kx, vx,
+                                      jnp.full((b,), kx.shape[1], jnp.int32))
                 y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh),
                                   plan, label="xattn-out")
-                new_cache.append(cache_s)
             else:
                 q, k, v = qkv_proj(sp["attn"], h, nh, kvh, dh, plan)
                 with jax.named_scope("attn_core"):
-                    o, entry = _decode_attention(q, k, v, cache_s, pos, cfg,
-                                                 rc, active, block_tables)
-                new_cache.append(entry)
+                    o, cache[si] = _decode_attention(
+                        q, k, v, cache_s, i, pos, cfg, rc, active,
+                        block_tables)
                 y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh),
                                   plan)
             x = x + y
             x, _ = _apply_ffn(slot, sp, x, cfg, plan)
-        return x, new_cache
+        return (x, cache), None
 
-    # scan over periods, threading per-period cache slices
+    np_ = n_periods(cfg)
     with jax.named_scope("layer_scan"):
-        x, new_caches = jax.lax.scan(
-            period_body, x, (params["slots"], cache),
-            unroll=max(1, min(rc.scan_unroll, n_periods(cfg))))
+        (x, cache), _ = jax.lax.scan(
+            period_body, (x, list(cache)),
+            (params["slots"], jnp.arange(np_, dtype=jnp.int32)),
+            unroll=max(1, min(rc.scan_unroll, np_)))
     x = rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
-    return _lm_logits(params, x, cfg, plan), new_caches
+    return _lm_logits(params, x, cfg, plan), cache

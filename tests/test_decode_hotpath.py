@@ -10,6 +10,8 @@ engine must produce token streams EXACTLY equal to the synchronous
 engine; donation must demonstrably update the cache pools in place; and
 autotuned GEMM blocks must always be legal (divisible, VMEM-fitting).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -373,3 +375,163 @@ def test_sweep_block_rows_ladder():
     assert sweep_block_rows(5000, n_fields, n_out) >= 5000  # single step
     assert sweep_block_rows(10 ** 6, n_fields, n_out,
                             vmem=1) == SWEEP_ROW_LADDER[0]
+
+
+# --- the layer scan carries the stacked cache --------------------------------
+
+# The batch step is one program, the layer loop runs op by op: float32
+# sums may be reassociated (a few ulps), and where such a difference
+# straddles a rounding edge a bfloat16 cache entry moves by one ulp
+# (2**-8 of its magnitude) and an int8 one by one quantization step.
+LOOP_TOL = {"float32": dict(rtol=1e-5, atol=1e-5),
+            "bfloat16": dict(rtol=2 ** -7, atol=1e-5),
+            "int8": dict(rtol=0, atol=1)}
+
+
+def _loop_step(cfg, rc, params, cache, tokens, pos, active, tables):
+    """A plain Python loop over the layers: each layer's weights and
+    cache taken with `unstack_tree`, every cache write a row-by-row
+    `.at[].set` on that layer's cache by host indices (active slots
+    only), and the layer's cache put back into the stack."""
+    from repro.models.attention import decode_attend
+    from repro.models.layers import (apply_rope, attn_out_proj, dtype_of,
+                                     qkv_proj, rmsnorm, unstack_tree)
+    from repro.models.mamba2 import mamba_apply
+    from repro.models.model import (_apply_ffn, _cross_q_proj,
+                                    _dequantize_kv, _lm_logits,
+                                    _quantize_kv, n_periods, period_slots)
+    nh, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim()
+    b = tokens.shape[0]
+    live = [s for s in range(b) if active[s]]
+    x = params["embed"][tokens].astype(dtype_of(cfg.compute_dtype))
+    out = [dict(c) for c in cache]
+    for i in range(n_periods(cfg)):
+        for si, slot in enumerate(period_slots(cfg)):
+            sp = unstack_tree(params["slots"][si], i)
+            layer = unstack_tree(cache[si], i)
+            h = rmsnorm(sp["norm1"], x, cfg.rmsnorm_eps)
+            if slot.mixer == "mamba":
+                y, (st, cv) = mamba_apply(
+                    sp["mamba"], h, cfg, state=layer["state"],
+                    conv_carry=layer["conv"], decode=True)
+                for n, new in (("state", st), ("conv", cv)):
+                    for s in live:
+                        layer[n] = layer[n].at[s].set(
+                            new[s].astype(layer[n].dtype))
+            elif slot.mixer == "cross":
+                q = _cross_q_proj(sp, h, b, 1, nh, dh)
+                o = decode_attend(q, layer["k"], layer["v"],
+                                  jnp.full((b,), layer["k"].shape[1]))
+                y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh),
+                                  label="xattn-out")
+            else:
+                q, k, v = qkv_proj(sp["attn"], h, nh, kvh, dh)
+                q = apply_rope(q, jnp.asarray(pos)[:, None], cfg.rope_theta)
+                k = apply_rope(k, jnp.asarray(pos)[:, None], cfg.rope_theta)
+                if "k_scale" in layer:
+                    (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)
+                    rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+                else:
+                    rows = {"k": k, "v": v}
+                bs = layer["k"].shape[1]
+                for n, r in rows.items():
+                    for s in live:
+                        layer[n] = layer[n].at[
+                            tables[s, pos[s] // bs], pos[s] % bs].set(
+                                r[s, 0].astype(layer[n].dtype))
+                strip = {n: layer[n][tables].reshape(
+                    (b, -1) + layer[n].shape[2:]) for n in layer}
+                kd, vd = strip["k"], strip["v"]
+                if "k_scale" in layer:
+                    kd = _dequantize_kv(kd, strip["k_scale"])
+                    vd = _dequantize_kv(vd, strip["v_scale"])
+                o = decode_attend(q, kd, vd, jnp.asarray(pos) + 1)
+                y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh))
+            for n in layer:
+                out[si][n] = out[si][n].at[i].set(layer[n])
+            x = x + y
+            x, _ = _apply_ffn(slot, sp, x, cfg)
+    x = rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
+    return _lm_logits(params, x, cfg), out
+
+
+@pytest.mark.parametrize("arch,kv", [
+    ("mamba2-780m", "bfloat16"),
+    ("mistral-nemo-12b", "bfloat16"),
+    ("mistral-nemo-12b", "int8"),
+    ("jamba-1.5-large-398b", "bfloat16"),
+    ("llama-3.2-vision-90b", "bfloat16"),
+])
+def test_batch_step_updates_stacked_cache_like_a_layer_loop(arch, kv):
+    """The batch step, with the stacked cache carried through its layer
+    scan and written in place, against a plain loop over the layers, for
+    every mixer kind (SSM state, paged KV in bf16 and int8, hybrid
+    periods, read-only cross-attention KV): the same logits and cache
+    over several steps of ragged positions and a changing active mask.
+    With and without donation the results are bit-exact, and inactive
+    slots' state rows and every KV block no active slot writes keep
+    their bits."""
+    from repro.models import init_paged_cache
+    cfg = dataclasses.replace(reduced(ARCHS[arch]), param_dtype="float32",
+                              compute_dtype="float32")
+    rc = RunConfig(remat=False, attn_impl="naive", kv_cache_dtype=kv)
+    params = init(jax.random.PRNGKey(0), cfg)
+    b, bs, max_blocks = 4, 4, 4
+    n_blocks = b * max_blocks + 2
+    n_img = (cfg.vision.n_image_tokens if cfg.vision else 0)
+    shapes = jax.eval_shape(lambda: init_paged_cache(
+        cfg, rc, b, n_blocks, bs, n_image_tokens=n_img))
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jax.random.randint(next(keys), a.shape, -127, 128,
+                                      jnp.int8)
+        return jax.random.normal(next(keys), a.shape).astype(a.dtype)
+    cache0 = jax.tree.map(fill, shapes)
+    rng = np.random.default_rng(2)
+    tables = rng.permutation(n_blocks)[:b * max_blocks].reshape(
+        b, max_blocks).astype(np.int32)
+    pos = np.array([3, 9, 0, 13], np.int32)
+    masks = [np.array(m) for m in ([1, 1, 0, 1], [1, 0, 1, 1],
+                                   [0, 1, 1, 0])]
+    cores = {d: DecodeCore(cfg, rc, params, donate=d) for d in (True, False)}
+    caches = {d: jax.tree.map(jnp.copy, cache0) for d in cores}
+    ref = cache0
+    for step, active in enumerate(masks):
+        active = active.astype(bool)
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab, (b, 1)), jnp.int32)
+        logits = {}
+        for d, core in cores.items():
+            logits[d], caches[d] = core.batch_step(
+                params, caches[d], tokens, jnp.asarray(pos),
+                jnp.asarray(active), jnp.asarray(tables))
+        want, new_ref = _loop_step(cfg, rc, params, ref, tokens, pos,
+                                   active, tables)
+        np.testing.assert_array_equal(np.asarray(logits[True]),
+                                      np.asarray(logits[False]))
+        for got, exp in zip(jax.tree.leaves(caches[True]),
+                            jax.tree.leaves(caches[False])):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+        np.testing.assert_allclose(np.asarray(logits[False]),
+                                   np.asarray(want), **LOOP_TOL["float32"])
+        written = {int(tables[s, pos[s] // bs]) for s in range(b)
+                   if active[s]}
+        for si, entry in enumerate(caches[False]):
+            for n, got in entry.items():
+                got, old = np.asarray(got), np.asarray(ref[si][n])
+                np.testing.assert_allclose(
+                    got.astype(np.float32),
+                    np.asarray(new_ref[si][n]).astype(np.float32),
+                    err_msg=f"{si} {n}", **LOOP_TOL[got.dtype.name])
+                if n in ("state", "conv"):
+                    np.testing.assert_array_equal(got[:, ~active],
+                                                  old[:, ~active])
+                elif got.shape[1] == n_blocks:
+                    kept = [k for k in range(n_blocks) if k not in written]
+                    np.testing.assert_array_equal(got[:, kept],
+                                                  old[:, kept])
+                else:
+                    np.testing.assert_array_equal(got, old)
+        ref = caches[False]
+        pos = np.where(active, pos + 1, pos)

@@ -5,14 +5,17 @@ host and the TPU compiler (Mosaic for Pallas) compiles for one of its
 chips.  That refuses what interpret mode accepts — block shapes off the
 (8, 128) tiling, operand layouts Mosaic cannot match, more VMEM than a
 kernel may claim — so these tests guard the kernels a "use CiM" verdict
-routes mamba2-780m's full-width projections to, and the fused planner
-sweep kernel, at no chip time.  Nothing runs; results are checked on
+routes mamba2-780m's full-width projections to, the fused planner
+sweep kernel, and the in-place cache update of the donating batch step,
+at no chip time.  Nothing runs; results are checked on
 the chip (chip_smoke.py).
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +23,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import ARCHS, SHAPES
+from repro.configs import ARCHS, SHAPES, RunConfig
 from repro.core.llm_workloads import gemms_of_model, is_projection_label
 from repro.kernels.autotune import int8_gemm_blocks, int8_gemm_vmem_bytes
 from repro.kernels.int8_gemm import int8_gemm
@@ -159,3 +162,55 @@ def test_int8_gemm_ragged_and_padded_blocks_match_reference():
             # accumulation order alone moves them by ~1e-6 of max|y|
             np.testing.assert_allclose(
                 got, want, rtol=0, atol=1e-5 * np.abs(want).max())
+
+
+_COPY = re.compile(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(")
+
+
+@pytest.mark.parametrize("arch,n_layers,slots,max_len", [
+    ("mamba2-780m", 4, 64, 1536),
+    # NeMo's pipeline-stage depth: its attention alone needs ~1.3 GiB of
+    # temp (the gathered strip repeated to 32 heads in f32), more than a
+    # 2-layer cache holds
+    ("mistral-nemo-12b", 10, 32, 2048),
+])
+def test_batch_step_updates_stacked_cache_in_place(one_chip, arch, n_layers,
+                                                   slots, max_len):
+    """The donating continuous-batching step at full width writes each
+    layer's rows into the stacked cache it carries through the layer
+    scan: the compiled program copies no stacked cache leaf and no
+    layer's slice of one, and needs less temp than the cache holds
+    (a scan over the cache as xs/ys copies every layer's slice out and
+    back and the whole stack after the loop)."""
+    from repro.models import init, init_paged_cache
+    from repro.serving import DecodeCore
+    cfg = dataclasses.replace(ARCHS[arch], n_layers=n_layers)
+    rc = RunConfig(attn_impl="naive", remat=False)
+    block = 16
+    params = jax.eval_shape(lambda: init(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: init_paged_cache(
+        cfg, rc, slots, slots * max_len // block, block))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    step = DecodeCore(cfg, rc, params, donate=True).batch_step
+    compiled = step.lower(
+        on_chip(params), on_chip(cache),
+        *on_chip((jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+                  jax.ShapeDtypeStruct((slots,), jnp.int32),
+                  jax.ShapeDtypeStruct((slots,), jnp.bool_),
+                  jax.ShapeDtypeStruct((slots, max_len // block),
+                                       jnp.int32)))).compile()
+    leaves = jax.tree.leaves(cache)
+    cache_shapes = set()
+    for a in leaves:
+        cache_shapes |= {a.shape, a.shape[1:], (1,) + a.shape[1:]}
+    copies = [line.strip()[:160]
+              for line in compiled.as_text().splitlines()
+              if (m := _COPY.search(line)) and tuple(
+                  int(d) for d in m.group(1).split(",") if d)
+              in cache_shapes]
+    assert not copies, copies
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes
