@@ -7,6 +7,7 @@ of any architecture (same family & wiring, tiny sizes).
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from typing import Literal
 
 Family = Literal["dense", "moe", "ssm", "hybrid", "vlm", "audio"]
@@ -22,6 +23,16 @@ class MoEConfig:
     every_n_layers: int = 1         # MoE FFN every N layers (1 = all)
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.001
+    # expert parallelism: the layer holds `n_experts` experts, numbered
+    # from `first_expert`, of the `router_experts` the router scores
+    # (0 = n_experts: the layer holds them all)
+    router_experts: int = 0
+    first_expert: int = 0
+
+    @property
+    def n_routed(self) -> int:
+        """The router's width: the model's whole expert count."""
+        return self.router_experts or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +43,10 @@ class SSMConfig:
     expand: int = 2
     chunk: int = 256                # SSD chunk length
     n_groups: int = 1               # B/C groups
+    # True: RMSNorm(y) * g * SiLU(z); False (published Mamba-2 gated
+    # norm): RMSNorm(y * SiLU(z)) * g
+    norm_before_gate: bool = True
+    conv_bias: bool = False         # a per-channel bias on each conv
 
     def d_inner(self, d_model: int) -> int:
         return self.expand * d_model
@@ -79,6 +94,15 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
 
+    def __post_init__(self):
+        # sub-configs given as mappings (a JSON file's groups) become
+        # their dataclasses
+        for name, kind in (("moe", MoEConfig), ("ssm", SSMConfig),
+                           ("vision", VisionConfig), ("audio", AudioConfig)):
+            value = getattr(self, name)
+            if isinstance(value, Mapping):
+                object.__setattr__(self, name, kind(**value))
+
     def head_dim(self) -> int:
         return self.d_head or (self.d_model // max(1, self.n_heads))
 
@@ -113,7 +137,7 @@ class ModelConfig:
                 self.moe.n_experts * 3 * d * self.moe.expert_d_ff
                 + (3 * d * self.moe.shared_d_ff
                    if self.moe.n_shared_experts else 0)
-                + d * self.moe.n_experts)
+                + d * self.moe.n_routed)
             total += dense_layers * 3 * d * self.d_ff
         elif self.family == "ssm":
             pass
@@ -214,8 +238,9 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     kw["n_layers"] = n_layers
     if cfg.moe:
         kw["moe"] = dataclasses.replace(
-            cfg.moe, n_experts=min(cfg.moe.n_experts, 4),
+            cfg.moe, n_experts=min(cfg.moe.n_routed, 4),
             top_k=min(cfg.moe.top_k, 2), expert_d_ff=64,
+            router_experts=0, first_expert=0,
             shared_d_ff=64 if cfg.moe.n_shared_experts else 0)
     if cfg.ssm:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=16, headdim=16,

@@ -72,10 +72,29 @@ JAMBA_1_5_LARGE_398B = ModelConfig(
     ssm=SSMConfig(d_state=128, headdim=64, expand=2))
 # [arXiv:2403.19887; hf] — Mamba+attn 1:7 interleave, MoE 16e top-2
 
+GRANITE_4_0_H_SMALL = ModelConfig(
+    name="granite-4.0-h-small", family="hybrid", n_layers=40, d_model=4096,
+    n_heads=32, n_kv_heads=8, d_ff=0, vocab=100352, d_head=128,
+    rope_theta=0.0,         # position_embedding_type "nope"
+    rmsnorm_eps=1e-5, tie_embeddings=True,
+    attn_every=10,          # attention at 5, 15, 25, 35: the period's middle
+    # every layer's FFN is the MoE (no dense FFN: d_ff 0).  The published
+    # model drops no token: a capacity factor >= 72 / 10 makes an expert's
+    # capacity at least the token count
+    moe=MoEConfig(n_experts=72, top_k=10, n_shared_experts=1,
+                  expert_d_ff=768, shared_d_ff=1536, every_n_layers=1,
+                  capacity_factor=8.0),
+    ssm=SSMConfig(d_state=128, d_conv=4, headdim=64, expand=2, chunk=256,
+                  n_groups=1, norm_before_gate=False, conv_bias=True))
+# [hf:ibm-granite/granite-4.0-h-small] — 32B-A9B, Mamba-2 + NoPE GQA 9:1,
+# 72 experts top-10 + 1 shared in every layer.  Its scalar multipliers
+# (embedding 12, residual 0.22, attention 1/128, logits 1/16) are folded
+# into the weights: granite_4_0_h_small.MULTIPLIERS, model.fold_multipliers
+
 ARCHS: dict[str, ModelConfig] = {c.name: c for c in (
     QWEN2_7B, QWEN1_5_32B, MISTRAL_NEMO_12B, MINITRON_4B, MUSICGEN_LARGE,
     QWEN2_MOE_A2_7B, LLAMA4_SCOUT_17B_A16E, MAMBA2_780M,
-    LLAMA3_2_VISION_90B, JAMBA_1_5_LARGE_398B)}
+    LLAMA3_2_VISION_90B, JAMBA_1_5_LARGE_398B, GRANITE_4_0_H_SMALL)}
 
 
 def get(name: str) -> ModelConfig:

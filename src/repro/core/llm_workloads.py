@@ -66,8 +66,11 @@ def gemms_of_model(cfg: ModelConfig, shape: ShapeConfig) -> list[GEMM]:
         moe_layers = cfg.n_layers // cfg.moe.every_n_layers
         dense_layers = (cfg.n_layers - moe_layers
                         if cfg.family == "hybrid" else 0)
+        # the router spreads each token's top_k choices over all n_routed
+        # experts; this model (or its expert-parallel share) computes the
+        # n_experts it holds
         tokens = M
-        per_expert_m = max(1, tokens * cfg.moe.top_k // cfg.moe.n_experts)
+        per_expert_m = max(1, tokens * cfg.moe.top_k // cfg.moe.n_routed)
         for nm, wn, wk in (("gate", cfg.moe.expert_d_ff, d),
                            ("up", cfg.moe.expert_d_ff, d),
                            ("down", d, cfg.moe.expert_d_ff)):

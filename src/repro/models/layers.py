@@ -64,6 +64,13 @@ FLOAT_ROUTE = "xla"
 SCOPES = ("embed", "norm", "ffn_act", "attn_core", "ssd", "cache_mask",
           "lm_head", "layer_scan")
 PROJ_SCOPE = "proj"
+# Scopes inside the expert layer (models/moe.py), apart from SCOPES, the
+# list that the benchmark's trace reduction (bench/lib/scopes.py) mirrors:
+#   moe_router   router logits, softmax, top-k, the held-expert mask
+#   moe_experts  the held experts (their projections in "proj/...") and
+#                the gated sum of their outputs
+#   moe_shared   the shared expert (its projections and ffn_act inside)
+MOE_SCOPES = ("moe_router", "moe_experts", "moe_shared")
 
 
 @contextlib.contextmanager

@@ -115,7 +115,7 @@ def mamba_init(key, cfg: ModelConfig, dtype):
     nh = s.n_ssm_heads(d)
     gdim = s.n_groups * s.d_state
     ks = jax.random.split(key, 6)
-    return {
+    p = {
         "w_z": dense_init(ks[0], d, di, dtype),
         "w_x": dense_init(ks[1], d, di, dtype),
         "w_B": dense_init(ks[2], d, gdim, dtype),
@@ -131,10 +131,16 @@ def mamba_init(key, cfg: ModelConfig, dtype):
         "norm_scale": jnp.ones((di,), dtype),
         "out_proj": dense_init(ks[0], di, d, dtype),
     }
+    if s.conv_bias:
+        p.update(conv_x_bias=jnp.zeros((di,), dtype),
+                 conv_B_bias=jnp.zeros((gdim,), dtype),
+                 conv_C_bias=jnp.zeros((gdim,), dtype))
+    return p
 
 
-def _causal_conv(xBC, w, carry=None):
-    """Depthwise causal conv over (b, l, c) with kernel (k, c).
+def _causal_conv(xBC, w, carry=None, bias=None):
+    """Depthwise causal conv over (b, l, c) with kernel (k, c) and an
+    optional per-channel bias (c,), then SiLU.
 
     carry: (b, k-1, c) previous context (decode) or None (train: zero pad).
     Returns (y, new_carry)."""
@@ -145,6 +151,8 @@ def _causal_conv(xBC, w, carry=None):
     xp = jnp.concatenate([pad, xBC], axis=1)
     # sum_k w[k] * x[t - (K-1) + k]
     y = sum(xp[:, i:i + l, :] * w[i] for i in range(k))
+    if bias is not None:
+        y = y + bias
     new_carry = xp[:, -(k - 1):, :] if k > 1 else None
     return jax.nn.silu(y), new_carry
 
@@ -177,9 +185,12 @@ def mamba_apply(params, x, cfg: ModelConfig, state=None, conv_carry=None,
                           conv_carry[..., di + gdim:])
         else:
             cx = cB = cC = None
-        xs, nx = _causal_conv(xs, params["conv_x"], cx)
-        B, nB = _causal_conv(B, params["conv_B"], cB)
-        C, nC = _causal_conv(C, params["conv_C"], cC)
+        xs, nx = _causal_conv(xs, params["conv_x"], cx,
+                              params.get("conv_x_bias"))
+        B, nB = _causal_conv(B, params["conv_B"], cB,
+                             params.get("conv_B_bias"))
+        C, nC = _causal_conv(C, params["conv_C"], cC,
+                             params.get("conv_C_bias"))
         new_conv = (jnp.concatenate([nx, nB, nC], axis=-1)
                     if nx is not None else None)
         p = s.headdim
@@ -197,12 +208,16 @@ def mamba_apply(params, x, cfg: ModelConfig, state=None, conv_carry=None,
                                        init_state=state)
         y = y + xh * params["D"][None, None, :, None]
         y = y.reshape(b, l, di)
-        # gated RMSNorm (mamba2 norm-before-gate)
+        # gated RMSNorm: RMSNorm(y) * g * SiLU(z) (norm before the gate),
+        # or the published RMSNorm(y * SiLU(z)) * g
         yf = y.astype(jnp.float32)
+        if not s.norm_before_gate:
+            yf = yf * jax.nn.silu(z.astype(jnp.float32))
         yf = yf * jax.lax.rsqrt(jnp.mean(yf ** 2, -1, keepdims=True)
                                 + cfg.rmsnorm_eps)
         y = (yf * params["norm_scale"].astype(jnp.float32)).astype(x.dtype)
-        y = y * jax.nn.silu(z)
+        if s.norm_before_gate:
+            y = y * jax.nn.silu(z)
     return linear(params["out_proj"], y, "ssm-out", plan), \
         (new_state, new_conv)
 

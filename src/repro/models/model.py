@@ -8,8 +8,10 @@ lax.scan over periods (small HLO, fast compile, remat-friendly):
 
   dense / moe / audio : period = [(attn, dense|moe)]
   ssm (mamba2)        : period = [(mamba, None)]
-  hybrid (jamba)      : period = [(attn, ffn0), (mamba, ffn1) x 7],
-                        ffn_i = moe on odd global layer indices
+  hybrid (jamba)      : period = [(mamba, ffn) x 4, (attn, ffn),
+                                  (mamba, ffn) x 3]: attention at index
+                        attn_every // 2, ffn_i = moe where
+                        i % every_n_layers == every_n_layers - 1
   vlm (llama3.2-v)    : period = [(attn, dense) x 4, (cross, dense)]
 
 Entry points:
@@ -57,7 +59,9 @@ def period_slots(cfg: ModelConfig) -> list[Slot]:
     if cfg.family == "hybrid":
         slots = []
         for i in range(cfg.attn_every):
-            mixer = "attn" if i == 0 else "mamba"
+            # the published layouts put attention mid-period (Jamba's
+            # attn_layer_offset 4 of 8, Granite-4.0-H's 5 of 10)
+            mixer = "attn" if i == cfg.attn_every // 2 else "mamba"
             ffn = "moe" if (cfg.moe and i % cfg.moe.every_n_layers
                             == cfg.moe.every_n_layers - 1) else "dense"
             slots.append(Slot(mixer, ffn))
@@ -125,6 +129,62 @@ def init(key, cfg: ModelConfig):
     return params
 
 
+def _times(w, c: float):
+    """A weight times a scalar: a quantized {"q", "scale"} leaf through
+    its per-output-channel scale, a float leaf in float32."""
+    if isinstance(w, dict):
+        return dict(w, scale=w["scale"] * c)
+    return (w.astype(jnp.float32) * c).astype(w.dtype)
+
+
+def fold_multipliers(params, cfg: ModelConfig, *, embedding: float,
+                     residual: float, attention: float, logits: float):
+    """Fold a model's scalar multipliers into the weights they multiply,
+    for a program that has none.  Exact for inference:
+
+      x_0 = embedding * E[t]           -> E <- embedding * E
+      x <- x + residual * mixer(x)     -> wo, out_proj <- residual * .
+      x <- x + residual * ffn(x)       -> every w_down <- residual * .
+      softmax(attention * q k^T)       -> wq <- attention * sqrt(d_head) * .
+                                          (the program scales by
+                                          1 / sqrt(d_head))
+      logits = head(x_L) / logits      -> final norm gain / logits, and
+                                          / embedding when the head is
+                                          tied to the scaled table
+
+    Takes the float tree of `init` or the quantized one (the scales of
+    a quantized projection take the factor)."""
+    out = dict(params)
+    out["embed"] = _times(params["embed"], embedding)
+    head = logits * (embedding if cfg.tie_embeddings else 1.0)
+    out["final_norm"] = {"scale": _times(params["final_norm"]["scale"],
+                                         1.0 / head)}
+    q_factor = attention * cfg.head_dim() ** 0.5
+
+    def slot(sp):
+        sp = dict(sp)
+        if "attn" in sp:
+            a = dict(sp["attn"], wq=_times(sp["attn"]["wq"], q_factor),
+                     wo=_times(sp["attn"]["wo"], residual))
+            if "bq" in a:
+                a["bq"] = _times(a["bq"], q_factor)
+            sp["attn"] = a
+        if "mamba" in sp:
+            sp["mamba"] = dict(sp["mamba"], out_proj=_times(
+                sp["mamba"]["out_proj"], residual))
+        for ffn in ("mlp", "moe"):
+            if ffn in sp:
+                sp[ffn] = dict(sp[ffn], w_down=_times(sp[ffn]["w_down"],
+                                                      residual))
+        if "shared" in sp.get("moe", {}):
+            sp["moe"]["shared"] = dict(
+                sp["moe"]["shared"],
+                w_down=_times(sp["moe"]["shared"]["w_down"], residual))
+        return sp
+    out["slots"] = [slot(sp) for sp in params["slots"]]
+    return out
+
+
 # --- forward (train / prefill) --------------------------------------------------
 
 def _cross_q_proj(sp, h, b, l, nh, dh, plan=None):
@@ -170,8 +230,9 @@ def _apply_mixer_full(slot: Slot, sp, x, cfg: ModelConfig, rc: RunConfig,
         return y, ((kimg, vimg) if return_cache else None)
     q, k, v = qkv_proj(sp["attn"], h, nh, kv, dh, plan)
     pos = jnp.arange(x.shape[1])[None, :]
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    if cfg.rope_theta:                  # 0: no positional embedding
+        q = apply_rope(q, pos, cfg.rope_theta)
+        k = apply_rope(k, pos, cfg.rope_theta)
     mode = rc.shard_attn or ("heads" if rc.shard_heads else "")
     if mode:
         # "heads": head-dim TP (GSPMD pads uneven head counts).
@@ -418,7 +479,7 @@ def _mask_rows(new, old, active):
 
 def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
                       rc: RunConfig, active, block_tables):
-    """RoPE, this token's KV write into layer `i` of the stacked cache
+    """RoPE (none when rope_theta is 0), this token's KV write into layer `i` of the stacked cache
     entry and attention over the slot's cache: (o, updated stacked entry).
     Paged when `block_tables` is given: the row is scattered into the
     slot's current block and its logical strip is gathered back;
@@ -427,8 +488,9 @@ def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
     b = q.shape[0]
     ragged = jnp.ndim(pos) == 1
     pvec = pos[:, None] if ragged else jnp.full((b, 1), pos, jnp.int32)
-    q = apply_rope(q, pvec, cfg.rope_theta)
-    k = apply_rope(k, pvec, cfg.rope_theta)
+    if cfg.rope_theta:                  # 0: no positional embedding
+        q = apply_rope(q, pvec, cfg.rope_theta)
+        k = apply_rope(k, pvec, cfg.rope_theta)
     int8_kv = rc.kv_cache_dtype == "int8"
     if int8_kv:
         (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)

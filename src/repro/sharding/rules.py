@@ -61,7 +61,7 @@ def _param_rule(name: str, ndim: int, cfg: ModelConfig, rc: RunConfig,
     if name in ("conv_B", "conv_C"):
         return P(None, None, None)
     # --- vectors ---
-    if name in ("bq", "bk", "bv", "norm_scale"):
+    if name in ("bq", "bk", "bv", "norm_scale", "conv_x_bias"):
         return P(None, tp)
     if name in ("A_log", "dt_bias", "D"):
         return P(None, tp)

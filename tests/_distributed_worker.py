@@ -4,7 +4,7 @@ Each of the N cooperating OS processes runs this module: env-driven
 `launch.distributed.initialize()` (REPRO_COORDINATOR / _NUM_PROCESSES /
 _PROCESS_ID — the exact path a pod launcher uses), a `distributed_engine`
 over the global row mesh with a chunk size forced small enough that the
-golden grid streams through several tiles, then the full 1338-row
+golden grid streams through several tiles, then the full 1542-row
 workload plan.  Every process writes its verdict rows + engine telemetry
 to $WORKER_OUT.<process_index> so the driver can assert (a) bitwise
 verdict equality with tests/golden/planner_verdicts.csv and (b) that all

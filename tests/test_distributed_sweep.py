@@ -10,7 +10,7 @@ The @slow test is the acceptance gate modeled on PR 2's 4-device
 subprocess test: it spawns 2 real OS processes that initialize
 `jax.distributed` over localhost (env-var driven, CPU gloo collectives),
 build ONE global row mesh spanning both processes' devices, and plan the
-full 1338-row golden workload grid through the chunked distributed
+full 1542-row golden workload grid through the chunked distributed
 engine.  Both processes must reproduce tests/golden/planner_verdicts.csv
 bitwise — the same fingerprint the single-process backends are pinned to
 — with the grid forced through >= 2 streaming chunks.
@@ -213,7 +213,7 @@ def test_distributed_engine_matches_golden_fingerprint(tmp_path):
         dist.ENV_COORDINATOR: f"127.0.0.1:{_free_port()}",
         dist.ENV_NUM_PROCESSES: str(nproc),
         "WORKER_OUT": out_base,
-        "WORKER_CHUNK_ROWS": "512",   # 1338-GEMM grid => >= 2 chunks/kind
+        "WORKER_CHUNK_ROWS": "512",   # 1542-GEMM grid => >= 2 chunks/kind
     })
     worker = os.path.join(REPO, "tests", "_distributed_worker.py")
     procs = []
@@ -259,7 +259,7 @@ def test_distributed_engine_matches_golden_fingerprint(tmp_path):
         assert (sum(d["shard_balance"].values())
                 == pay["chunks"]["rows"] + pay["chunks"]["padded_rows"])
         # THE gate: bitwise golden fingerprint, every field of every row
-        assert len(pay["rows"]) == len(golden) == 1338
+        assert len(pay["rows"]) == len(golden) == 1542
         for want, have in zip(golden, pay["rows"]):
             assert want == have, (want, have)
     # SPMD: both hosts computed the identical plan

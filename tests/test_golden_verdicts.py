@@ -39,7 +39,7 @@ PHASE_SEQ_LEN, PHASE_BATCH = 2048, 8
 PRECISIONS = ("int8", "int4", "fp8")
 FIELDS = ("arch", "shape", "precision", "label", "M", "N", "K",
           "best_energy", "best_throughput", "use_cim", "where")
-N_GRID = 1338
+N_GRID = 1542
 
 
 def _grid():

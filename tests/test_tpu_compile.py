@@ -101,7 +101,7 @@ def _decode_projection_shapes():
 
 def test_int8_gemm_compiles_every_decode_projection(one_chip):
     """The serving route's blocks compile for every decode projection
-    of the ten archs (M = 128 and M = 1)."""
+    of the registered archs (M = 128 and M = 1)."""
     for m, n, k in _decode_projection_shapes():
         compiled = _compile_int8_gemm(one_chip, m, n, k, "os")
         assert "tpu_custom_call" in compiled.as_text(), (m, n, k)
@@ -125,7 +125,7 @@ def _all_gemm_shapes():
 
 
 def test_int8_gemm_blocks_tiling_legal_all_archs():
-    """Pure Python: for every GEMM of the ten archs at every shape, the
+    """Pure Python: for every GEMM of the registered archs at every shape, the
     chosen blocks are the full dim or a multiple of the TPU tile (8 on
     the sublane axis M, 128 on the lane axes N and K), divide every dim
     that has such a divisor, and fit the VMEM budget."""
@@ -214,3 +214,56 @@ def test_batch_step_updates_stacked_cache_in_place(one_chip, arch, n_layers,
     assert not copies, copies
     cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes
+
+
+# what JAX reports a v5e chip may hold (16 GiB of HBM less the runtime's)
+V5E_USABLE_BYTES = int(15.75 * 2 ** 30)
+
+
+def test_granite_cell_batch_step_fits_one_chip(one_chip, monkeypatch):
+    """The granite_h_offline cell's batch step as the benchmark serves it
+    (INT8, plan-gated, 64 slots over 1536 positions, 10 layers at the
+    published widths with 9 of 72 experts held) compiles for one v5e
+    with its gated projections on the Pallas kernel, and its arguments
+    and temporaries fit the chip beside the second copy of the SSM state
+    that a slot's admission makes (the eager reset)."""
+    import math
+    import os
+
+    import repro.kernels.ops as ops
+    from repro.models import init_paged_cache
+    from repro.serving import DecodeCore
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from bench.lib.spec import Bench
+    from bench.lib.weights import model_config, served_shapes
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)   # compile Mosaic
+
+    bench = Bench()
+    cell = bench.cell("granite_h_offline")
+    cfg = model_config(bench.config(
+        bench.workload("granite_h_offline")["config"]))
+    slots, bs = cell["slots"], cell["block_size"]
+    blocks = math.ceil(cell["max_len"] / bs)
+    rc = RunConfig(attn_impl="naive", remat=False, kv_cache_dtype="bfloat16")
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(served_shapes(cfg))
+    core = DecodeCore(cfg, rc, params, quantize=True, donate=True,
+                      plan_batch=slots, plan_max_len=cell["max_len"])
+    cache = on_chip(jax.eval_shape(lambda: init_paged_cache(
+        cfg, rc, slots, slots * blocks, bs)))
+    args = on_chip((jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+                    jax.ShapeDtypeStruct((slots,), jnp.int32),
+                    jax.ShapeDtypeStruct((slots,), jnp.bool_),
+                    jax.ShapeDtypeStruct((slots, blocks), jnp.int32)))
+    state = sum(e["state"].size * 4 for e in cache if "state" in e)
+    for table in {core.plan_table, core.prefill_plan_table}:
+        compiled = core.batch_step_for(table).lower(
+            params, cache, *args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        m = compiled.memory_analysis()
+        used = m.argument_size_in_bytes + m.temp_size_in_bytes + state
+        assert used < V5E_USABLE_BYTES, used / 2 ** 30
