@@ -203,7 +203,7 @@ class RunConfig:
     grad_compress: bool = False       # int8 error-feedback all-reduce
     kv_cache_dtype: str = "bfloat16"  # "int8" for quantized cache
     attn_impl: str = "flash_jnp"      # "flash_jnp" | "naive" | "pallas"
-    attn_chunk: int = 1024            # kv chunk for flash_jnp / decode
+    attn_chunk: int = 1024            # kv chunk for flash_jnp
     scan_unroll: int = 0              # layer-scan unroll factor (dry-run:
                                       # XLA counts a while-loop body once,
                                       # so the roofline pass compiles two
@@ -217,7 +217,6 @@ class RunConfig:
                                       # for multi-pod) used by constraints
     shard_loss: bool = False          # constrain logits + sharded-vocab
                                       # masked-sum loss (no fp32 gather)
-    gqa_einsum: bool = False          # grouped-query einsums (no repeat)
     block_causal: bool = False        # triangular-chunk flash attention
     attn_q_chunk: int = 4096          # q-chunk for block-causal
     remat_policy: str = "nothing"     # "nothing" | "dots"

@@ -100,44 +100,38 @@ def flash_jnp(q, k, v, chunk: int = 1024, window: int = 0,
     return out.swapaxes(1, 2).astype(q.dtype)   # (b, sq, H, d)
 
 
-def decode_attend(q, k_cache, v_cache, cache_len, chunk: int = 0,
-                  window: int = 0, grouped: bool = False):
+def decode_attend(q, k_cache, v_cache, cache_len, window: int = 0):
     """Single-token decode attention over a (b, S, KV, d) cache.
 
     cache_len: (b,) valid lengths.  q: (b, 1, H, d).  Linear in S.
 
-    grouped=True uses grouped-query einsums that never materialize the
-    GQA-expanded cache: with a sequence-sharded cache this keeps every
-    large tensor S-sharded, so the only collectives are the tiny partial
-    softmax/output reductions (flash-decoding via GSPMD) — instead of the
-    full-cache all-gather the jnp.repeat formulation forces.
+    Each KV head's strip is contracted with its own group of H // KV
+    query heads (MHA and MQA are groups of 1 and of H).  Both
+    contractions read the cache as stored and accumulate in float32
+    (bf16 x bf16 products are exact in f32): the cache is never repeated
+    to H heads nor copied to f32.  Masking and the softmax run in f32;
+    the probabilities enter the second contraction in the dtype the
+    query and the cache promote to (the cache dtype where they agree, as
+    in `flash_jnp`).  With a sequence-sharded cache every large tensor
+    stays S-sharded, so the only collectives are the small softmax and
+    output reductions.
     """
     b, _, nh, d = q.shape
-    S = k_cache.shape[1]
-    kv = k_cache.shape[2]
+    S, kv = k_cache.shape[1], k_cache.shape[2]
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     pos = jnp.arange(S)[None, :]
     valid = pos < cache_len[:, None]
     if window:
         valid &= pos >= (cache_len[:, None] - window)
-    if grouped:
-        rep = nh // kv
-        qg = q.reshape(b, 1, kv, rep, d).astype(jnp.float32)
-        s = jnp.einsum("bqgrd,bsgd->bgrqs", qg,
-                       k_cache.astype(jnp.float32)) * scale
-        s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bgrqs,bsgd->bqgrd", p,
-                         v_cache.astype(jnp.float32))
-        return out.reshape(b, 1, nh, d).astype(q.dtype)
-    k = _gqa_expand(k_cache, nh)
-    v = _gqa_expand(v_cache, nh)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale      # (b, H, 1, S)
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    qg = q.reshape(b, 1, kv, nh // kv, d)
+    s = jnp.einsum("bqgrd,bsgd->bgrqs", qg, k_cache,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    p = p.astype(jnp.result_type(q, v_cache))
+    out = jnp.einsum("bgrqs,bsgd->bqgrd", p, v_cache,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, 1, nh, d).astype(q.dtype)
 
 
 def flash_block_causal(q, k, v, q_chunk: int = 4096, kv_chunk: int = 1024,

@@ -438,8 +438,10 @@ def _quantize_kv(t):
             scale[..., 0].astype(jnp.bfloat16))
 
 
-def _dequantize_kv(q, scale):
-    return q.astype(jnp.bfloat16) * scale[..., None]
+def _dequantize_kv(q, scale, dtype):
+    """int8 codes times their row's scale, in the query's `dtype`: exact
+    in float32, one rounding in bfloat16."""
+    return q.astype(dtype) * scale[..., None].astype(dtype)
 
 
 def _paged_write(pool, i, new, pos, block_tables, active):
@@ -512,12 +514,11 @@ def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
                 for n, c in entry.items()}
         lens = jnp.full((b,), pos + 1, jnp.int32)
     if int8_kv:
-        kd = _dequantize_kv(view["k"], view["k_scale"])
-        vd = _dequantize_kv(view["v"], view["v_scale"])
+        kd = _dequantize_kv(view["k"], view["k_scale"], q.dtype)
+        vd = _dequantize_kv(view["v"], view["v_scale"], q.dtype)
     else:
         kd, vd = view["k"], view["v"]
-    o = decode_attend(q, kd, vd, lens, window=cfg.sliding_window,
-                      grouped=rc.gqa_einsum)
+    o = decode_attend(q, kd, vd, lens, window=cfg.sliding_window)
     return o, entry
 
 

@@ -443,8 +443,8 @@ def _loop_step(cfg, rc, params, cache, tokens, pos, active, tables):
                     (b, -1) + layer[n].shape[2:]) for n in layer}
                 kd, vd = strip["k"], strip["v"]
                 if "k_scale" in layer:
-                    kd = _dequantize_kv(kd, strip["k_scale"])
-                    vd = _dequantize_kv(vd, strip["v_scale"])
+                    kd = _dequantize_kv(kd, strip["k_scale"], q.dtype)
+                    vd = _dequantize_kv(vd, strip["v_scale"], q.dtype)
                 o = decode_attend(q, kd, vd, jnp.asarray(pos) + 1)
                 y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh))
             for n in layer:
