@@ -13,7 +13,6 @@ import time
 from . import paper_benches as P
 from . import llm_planner_bench as L
 from . import sweep_bench as S
-from . import serve_gating_bench as G
 from . import campaign_bench as C
 from repro.launch.compile_cache import configure_compile_cache
 
@@ -28,7 +27,6 @@ BENCHES = [
     ("llm_planner_decisions", L.planner_decisions),
     ("planner_sweep_speed", S.planner_sweep_speed),
     ("campaign_speed", C.campaign_speed),
-    ("serve_gating_speed", G.serve_gating_speed),
 ]
 
 

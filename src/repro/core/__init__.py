@@ -18,7 +18,6 @@ from .pareto import (ParetoAccumulator, dominates, pareto_mask,
                      pareto_mask_np)
 from .memory import (DRAM, LEVELS, RF, SMEM, CiMSystemConfig, configb_count,
                      iso_area_primitive_count)
-from .plan_service import BucketLattice, PlanService
 from .planner import (Decision, decide, make_decision, plan_workload,
                       plan_workload_by_phase, standard_configs, summarize)
 from .sweep import (SweepEngine, decide_batched, plan_workload_batched,
@@ -47,7 +46,6 @@ __all__ = [
     "evaluate_batch", "exhaustive_best", "make_decision",
     "SweepEngine", "decide_batched", "plan_workload_batched",
     "sweep_evaluate", "sweep_evaluate_baseline",
-    "BucketLattice", "PlanService",
     "CampaignSpec", "CampaignResult", "Constraint", "build_config",
     "run_campaign", "certify_point", "certify_front", "parse_precision",
     "ParetoAccumulator", "dominates", "pareto_mask", "pareto_mask_np",

@@ -148,113 +148,6 @@ def shard_balance_table(cells: list[dict]) -> str:
             else "(no distributed sweep telemetry in these cells)")
 
 
-def serve_traffic_table(bench: dict) -> str:
-    """Throughput-vs-latency rows from BENCH_serve.json's `traffic`
-    block (the continuous-batching open-loop bench): one row per
-    arrival rate, TTFT percentiles against engine tokens/s, plus the
-    scheduler health columns (queue depth, slot occupancy, evictions).
-    The fixed-batch reference row anchors the curves against the legacy
-    lockstep session on the same core."""
-    t = bench.get("traffic")
-    if not t:
-        return "(no traffic block in BENCH_serve.json — run " \
-               "benchmarks.serve_traffic_bench)"
-    lines = [f"arch={t['arch']} slots={t['n_slots']} "
-             f"block_size={t['block_size']} "
-             f"requests/rate={t['requests_per_rate']} seed={t['seed']}",
-             "",
-             "| arrival req/s | TTFT p50 | TTFT p95 | engine tok/s | "
-             "req tok/s | occupancy | queue depth | evict |",
-             "|---|---|---|---|---|---|---|---|"]
-    for c in t.get("curves", []):
-        lines.append(
-            f"| {c['arrival_rate_req_per_s']:g} | "
-            f"{fmt_s(c['ttft_p50_s'])} | {fmt_s(c['ttft_p95_s'])} | "
-            f"{c['engine_tokens_per_s']:.1f} | "
-            f"{c['request_tokens_per_s_mean']:.1f} | "
-            f"{c['slot_occupancy_mean']:.2f} | "
-            f"{c['queue_depth_mean']:.2f} | {c['evictions']} |")
-    ref = t.get("fixed_batch_reference_tokens_per_s")
-    if ref is not None:
-        lines.append(f"\nfixed-batch reference (legacy lockstep, "
-                     f"batch={t['n_slots']}): {ref:.1f} tok/s")
-    return "\n".join(lines)
-
-
-def serve_step_breakdown_table(bench: dict) -> str:
-    """Decode hot-path health from the `traffic` block's per-rate
-    `decode_step_breakdown`: where each step's host budget went
-    (device dispatch vs blocking host fetch vs telemetry sampling),
-    whether the loop ran pipelined (host fetch of step t overlapped
-    with step t+1's compute), and whether KV-cache buffer donation took
-    effect (no per-token pool copy; "off" = donation disabled, the CPU
-    default)."""
-    t = bench.get("traffic")
-    curves = (t or {}).get("curves", [])
-    if not any("decode_step_breakdown" in c for c in curves):
-        return "(no decode_step_breakdown in BENCH_serve.json traffic " \
-               "curves — regenerate with benchmarks.serve_traffic_bench)"
-    lines = ["| arrival req/s | steps | pipelined | donation | "
-             "dispatch/step | fetch/step | telemetry/step |",
-             "|---|---|---|---|---|---|---|"]
-    for c in curves:
-        b = c.get("decode_step_breakdown")
-        if not b:
-            continue
-        don = c.get("kv_donation_ok")
-        lines.append(
-            f"| {c['arrival_rate_req_per_s']:g} | {b['steps']} | "
-            f"{'yes' if b['pipelined'] else 'no'} | "
-            f"{'ok' if don else ('off' if don is None else 'FAIL')} | "
-            f"{b['dispatch_ms_per_step']:.2f}ms | "
-            f"{b['host_fetch_ms_per_step']:.2f}ms | "
-            f"{b['telemetry_ms_per_step']:.2f}ms |")
-    return "\n".join(lines)
-
-
-def serve_adaptive_table(bench: dict) -> str:
-    """Adaptive-planning rows from BENCH_serve.json's `adaptive` block
-    (benchmarks.serve_adaptive_bench): adaptive vs frozen-plan engine
-    throughput, the hot-swap counters of the forced-flip scenario, and
-    the per-bucket hit/build/flip table of the plan service."""
-    a = bench.get("adaptive")
-    if not a:
-        return "(no adaptive block in BENCH_serve.json — run " \
-               "benchmarks.serve_adaptive_bench)"
-    lines = [f"arch={a['arch']} slots={a['n_slots']} "
-             f"requests={a['requests']} seed={a['seed']}",
-             "",
-             "| mode | engine tok/s | plan swaps | verdict flips | "
-             "executables | swap mean | swap max |",
-             "|---|---|---|---|---|---|---|"]
-    for mode in ("no_flip", "forced_flip"):
-        s = a.get(mode)
-        if not s:
-            continue
-        lat = s.get("swap_latency_s") or {}
-        mean = lat.get("mean")
-        mx = lat.get("max")
-        lines.append(
-            f"| {mode.replace('_', '-')} | "
-            f"{s['engine_tokens_per_s']:.1f} | {s['plan_swaps']} | "
-            f"{s['verdict_flips']} | {s['decode_executables']} | "
-            f"{fmt_s(mean) if mean else '—'} | "
-            f"{fmt_s(mx) if mx else '—'} |")
-    frozen = a.get("frozen_tokens_per_s")
-    if frozen is not None:
-        lines.append(f"\nfrozen-plan reference engine: {frozen:.1f} tok/s")
-    buckets = ((a.get("forced_flip") or {}).get("service") or {}) \
-        .get("buckets") or {}
-    if buckets:
-        lines += ["", "| bucket | hits | misses | builds | flips | "
-                  "plan digest |", "|---|---|---|---|---|---|"]
-        for name, b in buckets.items():
-            lines.append(
-                f"| {name} | {b['hits']} | {b['misses']} | "
-                f"{b['builds']} | {b['flips']} | {b['table_digest']} |")
-    return "\n".join(lines)
-
-
 def campaign_table(report: dict) -> str:
     """Campaign summary from results/campaign/campaign_report.json
     (repro.launch.campaign): grid provenance, constraint accounting,
@@ -332,19 +225,6 @@ if __name__ == "__main__":
     print(planner_cache_table(cells))
     print("\n## Distributed sweeps (per-host cache + shard balance)\n")
     print(shard_balance_table(cells))
-    bench_path = os.environ.get("BENCH_SERVE_OUT", "BENCH_serve.json")
-    if os.path.exists(bench_path):
-        with open(bench_path) as f:
-            bench = json.load(f)
-        print("\n## Serving traffic (continuous batching, "
-              "throughput vs latency)\n")
-        print(serve_traffic_table(bench))
-        print("\n## Decode step breakdown (dispatch vs host fetch vs "
-              "telemetry)\n")
-        print(serve_step_breakdown_table(bench))
-        print("\n## Adaptive planning (bucket hit rates, verdict "
-              "flips, plan swaps)\n")
-        print(serve_adaptive_table(bench))
     campaign_path = os.environ.get("CAMPAIGN_REPORT",
                                    "results/campaign/campaign_report.json")
     if os.path.exists(campaign_path):

@@ -15,16 +15,6 @@ served by the slot-scheduled, paged-KV request engine
 TTFT / queue wait / tokens/s plus engine-level queue depth, slot
 occupancy, KV-block usage and eviction counts.  All defaults are
 documented in --help.
-
---adaptive (traffic mode, implies --quantize) puts the shape-bucketed
-plan service (repro.core.plan_service) beside the engine: every step the
-live (active slots, max position) point is bucketed, the bucket's
-verdicts are served from the sweep LRU, and a verdict change hot-swaps
-the decode plan between compiled variants.  --bucket-edges overrides the
-lattice ("b1,b2,..:l1,l2,.."); --refresh-every N re-plans a bucket in
-the background after every N lookups.  The report gains the engine's
-`adaptive` telemetry block (bucket hit rates, flips, swap latency),
-rendered by launch.report.
 """
 from __future__ import annotations
 
@@ -68,9 +58,8 @@ def steady_decode_tokens_per_s(sessions, prompt, n_tokens: int,
     contention degrades all of them symmetrically: timing back-to-back
     once recorded a 2.7x split between two byte-identical programs.
 
-    The single timing loop shared by the gating benchmark and the
-    traffic benchmark's fixed-batch reference row — tune via their
-    --new-tokens/--repeats/--warmup flags."""
+    The timing loop behind the fixed-batch report's gated and ungated
+    decode tokens/s (`run_fixed_batch`)."""
     if n_tokens < 1:
         raise ValueError(f"n_tokens must be >= 1, got {n_tokens}")
     if repeats < 1:
@@ -101,25 +90,15 @@ def steady_decode_tokens_per_s(sessions, prompt, n_tokens: int,
 
 def run_traffic(cfg, rc, params, args) -> dict:
     """Continuous-batching traffic mode: synthetic open-loop arrivals
-    through the slot-scheduled paged-KV engine (optionally with the
-    shape-bucketed adaptive plan service); returns the serve report
+    through the slot-scheduled paged-KV engine; returns the serve report
     dict."""
-    from ..core.plan_service import BucketLattice, PlanService
-    quantize = args.quantize or args.adaptive
     max_len = args.max_len or (args.prompt_len + args.new_tokens + 1)
-    core = DecodeCore(cfg, rc, params, quantize=quantize,
+    core = DecodeCore(cfg, rc, params, quantize=args.quantize,
                       plan_batch=args.slots, plan_max_len=max_len)
-    service = None
-    if args.adaptive:
-        lattice = (BucketLattice.parse(args.bucket_edges)
-                   if args.bucket_edges
-                   else BucketLattice.for_engine(args.slots, max_len))
-        service = PlanService(cfg, lattice,
-                              refresh_every=args.refresh_every)
     engine = ContinuousBatchingEngine(
         core, n_slots=args.slots, max_len=max_len,
         block_size=args.block_size, n_kv_blocks=args.kv_blocks,
-        seed=args.seed, plan_service=service)
+        seed=args.seed)
     reqs = synthetic_requests(
         cfg, args.requests, seed=args.seed,
         prompt_len=(max(1, args.prompt_len // 2), args.prompt_len),
@@ -128,20 +107,16 @@ def run_traffic(cfg, rc, params, args) -> dict:
     arrivals = poisson_arrivals(args.requests, args.arrival_rate,
                                 seed=args.seed)
     telemetry = engine.run(reqs, arrivals)
-    if service is not None:
-        service.drain()              # settle background refreshes
-        telemetry["adaptive"] = engine._adaptive_telemetry()
     report = {
         "arch": cfg.name,
         "mode": "continuous-batching",
         "requests": args.requests,
         "arrival_rate_req_per_s": args.arrival_rate,
         "seed": args.seed,
-        "adaptive": args.adaptive,
         "traffic": telemetry,
         "planner_cache": core.plan_cache_telemetry,
     }
-    if quantize:
+    if args.quantize:
         routes = core.route_report(args.slots, engine.max_len)
         report["gating"] = {
             "routes": routes,
@@ -199,17 +174,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--max-len", type=int, default=0,
                     help="per-request length cap in traffic mode "
                          "(0 = prompt-len + new-tokens + 1)")
-    ap.add_argument("--adaptive", action="store_true",
-                    help="traffic mode: consult the shape-bucketed plan "
-                         "service each step and hot-swap the decode plan "
-                         "on verdict flips (implies --quantize)")
-    ap.add_argument("--bucket-edges", default="",
-                    help="adaptive bucket lattice as 'b1,b2,..:l1,l2,..' "
-                         "(batch edges : length edges; empty = power-of-"
-                         "two edges over slots x max-len)")
-    ap.add_argument("--refresh-every", type=int, default=0,
-                    help="adaptive: background re-plan a bucket after "
-                         "every N lookups (0 = never refresh)")
     return ap
 
 
@@ -310,8 +274,6 @@ def serve(args) -> dict:
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
-    if args.adaptive and args.requests <= 0:
-        ap.error("--adaptive needs traffic mode (--requests N)")
     configure_compile_cache()
     print(json.dumps(serve(args), indent=1))
 

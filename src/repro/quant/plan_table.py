@@ -15,18 +15,16 @@ stripped — so the table is independent of which config produced it.
 Lookup of a label the planner never saw raises `KeyError` (listing the
 known labels): model-side label drift must not silently disable gating.
 
-Tables are **versioned** by content: `digest` is a stable hash of the
-sorted entries (two tables built from the same decisions in any order
-share it), which is what the adaptive serving layer keys its bounded
-executable cache on and what telemetry reports as the plan version.
-`flips(other)` diffs two versions by their "when" gate, and
-`with_flip(label)` is the forced-flip harness used by the adaptive
-tests/bench.
+A serving core builds one table per phase (decode and prefill) before it
+jits and never changes them; equality and hashing are by content (the
+entries are kept sorted), so `DecodeCore.batch_step_for` keeps one
+compiled step per distinct table.  `flips(other)` diffs two tables by
+their "when" gate — the core compiles a separate prefill program only
+when a projection label flips between the phases.
 """
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 from functools import cached_property
 from typing import Iterable
 
@@ -91,21 +89,9 @@ class KernelPlanTable:
             (lab, dataclasses.replace(e, use_cim=False))
             for lab, e in self.entries))
 
-    # --- versioning -------------------------------------------------------
-
-    @cached_property
-    def digest(self) -> str:
-        """Stable content hash — the table's *version*.  Entries are kept
-        sorted by `from_decisions`, so two tables built from the same
-        decisions in any order share one digest; any verdict change
-        yields a new one.  (Python's built-in hash() is salted per
-        process; this digest is reproducible across runs, so it can live
-        in benchmark artifacts and serve telemetry.)"""
-        return hashlib.sha256(repr(self.entries).encode()).hexdigest()[:12]
-
     def flips(self, other: "KernelPlanTable") -> tuple[str, ...]:
         """Labels whose "when" gate (use_cim) differs between the two
-        versions; a label present in only one table counts as flipped."""
+        tables; a label present in only one table counts as flipped."""
         labels = set(self._index) | set(other._index)
         out = []
         for lab in sorted(labels):
@@ -113,14 +99,3 @@ class KernelPlanTable:
             if a is None or b is None or a.use_cim != b.use_cim:
                 out.append(lab)
         return tuple(out)
-
-    def with_flip(self, label: str) -> "KernelPlanTable":
-        """Copy with one label's gate toggled — the deterministic
-        forced-flip harness for the adaptive-serving tests and bench.
-        Raises the KeyError-with-known-labels contract on unknown
-        labels."""
-        self.entry(label)          # enforce the drift gate
-        return KernelPlanTable(entries=tuple(
-            (lab, dataclasses.replace(e, use_cim=not e.use_cim)
-             if lab == label else e)
-            for lab, e in self.entries))

@@ -8,32 +8,27 @@ The scheduler layer (repro.serving.scheduler) and the legacy fixed-batch
 `ServeSession` (repro.serving.engine) are both thin mutable shells over
 one core — requests join and leave, the core never retraces.
 
-Two kinds of executables live here, each compiled exactly once per plan:
+The plan is fixed when the core is built: one `KernelPlanTable` per
+serving phase (`plan_table` for decode, `prefill_plan_table` for
+prefill; the same table when the phases gate every projection alike).
+Two kinds of executables live here, each compiled exactly once per
+phase table:
 
   * `step(params, cache, tokens, pos)` — the legacy fixed-batch step
     (scalar uniform position), what the dry-run lowers and ServeSession
     drives;
-  * `batch_step(params, cache, tokens, pos, active, block_tables)` — the
-    continuous-batching step: ragged per-slot positions, an active-slot
-    mask, and a paged KV block pool (models.model.init_paged_cache).
-    All four scheduler-side inputs are jit-*dynamic*, so slot churn under
-    live traffic hits the same compiled program every step.
-
-The continuous-batching step is served from a **bounded per-plan
-executable cache** (`batch_step_for(plan)`): each distinct (versioned)
-`KernelPlanTable` gets its own jitted program, LRU-bounded at
-`max_plan_variants`.  That is what lets the adaptive serving layer
-(`repro.serving.scheduler` + `repro.core.plan_service`) hot-swap the
-decode plan when a shape bucket's verdict flips — a flip compiles the
-new variant once, off the critical decode step, and every later step
-under either plan reuses its already-compiled program
-(`batch_decode_executables == number of distinct plans served`).
+  * `batch_step_for(table)(params, cache, tokens, pos, active,
+    block_tables)` — the continuous-batching step: ragged per-slot
+    positions, an active-slot mask, and a paged KV block pool
+    (models.model.init_paged_cache).  All four scheduler-side inputs are
+    jit-*dynamic*, so slot churn under live traffic hits the same
+    compiled program every step (`batch_decode_executables` == the
+    number of distinct phase tables served).
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
-from collections import OrderedDict
 from typing import Any
 
 import jax
@@ -73,12 +68,15 @@ class DecodeCore:
     """Frozen compiled core: params + plan + the jitted decode programs.
 
     quantize=True turns the planner verdicts into the execution policy:
-    projection weights are INT8-quantized at init, the kernel plan is
-    built eagerly (before jitting), and both jitted steps close over the
-    static KernelPlanTable.  gated=False keeps the quantized weights but
-    forces every label onto the standard path — the parity baseline for
-    the gated program (identical numerics source, routing the only
-    difference)."""
+    projection weights are INT8-quantized at init, and both serving
+    phases are planned eagerly (before jitting) into one
+    KernelPlanTable each — `plan_table` (decode) and
+    `prefill_plan_table` — that the jitted steps close over.  The tables
+    never change afterwards; the engine only chooses, per step, which of
+    the two phase programs to run.  gated=False keeps the quantized
+    weights but forces every label onto the standard path — the parity
+    baseline for the gated program (identical numerics source, routing
+    the only difference)."""
     cfg: ModelConfig
     rc: RunConfig
     params: Any
@@ -93,9 +91,6 @@ class DecodeCore:
     # the paper's M=1 pathology; ServeSession passes its own)
     plan_batch: int = 8
     plan_max_len: int = 1024
-    # bound on concurrently-cached jitted batch-step variants (one per
-    # distinct plan table the adaptive layer has served)
-    max_plan_variants: int = 4
     # donate the cache argument of both jitted steps so XLA aliases the
     # KV pools / mamba state into the outputs (in-place update, no
     # per-token copy of the multi-MB cache).  None resolves per
@@ -106,18 +101,14 @@ class DecodeCore:
     donate: bool | None = None
 
     def __post_init__(self):
-        if self.max_plan_variants < 1:
-            raise ValueError(f"max_plan_variants must be >= 1, "
-                             f"got {self.max_plan_variants}")
         self._kernel_plan = None
         self._kernel_plans = None
         self._plan_cache_telemetry = None
         self._plan_lock = threading.Lock()
         self._verdict_table = None
         self._phase_verdict_tables = None
-        self._batch_steps: OrderedDict = OrderedDict()
+        self._batch_steps: dict = {}
         self._exec_lock = threading.Lock()
-        self.plan_evictions = 0
         self.plan_table = None
         self.prefill_plan_table = None
         if self.quantize:
@@ -274,17 +265,16 @@ class DecodeCore:
         return self._prefill_step(self.params, cache, tokens, pos)
 
     def batch_step_for(self, plan):
-        """The continuous-batching executable for one (versioned) plan
-        table: (params, cache, tokens, pos_vec, active, block_tables) ->
+        """The continuous-batching executable for one phase table:
+        (params, cache, tokens, pos_vec, active, block_tables) ->
         (logits, cache).  pos_vec (b,) int32, active (b,) bool and
         block_tables (b, max_blocks) int32 are dynamic — join/evict/
         ragged lengths never retrace.
 
-        Variants are memoized per plan table (the table's hash/equality
-        is its version) in an LRU bounded by `max_plan_variants`: an
-        adaptive engine swapping between plans reuses each variant's
-        single compiled program; a plan evicted from the bound recompiles
-        if it ever returns (`plan_evictions` counts those drops)."""
+        One jitted step per distinct table (keyed on the table's
+        equality and hash), built on first request and kept for the
+        core's life; the engine asks for at most two, the decode and
+        prefill tables."""
         with self._exec_lock:
             fn = self._batch_steps.get(plan)
             if fn is None:
@@ -302,23 +292,13 @@ class DecodeCore:
                 fn = jax.jit(serve_batch_step,
                              donate_argnums=(1,) if self.donate else ())
                 self._batch_steps[plan] = fn
-            self._batch_steps.move_to_end(plan)
-            while len(self._batch_steps) > self.max_plan_variants:
-                self._batch_steps.popitem(last=False)
-                self.plan_evictions += 1
         return fn
 
     @property
     def batch_step(self):
-        """The continuous-batching executable for this core's own frozen
-        plan table (the non-adaptive path) — see `batch_step_for`."""
+        """The continuous-batching executable for the decode-phase table
+        — see `batch_step_for`."""
         return self.batch_step_for(self.plan_table)
-
-    @property
-    def plan_variants(self) -> int:
-        """Distinct plan tables with a live jitted batch-step variant."""
-        with self._exec_lock:
-            return len(self._batch_steps)
 
     @staticmethod
     def _executables(fn) -> int | None:
@@ -341,8 +321,8 @@ class DecodeCore:
         return self._executables(self._prefill_step)
 
     def plan_executables(self, plan) -> int | None:
-        """Programs compiled for one plan table's batch-step variant (0
-        when that variant never ran).  None if the private jax jit-cache
+        """Programs compiled for one phase table's batch step (0 when
+        that step never ran).  None if the private jax jit-cache
         probe is unavailable."""
         with self._exec_lock:
             fn = self._batch_steps.get(plan)
@@ -350,11 +330,10 @@ class DecodeCore:
 
     @property
     def batch_decode_executables(self) -> int | None:
-        """Total programs compiled across every cached batch-step variant
-        — the no-retrace gate: equals 1 for frozen-plan traffic and the
-        number of distinct plan tables for adaptive traffic (each variant
-        compiles exactly once).  None if the private jax jit-cache probe
-        is unavailable."""
+        """Total programs compiled across the batch steps — the
+        no-retrace gate: equals the number of distinct phase tables
+        served (each compiles exactly once).  None if the private jax
+        jit-cache probe is unavailable."""
         with self._exec_lock:
             fns = list(self._batch_steps.values())
         if not fns:

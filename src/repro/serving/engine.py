@@ -72,8 +72,8 @@ def make_prefill(cfg: ModelConfig, rc: RunConfig,
 
 def cim_fraction(routes: dict) -> float:
     """Fraction of traced projection routes that lowered to the CiM
-    INT8 Pallas path (shared by the serve CLI, the dry-run decode cells
-    and the gating benchmark — one definition, three surfaces)."""
+    INT8 Pallas path (shared by the serve CLI's two modes and the dry-run
+    decode cells — one definition, one meaning)."""
     vals = [r["route"] if isinstance(r, dict) else r
             for r in routes.values()]
     return sum(v == CIM_ROUTE for v in vals) / max(1, len(vals))

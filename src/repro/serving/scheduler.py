@@ -39,21 +39,17 @@ server:
     telemetry), opened and closed where the phase's counter reads the
     clock.  With no profiler running, a span costs about a
     microsecond of host time;
-  * **adaptive planning** (optional): an engine given a
-    `repro.core.plan_service.PlanService` consults it every step at the
-    live operating point (active-slot count, deepest position); when the
-    shape bucket's verdict flips, the engine **hot-swaps** the decode
-    plan — the new plan's executable is fetched (compiling at most once,
-    off the critical decode step, via a discarded warm-up call) from
-    `DecodeCore.batch_step_for`'s bounded variant cache, then the step
-    pointer flips.  Bucket transitions, plan swaps and swap latencies
-    land in `telemetry()["adaptive"]`.
+  * **phase-split gating**: the core fixes one plan table per serving
+    phase when it is built; a step whose live slots are all still
+    feeding their prompt runs the prefill table's program, any other
+    step the decode table's.
 
-The scheduler is pure host-side Python around `DecodeCore.batch_step`;
-everything it varies per step (tokens, positions, active mask, block
-tables) is a jit-*dynamic* input, so any traffic pattern hits exactly
-one compiled executable per distinct plan (`decode_executables == 1`
-frozen, `== n_distinct_plans` adaptive).
+The scheduler is pure host-side Python around the core's batch steps
+(`DecodeCore.batch_step_for`); everything it varies per step (tokens,
+positions, active mask, block tables) is a jit-*dynamic* input, so any
+traffic pattern hits exactly one compiled executable per distinct phase
+table (`decode_executables` is 1 when the phases share a table, 2 when
+they differ).
 """
 from __future__ import annotations
 
@@ -204,17 +200,12 @@ class ContinuousBatchingEngine:
     def __init__(self, core: DecodeCore, n_slots: int, max_len: int,
                  block_size: int = 8, n_kv_blocks: int | None = None,
                  seed: int = 0, record_logits: bool = False,
-                 plan_service=None, pipeline: bool = True,
+                 pipeline: bool = True,
                  clock: Callable[[], float] = time.perf_counter):
         if core.cfg.family == "vlm":
             raise NotImplementedError(
                 "continuous batching does not yet thread per-request "
                 "image embeddings through cross-attention slots")
-        if plan_service is not None and core.plan_table is None:
-            raise ValueError(
-                "adaptive planning needs a plan-gated core: build the "
-                "DecodeCore with quantize=True so plan tables route the "
-                "decode step (an unquantized core ignores verdicts)")
         self.core = core
         self.cfg = core.cfg
         self.n_slots = n_slots
@@ -257,24 +248,17 @@ class ContinuousBatchingEngine:
         # holds admission (admit_s) and the samples
         self.admissions = 0
         self.admit_s = 0.0
-        self.plan_s = 0.0
+        self.plan_s = 0.0             # phase-table selection + step fetch
         self.dispatch_s = 0.0
         self.host_fetch_s = 0.0
         self.telemetry_s = 0.0
-        # adaptive planning: current plan + hot-swap telemetry
-        self.plan_service = plan_service
-        self._plan = core.plan_table
-        self._step_fn = None          # resolved lazily / on swap
-        self._bucket: tuple[int, int] | None = None
-        self.bucket_transitions = 0
-        self.plan_swaps = 0
-        self.swap_latencies_s: list[float] = []
-        # phase-split gating (frozen-plan engines only — an attached
-        # plan service owns the plan): a step whose live slots are ALL
-        # still prefilling runs under the prefill-phase table, any
-        # decoding slot makes it a decode-phase step.  Both variants
-        # come from the core's bounded executable cache, so steady
-        # mixed traffic serves from at most two compiled programs.
+        # phase-split gating: a step whose live slots are ALL still
+        # prefilling runs under the prefill-phase table, any decoding
+        # slot makes it a decode-phase step.  Each phase's program comes
+        # from the core's per-table memo, so steady mixed traffic serves
+        # from at most two compiled programs.  An unquantized core has
+        # no prefill table: every step runs the decode program.
+        self._step_fn = None          # resolved on the first step
         self._phase_tables = {"decode": core.plan_table,
                               "prefill": core.prefill_plan_table}
         self._phase = "decode"
@@ -369,61 +353,21 @@ class ContinuousBatchingEngine:
                 toks[i, 0] = 0 if tok is None else tok
         return toks
 
-    def _consult_plan_service(self) -> None:
-        """Ask the plan service for the current operating point's bucket
-        verdicts; hot-swap the decode plan if they differ from the one
-        being served (the swap compiles at most once, off the decode hot
-        path — see `_swap_plan`)."""
-        n_active = self.active_slots
-        max_pos = max(s.pos for s in self.slots if s is not None)
-        bucket, table = self.plan_service.lookup(n_active, max_pos)
-        if bucket != self._bucket:
-            if self._bucket is not None:
-                self.bucket_transitions += 1
-            self._bucket = bucket
-        if table != self._plan:
-            self._swap_plan(table)
-
-    def _swap_plan(self, table) -> None:
-        """Compile-then-swap: fetch the new plan's executable from the
-        core's bounded variant cache and warm it with a discarded
-        all-inactive call (so any compile happens *here*, between steps,
-        never inside the decode hot path), then flip the step pointer.
-        The full fetch+warm latency is recorded as the swap latency —
-        near-zero when the variant is already compiled."""
-        t0 = self.clock()
-        fn = self.core.batch_step_for(table)
-        # the warm call donates self.cache like every step; all lanes
-        # are inactive so the returned cache is contents-identical —
-        # rebind it (the donated input buffers are gone)
-        warm_toks = self._mix_tokens(self._token_batch(),
-                                     np.zeros(self.n_slots, bool))
-        warm_logits, warmed = fn(self.core.params, self.cache, warm_toks,
-                                 np.zeros(self.n_slots, np.int32),
-                                 np.zeros(self.n_slots, bool),
-                                 self.block_tables)
-        jax.block_until_ready(warm_logits)
-        self.cache = warmed
-        self.swap_latencies_s.append(self.clock() - t0)
-        self._plan = table
-        self._step_fn = fn
-        self.plan_swaps += 1
-
     def _select_phase_table(self) -> None:
-        """Per-step phase gating for frozen-plan engines: serve a
-        pure-prefill step (every live slot still feeding its prompt)
-        under the prefill-phase plan table, anything else under the
-        decode table.  A phase flip swaps the step pointer through the
-        core's bounded variant cache — each phase's program compiles at
-        most once, steady traffic never retraces."""
+        """Per-step phase gating: serve a pure-prefill step (every live
+        slot still feeding its prompt) under the prefill-phase plan
+        table, anything else under the decode table.  A phase flip
+        points the step at the other phase's program from the core's
+        per-table memo — each compiles at most once, steady traffic
+        never retraces."""
         live = [s for s in self.slots if s is not None and not s.draining]
         phase = ("prefill" if live and all(s.prefilling for s in live)
                  else "decode")
         if phase != self._phase:
             self._phase = phase
             self.phase_switches += 1
-            self._plan = self._phase_tables[phase]
-            self._step_fn = self.core.batch_step_for(self._plan)
+            self._step_fn = self.core.batch_step_for(
+                self._phase_tables[phase])
         self.phase_steps[phase] += 1
 
     @property
@@ -475,12 +419,11 @@ class ContinuousBatchingEngine:
                 return True
             return False
         with _Span(self, "plan", "plan_s"):
-            if self.plan_service is not None:
-                self._consult_plan_service()
-            elif self._phase_tables["prefill"] is not None:
+            if self._phase_tables["prefill"] is not None:
                 self._select_phase_table()
             if self._step_fn is None:
-                self._step_fn = self.core.batch_step_for(self._plan)
+                self._step_fn = self.core.batch_step_for(
+                    self._phase_tables[self._phase])
         prev = self._inflight
         with _Span(self, "dispatch", "dispatch_s"):
             self._inflight = self._dispatch()
@@ -723,8 +666,7 @@ class ContinuousBatchingEngine:
             "decode_executables": self.decode_executables,
             "kv_donation_ok": self.donation_ok,
             "phase_gating": {
-                "enabled": (self.plan_service is None
-                            and self._phase_tables["prefill"] is not None),
+                "enabled": self._phase_tables["prefill"] is not None,
                 "phase_switches": self.phase_switches,
                 "phase_steps": dict(self.phase_steps),
                 # programs compiled per phase plan: 1 each when nothing
@@ -736,13 +678,12 @@ class ContinuousBatchingEngine:
             },
             "decode_step_breakdown": self._step_breakdown(),
         }
-        return {"requests": reqs, "aggregate": agg,
-                "adaptive": self._adaptive_telemetry()}
+        return {"requests": reqs, "aggregate": agg}
 
     def _step_breakdown(self) -> dict:
         """Where the per-step host budget goes: admission (slot-state
-        resets included), plan selection (phase table or plan-service
-        lookup, swaps included), device dispatch (token select + step
+        resets included), plan selection (the phase-table choice and the
+        fetch of its step from the core), device dispatch (token select + step
         call + bookkeeping), blocking host fetches (tokens /
         first-logits at retire), and telemetry, which counts admission
         and the samples (telemetry_s >= admit_s).  Pipelined engines
@@ -757,30 +698,6 @@ class ContinuousBatchingEngine:
             out[f"{name}_s"] = round(secs, 6)
             out[f"{name}_ms_per_step"] = round(1e3 * secs / n, 4)
         return out
-
-    def _adaptive_telemetry(self) -> dict | None:
-        """The telemetry()["adaptive"] block: bucket transitions, plan
-        swaps + latency stats, the core's variant-cache state, and the
-        plan service's per-bucket hit/flip counters.  None when the
-        engine runs a frozen plan."""
-        if self.plan_service is None:
-            return None
-        lat = self.swap_latencies_s
-        return {
-            "bucket_transitions": self.bucket_transitions,
-            "plan_swaps": self.plan_swaps,
-            "swap_latency_s": {
-                "count": len(lat),
-                "mean": float(np.mean(lat)) if lat else None,
-                "max": float(max(lat)) if lat else None,
-                "total": float(sum(lat)),
-            },
-            "plan_variants": self.core.plan_variants,
-            "plan_evictions": self.core.plan_evictions,
-            "active_plan_digest": (self._plan.digest
-                                   if self._plan is not None else None),
-            "service": self.plan_service.telemetry(),
-        }
 
 
 class _Span:
@@ -818,7 +735,7 @@ def synthetic_requests(cfg, n: int, seed: int = 0,
                        temperature: float = 0.0) -> list[Request]:
     """Seeded ragged request set (uniform prompt/output length ranges,
     inclusive) — the reproducible workload behind `launch.serve
-    --requests` and the traffic benchmark."""
+    --requests` and the serving tests."""
     rng = np.random.RandomState(seed)
     reqs = []
     for i in range(n):

@@ -49,6 +49,11 @@ def attn():
     return _core("mistral-nemo-12b")
 
 
+@pytest.fixture(scope="module")
+def hybrid():
+    return _core("granite-4.0-h-small")
+
+
 # --- epilogue-fused dequant --------------------------------------------------
 
 def _quantized(key, k, n, stacked=()):
@@ -249,11 +254,11 @@ def _stream(core, cfg, pipeline):
                  for r in eng.completed}
 
 
-@pytest.mark.parametrize("arch_fixture", ["mamba", "attn"])
+@pytest.mark.parametrize("arch_fixture", ["mamba", "attn", "hybrid"])
 def test_pipelined_tokens_exactly_match_sync(arch_fixture, request):
     """The one-step-deep pipelined loop is a scheduling change only:
     token streams are EXACTLY the synchronous engine's, per request, on
-    both the ssm and the paged-KV arch."""
+    the ssm, the paged-KV and the hybrid arch."""
     cfg, _, core = request.getfixturevalue(arch_fixture)
     eng_p, piped = _stream(core, cfg, pipeline=True)
     _, synced = _stream(core, cfg, pipeline=False)

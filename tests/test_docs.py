@@ -90,7 +90,7 @@ def test_docs_contain_executable_snippets():
     assert {doc for doc, _, _ in SNIPPETS} >= {
         "architecture.md", "sweep-backends.md",
         "reproducing-paper-figures.md", "serving.md",
-        "adaptive-planning.md", "campaigns.md"}
+        "campaigns.md"}
 
 
 @pytest.mark.parametrize("doc,idx,code",
@@ -158,5 +158,5 @@ def test_readme_links_the_docs_tree():
         readme = f.read()
     for doc in ("docs/architecture.md", "docs/sweep-backends.md",
                 "docs/reproducing-paper-figures.md", "docs/serving.md",
-                "docs/adaptive-planning.md", "docs/campaigns.md"):
+                "docs/campaigns.md"):
         assert doc in readme, f"README does not link {doc}"

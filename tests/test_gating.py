@@ -188,6 +188,28 @@ def test_gated_decode_parity_and_single_executable():
     assert ungated.decode_executables in (1, None)
 
 
+@pytest.mark.parametrize("arch", ["mistral-nemo-12b", "qwen2-moe-a2.7b"])
+def test_gated_prefill_logits_within_parity_atol(arch):
+    """The dense and MoE archs at decode batch 8: gated and ungated
+    prefill logits agree within the serve CLI's PARITY_ATOL, and the
+    gated session compiles one decode executable.  (mamba2, the
+    mixed-verdict arch, is held to the same bound by the serve CLI's
+    fixed-batch report test.)"""
+    from repro.launch.serve import PARITY_ATOL
+    cfg = reduced(ARCHS[arch])
+    params = init(jax.random.PRNGKey(0), cfg)
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (8, 6), 0,
+                                cfg.vocab)
+    gated = ServeSession(cfg, RC, params, max_len=24, batch=8,
+                         quantize=True)
+    ungated = ServeSession(cfg, RC, params, max_len=24, batch=8,
+                           quantize=True, gated=False)
+    lg = gated.prefill(prompt).astype(jnp.float32)
+    lu = ungated.prefill(prompt).astype(jnp.float32)
+    assert float(jnp.max(jnp.abs(lg - lu))) <= PARITY_ATOL
+    assert gated.decode_executables in (1, None)
+
+
 def test_gated_session_plan_built_before_jit():
     """quantize=True builds the plan eagerly; the table is frozen and the
     gated labels match the planner verdicts."""

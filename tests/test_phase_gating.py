@@ -16,11 +16,19 @@ contracts under test:
     second copy;
   * an empty phase workload raises instead of silently disabling gating
     (plan_workload_by_phase's zero-GEMM guard);
+  * the plan is fixed when the core is built: `batch_step_for` keeps one
+    jitted step per distinct phase table, every engine step is counted
+    in exactly one phase, and an unquantized core (no tables) serves
+    from one program with phase gating off;
+  * `KernelPlanTable` equality/hash by content, `flips`, the
+    KeyError-with-known-labels drift gate and `strip_model_prefix`;
   * the widened What axis at runtime: quantize=True sessions at
     precision="int4" / "fp8" route gated projections through the
     low-bit CiM Pallas paths and match the ungated program's logits —
     routing is the only difference, same quantized weights.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -30,7 +38,10 @@ from repro.core.llm_workloads import (is_projection_label,
                                       phase_gemms_of_model)
 from repro.core.planner import plan_workload_by_phase
 from repro.models import init
-from repro.serving import ContinuousBatchingEngine, Request, ServeSession
+from repro.quant.plan_table import (KernelPlanTable, PlanEntry,
+                                    strip_model_prefix)
+from repro.serving import (ContinuousBatchingEngine, Request, ServeSession,
+                           synthetic_requests)
 from repro.serving.core import DecodeCore
 
 RC = RunConfig(remat=False, attn_impl="naive")
@@ -40,6 +51,8 @@ RC = RunConfig(remat=False, attn_impl="naive")
 # (prefill's M=2048 reuse earns CiM a different verdict than decode's
 # M=8 GEMV) while e.g. batch 4 gates both phases identically.
 MIXED_ARCH, MIXED_BATCH, MIXED_LEN = "mamba2-780m", 8, 2048
+# one arch per served family: ssm, dense GQA, Mamba-2 + GQA + MoE hybrid
+SERVED_ARCHS = ("mamba2-780m", "mistral-nemo-12b", "granite-4.0-h-small")
 
 
 # --- per-phase planning ------------------------------------------------------
@@ -153,6 +166,139 @@ def test_scheduler_switches_phase_tables_under_traffic():
             == eng.steps)
     # one compiled batch-step per distinct phase plan, nothing more
     assert core.batch_decode_executables in (2, None)
+
+
+# --- one plan per phase, fixed when the core is built ------------------------
+
+def _served_core(arch, **kw):
+    cfg = reduced(ARCHS[arch])
+    params = init(jax.random.PRNGKey(0), cfg)
+    return cfg, DecodeCore(cfg, RC, params, **kw)
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_batch_step_for_one_step_per_phase_table(arch):
+    """`batch_step_for` hands back the identical callable for equal
+    tables (an equal copy of a table included) and one callable per
+    distinct phase table."""
+    _, core = _served_core(arch, quantize=True, plan_batch=MIXED_BATCH,
+                           plan_max_len=MIXED_LEN)
+    tables = (core.plan_table, core.prefill_plan_table)
+    fns = [core.batch_step_for(t) for t in tables]
+    copy = KernelPlanTable(entries=core.plan_table.entries)
+    assert copy is not core.plan_table
+    assert core.batch_step_for(copy) is fns[0]
+    assert core.batch_step is fns[0]
+    assert (fns[0] is fns[1]) == (tables[0] == tables[1])
+    if arch == MIXED_ARCH:
+        assert fns[0] is not fns[1]      # the phases gate differently
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_phase_steps_cover_every_step_of_mixed_traffic(arch):
+    """Requests joining while others decode: every engine step runs in
+    exactly one phase, both phases occur, and the core compiled one
+    program per distinct phase table."""
+    cfg, core = _served_core(arch, quantize=True, plan_batch=MIXED_BATCH,
+                             plan_max_len=MIXED_LEN)
+    eng = ContinuousBatchingEngine(core, n_slots=3, max_len=24,
+                                   block_size=4)
+    t = eng.run(synthetic_requests(cfg, 5, seed=4, prompt_len=(3, 6),
+                                   new_tokens=(3, 6)), None)
+    assert t["aggregate"]["completed"] == 5
+    tel = t["aggregate"]["phase_gating"]
+    assert tel["enabled"] is True
+    assert tel["phase_steps"]["prefill"] >= 1
+    assert tel["phase_steps"]["decode"] >= 1
+    assert sum(eng.phase_steps.values()) == eng.steps
+    n_tables = len({core.plan_table, core.prefill_plan_table})
+    assert core.batch_decode_executables in (n_tables, None)
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_unquantized_core_serves_one_program_ungated(arch):
+    """An unquantized core has no plan tables: the engine never splits
+    phases and serves every step from one compiled program."""
+    cfg, core = _served_core(arch, quantize=False)
+    assert core.plan_table is None and core.prefill_plan_table is None
+    eng = ContinuousBatchingEngine(core, n_slots=2, max_len=24,
+                                   block_size=4)
+    t = eng.run(synthetic_requests(cfg, 3, seed=5, prompt_len=(3, 5),
+                                   new_tokens=(3, 5)), None)
+    assert t["aggregate"]["completed"] == 3
+    tel = t["aggregate"]["phase_gating"]
+    assert tel["enabled"] is False
+    assert tel["phase_switches"] == 0
+    assert tel["phase_steps"] == {"prefill": 0, "decode": 0}
+    assert tel["executables"] == {}
+    assert core.batch_decode_executables in (1, None)
+
+
+# --- KernelPlanTable ---------------------------------------------------------
+
+def _decision(label, use_cim, what="baseline", where="PE"):
+    """Minimal planner-Decision stand-in for from_decisions."""
+    gemm = dataclasses.make_dataclass("G", ["label"])(label)
+    return dataclasses.make_dataclass(
+        "D", ["gemm", "use_cim", "what", "where"])(
+            gemm, use_cim, what, where)
+
+
+def test_equality_and_hash_stable_across_orderings():
+    a = KernelPlanTable.from_decisions(
+        [_decision("m Wq", True), _decision("m lm_head", False)],
+        model_name="m")
+    b = KernelPlanTable.from_decisions(
+        [_decision("m lm_head", False), _decision("m Wq", True)],
+        model_name="m")
+    assert a == b
+    assert hash(a) == hash(b)
+    # any verdict change is a different table
+    c = KernelPlanTable.from_decisions(
+        [_decision("Wq", False), _decision("lm_head", False)])
+    assert c != a
+    assert c.flips(a) == ("Wq",)
+
+
+def test_flips_diffs_by_gate_and_one_sided_labels():
+    a = KernelPlanTable.from_decisions(
+        [_decision("Wq", True), _decision("Wk", False)])
+    assert a.flips(a) == ()
+    b = KernelPlanTable.from_decisions(
+        [_decision("Wq", True), _decision("Wk", True)])
+    assert a.flips(b) == ("Wk",)
+    # a label present in only one table counts as flipped
+    wider = KernelPlanTable(entries=a.entries
+                            + (("Wv", PlanEntry(use_cim=True)),))
+    assert a.flips(wider) == ("Wv",)
+    assert wider.flips(a) == ("Wv",)
+
+
+def test_drift_gate_holds_on_every_table():
+    """The KeyError-with-known-labels contract holds on any table, one
+    with a flipped verdict included: silent ungating on label drift is
+    the failure mode."""
+    base = KernelPlanTable.from_decisions(
+        [_decision("Wq", True), _decision("lm_head", False)])
+    flipped = KernelPlanTable.from_decisions(
+        [_decision("Wq", True), _decision("lm_head", True)])
+    assert flipped.use_cim("lm_head") != base.use_cim("lm_head")
+    assert flipped.use_cim("Wq") == base.use_cim("Wq")
+    with pytest.raises(KeyError, match="known labels.*Wq"):
+        flipped.use_cim("mlp-up")
+    with pytest.raises(KeyError, match="unknown GEMM label"):
+        base.entry("nope")
+
+
+def test_strip_model_prefix_edges():
+    assert strip_model_prefix("m Wq", "m") == "Wq"
+    # empty model name: no prefix to strip
+    assert strip_model_prefix("m Wq", "") == "m Wq"
+    # label equal to the bare prefix (no trailing space): untouched
+    assert strip_model_prefix("m", "m") == "m"
+    # prefix-with-space but empty remainder strips to empty
+    assert strip_model_prefix("m ", "m") == ""
+    assert strip_model_prefix("other Wq", "m") == "other Wq"
 
 
 # --- widened-precision routes: INT4 / FP8 gated execution --------------------

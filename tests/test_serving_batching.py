@@ -8,8 +8,10 @@ and produces, per request, exactly the tokens the legacy
 `ServeSession(batch=1)` produces for that request alone.  mamba2-780m
 is the mixed-verdict gated case (ssm-BCdt on CiM, the rest standard);
 mistral-nemo-12b exercises the paged-KV gather/scatter across block
-boundaries.
+boundaries; granite-4.0-h-small holds both kinds of cache (SSM state and
+paged KV) and an expert layer in one step.
 """
+import dataclasses
 import math
 
 import jax
@@ -28,8 +30,11 @@ MAX_LEN = 24
 BLOCK = 4          # small so smoke prompts cross block edges
 
 
-def _core(arch: str, quantize: bool):
+def _core(arch: str, quantize: bool, dtype: str | None = None):
     cfg = reduced(ARCHS[arch])
+    if dtype is not None:
+        cfg = dataclasses.replace(cfg, param_dtype=dtype,
+                                  compute_dtype=dtype)
     params = init(jax.random.PRNGKey(0), cfg)
     return cfg, params, DecodeCore(cfg, RC, params, quantize=quantize,
                                    plan_batch=4, plan_max_len=MAX_LEN)
@@ -45,6 +50,18 @@ def mamba():
 def attn():
     """Quantized attention core (paged KV path)."""
     return _core("mistral-nemo-12b", quantize=True)
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    """Quantized Mamba-2 + GQA + MoE core (SSM state and paged KV), in
+    float32: the expert layer contracts every held expert over all of a
+    step's tokens, and the CPU's accumulation order for that contraction
+    depends on the token count, so in bfloat16 a 3-slot engine and the
+    1-lane session can round a logit one bfloat16 step apart.  In
+    float32 the two agree to well inside the parity tolerance."""
+    return _core("granite-4.0-h-small", quantize=True,
+                 dtype="float32")
 
 
 def _engine(core, n_slots, **kw):
@@ -161,11 +178,12 @@ def test_join_at_full_occupancy(mamba):
     assert np.array_equal(np.asarray(last.tokens), want)
 
 
-def test_evict_on_eos_mid_scan(mamba):
+@pytest.mark.parametrize("arch_fixture", ["mamba", "attn", "hybrid"])
+def test_evict_on_eos_mid_scan(arch_fixture, request):
     """Learn the greedy token stream, re-run with one of its tokens as
     EOS: the request must finish early with done_reason='eos' while the
     other slot keeps decoding to max_tokens."""
-    cfg, params, core = mamba
+    cfg, params, core = request.getfixturevalue(arch_fixture)
     probe = _engine(core, n_slots=1)
     probe.run(synthetic_requests(cfg, 1, seed=2, prompt_len=(4, 4),
                                  new_tokens=(8, 8)), None)
@@ -187,8 +205,9 @@ def test_evict_on_eos_mid_scan(mamba):
     assert by_rid["full"].t_done > by_rid["eos"].t_done
 
 
-def test_single_request_batch_matches_legacy(mamba):
-    cfg, params, core = mamba
+@pytest.mark.parametrize("arch_fixture", ["mamba", "attn", "hybrid"])
+def test_single_request_batch_matches_legacy(arch_fixture, request):
+    cfg, params, core = request.getfixturevalue(arch_fixture)
     eng = _engine(core, n_slots=1)
     reqs = synthetic_requests(cfg, 1, seed=5, prompt_len=(6, 6),
                               new_tokens=(10, 10))
@@ -200,7 +219,7 @@ def test_single_request_batch_matches_legacy(mamba):
 
 # --- parity + no-retrace -----------------------------------------------------
 
-@pytest.mark.parametrize("arch_fixture", ["mamba", "attn"])
+@pytest.mark.parametrize("arch_fixture", ["mamba", "attn", "hybrid"])
 def test_continuous_matches_fixed_batch(arch_fixture, request):
     """Token + first-logits parity of the continuous engine against the
     legacy per-request session, through slot churn.  Prompts are long
@@ -238,11 +257,15 @@ def test_continuous_matches_fixed_batch(arch_fixture, request):
         assert eng2.decode_executables == n_before
 
 
-def test_decode_executables_one_after_churn():
+@pytest.mark.parametrize("arch", ["mamba2-780m", "mistral-nemo-12b",
+                                  "granite-4.0-h-small"])
+def test_decode_executables_one_after_churn(arch):
     """Fresh gated core, fixed slot count, back-to-back runs with
-    different ragged traffic: exactly one compiled masked step — the
-    bench's absolute no-retrace gate."""
-    cfg, params, core = _core("mamba2-780m", quantize=True)
+    different ragged traffic: exactly one compiled masked step (at this
+    planning shape both phases share one table) — the absolute
+    no-retrace gate."""
+    cfg, params, core = _core(arch, quantize=True)
+    assert core.prefill_plan_table == core.plan_table
     for n_req, seed in ((3, 11), (1, 12)):
         eng = _engine(core, n_slots=2)
         eng.run(synthetic_requests(cfg, n_req, seed=seed,
