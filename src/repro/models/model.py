@@ -479,6 +479,15 @@ def _mask_rows(new, old, active):
         return jnp.where(m, new.astype(old.dtype), old)
 
 
+def _zero_fresh(old, fresh):
+    """Per-slot select: lanes at position 0 read a zero SSM state / conv
+    carry (a lane with no context has none), the others their own.  A
+    select, not a product, so a NaN left in a freed lane cannot leak."""
+    with jax.named_scope("cache_mask"):
+        m = fresh.reshape(fresh.shape + (1,) * (old.ndim - fresh.ndim))
+        return jnp.where(m, jnp.zeros((), old.dtype), old)
+
+
 def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
                       rc: RunConfig, active, block_tables):
     """RoPE (none when rope_theta is 0), this token's KV write into layer `i` of the stacked cache
@@ -537,6 +546,9 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
       * `active` (b,) bool: cache writes of inactive (free / draining)
         slots are masked out, so join/evict never retraces or corrupts
         neighbouring requests;
+      * a slot at position 0 starts from a zero SSM state and conv
+        carry, whatever its cache rows hold: a joining request needs no
+        reset outside the step;
       * `block_tables` (b, max_blocks) int32: attention KV lives in the
         block pool laid out by `init_paged_cache`; reads gather the
         slot's logical strip, writes scatter one row into its current
@@ -559,6 +571,7 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
             x = params["embed"][tokens]
         x = x.astype(dtype_of(cfg.compute_dtype))
     nh, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim()
+    fresh = jnp.asarray(pos) == 0       # () or (b,): lanes with no context
 
     # The stacked cache rides in the scan's carry and each layer reads and
     # writes its own index of it in place.  Donation aliases the
@@ -575,8 +588,10 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
                 old = {n: jax.lax.dynamic_index_in_dim(c, i, keepdims=False)
                        for n, c in cache_s.items()}
                 y, new = mamba_apply(
-                    sp["mamba"], h, cfg, state=old["state"],
-                    conv_carry=old["conv"], decode=True, plan=plan)
+                    sp["mamba"], h, cfg,
+                    state=_zero_fresh(old["state"], fresh),
+                    conv_carry=_zero_fresh(old["conv"], fresh),
+                    decode=True, plan=plan)
                 cache[si] = {
                     n: jax.lax.dynamic_update_index_in_dim(
                         cache_s[n],

@@ -34,11 +34,14 @@ server:
     host-fetch and telemetry time per step);
   * **spans on the profiler's clock**: every `step()` is a
     `jax.profiler.StepTraceAnnotation` "engine.step" holding one
-    "engine.<phase>" `TraceAnnotation` per phase (admit, with a nested
-    "engine.reset_slot" per joining slot; plan; dispatch; retire;
-    telemetry), opened and closed where the phase's counter reads the
-    clock.  With no profiler running, a span costs about a
-    microsecond of host time;
+    "engine.<phase>" `TraceAnnotation` per phase (admit, plan,
+    dispatch, retire, telemetry), opened and closed where the phase's
+    counter reads the clock.  With no profiler running, a span costs
+    about a microsecond of host time;
+  * **admission is host-only**: a joining slot starts at position 0,
+    and the decode step itself zeroes the SSM state and conv carry of
+    every lane at position 0 (`fresh_lanes` counts them), so admission
+    dispatches no device work and copies no state;
   * **phase-split gating**: the core fixes one plan table per serving
     phase when it is built; a step whose live slots are all still
     feeding their prompt runs the prefill table's program, any other
@@ -247,6 +250,7 @@ class ContinuousBatchingEngine:
         # decode_step_breakdown accumulators (seconds): telemetry_s
         # holds admission (admit_s) and the samples
         self.admissions = 0
+        self.fresh_lanes = 0          # lane-steps dispatched at pos 0
         self.admit_s = 0.0
         self.plan_s = 0.0             # phase-table selection + step fetch
         self.dispatch_s = 0.0
@@ -299,20 +303,13 @@ class ContinuousBatchingEngine:
         req.t_submit = self._now()
         self.queue.append(req)
 
-    def _reset_slot_state(self, i: int) -> None:
-        """Zero the joining slot's O(1) caches (mamba state / conv
-        carry).  Attention needs nothing: stale pool blocks are dead by
-        construction (per-slot lens mask + freed block ids)."""
-        for c, entry in enumerate(self.cache):
-            if "state" in entry:
-                self.cache[c] = {
-                    "state": entry["state"].at[:, i].set(0.0),
-                    "conv": entry["conv"].at[:, i].set(0.0)}
-
     def _admit(self) -> None:
         """FIFO admission: the queue head takes the first free slot if
         its full KV horizon fits in the pool (no skipping — head-of-line
-        order keeps TTFT fairness)."""
+        order keeps TTFT fairness).  Host bookkeeping only: the slot's
+        stale SSM state is zeroed by the step that feeds it at position
+        0, and its stale KV blocks are dead by construction (per-slot
+        lens mask + freed block ids)."""
         for i in range(self.n_slots):
             if not self.queue:
                 return
@@ -326,9 +323,6 @@ class ContinuousBatchingEngine:
             self.block_tables[i, :] = 0
             if blocks:
                 self.block_tables[i, :len(blocks)] = blocks
-            with jax.profiler.TraceAnnotation("engine.reset_slot", slot=i,
-                                              rid=req.rid):
-                self._reset_slot_state(i)
             self.slots[i] = _Slot(req, blocks)
             self.admissions += 1
             req.state = "running"
@@ -451,6 +445,8 @@ class ContinuousBatchingEngine:
                             and not s.prefilling for s in self.slots],
                            bool)
         tokens = self._mix_tokens(host_toks, use_dev)
+        # the step zeroes these lanes' SSM state before their first token
+        self.fresh_lanes += int(np.count_nonzero(active & (pos == 0)))
         probe = None
         if self.donation_ok is None and self.core.donate:
             probe = next((leaf for leaf in jax.tree.leaves(self.cache)
@@ -681,17 +677,20 @@ class ContinuousBatchingEngine:
         return {"requests": reqs, "aggregate": agg}
 
     def _step_breakdown(self) -> dict:
-        """Where the per-step host budget goes: admission (slot-state
-        resets included), plan selection (the phase-table choice and the
-        fetch of its step from the core), device dispatch (token select + step
-        call + bookkeeping), blocking host fetches (tokens /
-        first-logits at retire), and telemetry, which counts admission
-        and the samples (telemetry_s >= admit_s).  Pipelined engines
-        overlap the fetch of step t with the compute of step t+1, so
-        fetch time here is host *blocked* time, not device time."""
+        """Where the per-step host budget goes: admission, plan
+        selection (the phase-table choice and the fetch of its step from
+        the core), device dispatch (token select + step call +
+        bookkeeping), blocking host fetches (tokens / first-logits at
+        retire), and telemetry, which counts admission and the samples
+        (telemetry_s >= admit_s).  Pipelined engines overlap the fetch
+        of step t with the compute of step t+1, so fetch time here is
+        host *blocked* time, not device time.  `fresh_lanes` counts the
+        lane-steps the step started from a zero SSM state: one per
+        admission."""
         n = max(1, self.steps)
         out = {"steps": self.steps, "pipelined": self._pipelined,
-               "admissions": self.admissions}
+               "admissions": self.admissions,
+               "fresh_lanes": self.fresh_lanes}
         for name in ("admit", "plan", "dispatch", "host_fetch",
                      "telemetry"):
             secs = getattr(self, f"{name}_s")

@@ -397,7 +397,8 @@ def _loop_step(cfg, rc, params, cache, tokens, pos, active, tables):
     """A plain Python loop over the layers: each layer's weights and
     cache taken with `unstack_tree`, every cache write a row-by-row
     `.at[].set` on that layer's cache by host indices (active slots
-    only), and the layer's cache put back into the stack."""
+    only), and the layer's cache put back into the stack.  A slot at
+    position 0 reads a zero SSM state and conv carry."""
     from repro.models.attention import decode_attend
     from repro.models.layers import (apply_rope, attn_out_proj, dtype_of,
                                      qkv_proj, rmsnorm, unstack_tree)
@@ -416,9 +417,14 @@ def _loop_step(cfg, rc, params, cache, tokens, pos, active, tables):
             layer = unstack_tree(cache[si], i)
             h = rmsnorm(sp["norm1"], x, cfg.rmsnorm_eps)
             if slot.mixer == "mamba":
+                read = dict(layer)
+                for n in ("state", "conv"):
+                    for s in range(b):
+                        if pos[s] == 0:
+                            read[n] = read[n].at[s].set(0)
                 y, (st, cv) = mamba_apply(
-                    sp["mamba"], h, cfg, state=layer["state"],
-                    conv_carry=layer["conv"], decode=True)
+                    sp["mamba"], h, cfg, state=read["state"],
+                    conv_carry=read["conv"], decode=True)
                 for n, new in (("state", st), ("conv", cv)):
                     for s in live:
                         layer[n] = layer[n].at[s].set(
@@ -540,3 +546,64 @@ def test_batch_step_updates_stacked_cache_like_a_layer_loop(arch, kv):
                     np.testing.assert_array_equal(got, old)
         ref = caches[False]
         pos = np.where(active, pos + 1, pos)
+
+
+# --- a lane at position 0 starts from a zero SSM state -----------------------
+
+@pytest.mark.parametrize("arch_fixture", ["mamba", "hybrid"])
+@pytest.mark.parametrize("ragged", [True, False])
+def test_fresh_lane_state_is_zeroed_in_the_step(arch_fixture, ragged,
+                                                request):
+    """The step zeroes the SSM state and conv carry of a lane at
+    position 0, whatever its cache rows hold (garbage, NaN included):
+    logits and the whole updated cache equal those of the same call with
+    that lane's rows zeroed, bit for bit.  Lanes at a later position
+    read their own state.  Ragged positions (the engine's batch step) and
+    a uniform position (the fixed-batch step, where every lane is at 0)."""
+    from repro.models import init_paged_cache
+    cfg, _, core = request.getfixturevalue(arch_fixture)
+    b, bs, max_blocks = 4, BLOCK, 4
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 32))
+
+    def fill(a):
+        return jax.random.normal(next(keys), a.shape).astype(a.dtype)
+    tokens = jax.random.randint(next(keys), (b, 1), 0, cfg.vocab)
+    if ragged:
+        pos = jnp.array([0, 3, 7, 1], jnp.int32)
+        tables = jnp.arange(b * max_blocks, dtype=jnp.int32).reshape(
+            b, max_blocks)
+        cache = jax.tree.map(fill, init_paged_cache(
+            cfg, RC, b, b * max_blocks, bs))
+
+        def step(c):
+            return core.batch_step(core.params, c, tokens, pos,
+                                   jnp.ones(b, bool), tables)
+    else:
+        pos = jnp.int32(0)
+        cache = jax.tree.map(fill, init_cache(cfg, RC, b, MAX_LEN))
+
+        def step(c):
+            return core.step(c, tokens, pos)
+    fresh = np.broadcast_to(np.asarray(pos) == 0, (b,))
+
+    def with_rows(c, lanes, value):
+        m = jnp.asarray(lanes)
+        return [{n: (jnp.where(m.reshape((1, b) + (1,) * (a.ndim - 2)),
+                               jnp.asarray(value, a.dtype), a)
+                     if n in ("state", "conv") else a)
+                 for n, a in e.items()} for e in c]
+    garbage = with_rows(cache, np.arange(b) == 0, np.nan)
+    zeroed = with_rows(cache, fresh, 0.0)
+    lg, cg = step(garbage)
+    lz, cz = step(zeroed)
+    assert np.all(np.isfinite(np.asarray(lg, np.float32)))
+    np.testing.assert_array_equal(np.asarray(lg), np.asarray(lz))
+    for got, want in zip(jax.tree.leaves(cg), jax.tree.leaves(cz)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if ragged:
+        # lanes 1-3 read their state: zeroing it changes their logits
+        lo, _ = step(with_rows(cache, np.ones(b, bool), 0.0))
+        lo, lz = np.asarray(lo, np.float32), np.asarray(lz, np.float32)
+        np.testing.assert_array_equal(lo[0], lz[0])
+        for s in (1, 2, 3):
+            assert np.max(np.abs(lo[s] - lz[s])) > 1e-3, s

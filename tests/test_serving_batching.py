@@ -275,6 +275,36 @@ def test_decode_executables_one_after_churn(arch):
         assert eng.decode_executables in (1, None)
 
 
+@pytest.mark.parametrize("arch_fixture", ["mamba", "hybrid"])
+def test_admission_dispatches_no_device_work(arch_fixture, request):
+    """Admission is host bookkeeping: across a churned run (more requests
+    than slots, so freed slots are taken again) no admission replaces a
+    cache array or makes a new device array.  The step zeroes each
+    joining lane's SSM state instead, and `fresh_lanes` counts one
+    lane-step at position 0 per join."""
+    cfg, _, core = request.getfixturevalue(arch_fixture)
+    eng = _engine(core, n_slots=2)
+    admit, joins = eng._admit, []
+
+    def watched_admit():
+        before = eng.admissions
+        leaves = [id(a) for a in jax.tree.leaves(eng.cache)]
+        live = {id(a) for a in jax.live_arrays()}
+        admit()
+        if eng.admissions > before:
+            joins.append(eng.admissions - before)
+            assert [id(a) for a in jax.tree.leaves(eng.cache)] == leaves
+            assert {id(a) for a in jax.live_arrays()} <= live
+    eng._admit = watched_admit
+    reqs = synthetic_requests(cfg, 5, seed=4, prompt_len=(3, 5),
+                              new_tokens=(2, 4))
+    eng.run(reqs, None)
+    assert len(eng.completed) == len(reqs)
+    assert sum(joins) == len(reqs) and len(joins) >= 3   # slots reused
+    bd = eng.telemetry()["aggregate"]["decode_step_breakdown"]
+    assert bd["fresh_lanes"] == bd["admissions"] == len(reqs)
+
+
 def test_vlm_rejected():
     cfg = reduced(ARCHS["llama-3.2-vision-90b"])
     params = init(jax.random.PRNGKey(0), cfg)

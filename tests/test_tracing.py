@@ -88,7 +88,7 @@ def test_admission_counters(served):
     assert 0.0 < eng.admit_s <= eng.telemetry_s
     assert eng.plan_s > 0.0
     bd = eng.telemetry()["aggregate"]["decode_step_breakdown"]
-    assert bd["admissions"] == len(reqs)
+    assert bd["admissions"] == bd["fresh_lanes"] == len(reqs)
     for name in ("admit", "plan", "dispatch", "host_fetch", "telemetry"):
         assert bd[f"{name}_s"] >= 0.0
         assert bd[f"{name}_ms_per_step"] >= 0.0
@@ -109,7 +109,9 @@ class _Clock:
 def test_spans_and_counters_share_their_edges(monkeypatch):
     """Each "engine.<phase>" span opens just before its counter reads the
     clock and closes just after, so the counter holds exactly the ticks
-    read inside the span; admission's slot resets nest inside it."""
+    read inside the span.  Admission opens no span of its own per
+    joining slot: the step zeroes a joining lane's state, and
+    `fresh_lanes` counts one such lane-step per admission."""
     cfg = reduced(ARCHS["mamba2-780m"])
     core = DecodeCore(cfg, RC, init(jax.random.PRNGKey(0), cfg),
                       quantize=True, plan_batch=2, plan_max_len=MAX_LEN)
@@ -138,12 +140,9 @@ def test_spans_and_counters_share_their_edges(monkeypatch):
     while eng.step():
         calls += 1
     calls += 1
-    ticks, open_at, depth, resets = {}, {}, [], []
-    for kind, name, now, args in log:
+    ticks, open_at, depth = {}, {}, []
+    for kind, name, now, _ in log:
         if kind == "enter":
-            if name == "engine.reset_slot":
-                assert depth[-1] == "engine.admit"
-                resets.append((args["slot"], args["rid"]))
             open_at[name] = now
             depth.append(name)
             continue
@@ -156,9 +155,11 @@ def test_spans_and_counters_share_their_edges(monkeypatch):
         spans = ticks[f"engine.{phase}"]
         assert getattr(eng, counter) == sum(t - 1 for t in spans), phase
     assert len(ticks["engine.step"]) == calls
-    assert sorted(rid for _, rid in resets) == [r.rid for r in reqs]
-    assert {slot for slot, _ in resets} == {0, 1}
-    assert eng.admissions == len(resets)
+    assert "engine.reset_slot" not in {name for _, name, _, _ in log}
+    assert set(ticks) == {"engine.step", "engine.admit", "engine.plan",
+                          "engine.dispatch", "engine.retire",
+                          "engine.telemetry"}
+    assert eng.fresh_lanes == eng.admissions == len(reqs)
 
 
 def test_serve_traffic_report_splits_admission_and_planning():
