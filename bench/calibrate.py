@@ -8,11 +8,12 @@ the window at the cell's own load, the reference comparison) and records
 the widest gap; then the same for the control: the program with its own
 lower-precision weight path switched on (INT4, the step below the INT8
 the configuration states), still compared against the INT8 weights'
-float32 reference.  Where the family states a float32 state (Mamba-2's
-SSM state), each program run also reads a second control on the same
-sample: the float32 reference with its state rounded to bfloat16 after
-every update, put in the program's place (the gap of the token it puts
-first).  The limit goes above the program's largest reading and below
+float32 reference.  Where the family's module under families/ names
+reference controls (`STATE_CONTROLS`: the ssm and hybrid families'
+float32 SSM state rounded to bfloat16 after every update), each program
+run also reads them on the same sample, each put in the program's
+place (the gap of the token it puts first).  The limit goes above the
+program's largest reading and below
 the controls' smallest.  The benchmark's own runs never run a control.
 Prints one line per run and writes them all to
 chiprun_out/bench/calibrate-<workload>.json.
@@ -33,9 +34,13 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 # the program's own weight path one precision step below the INT8 the
 # configurations state
 CONTROL = "int4"
-# reference controls per family: a state the configuration keeps in
-# float32, rounded to the step below
-STATE_CONTROLS = {"ssm": {"ssm_state_bf16": {"state_dtype": "bfloat16"}}}
+
+
+def state_controls(name: str) -> dict:
+    """The reference controls that family `name` names in its families/
+    module ({control: options of its reference's `logits`}), or none."""
+    from bench.lib.spec import family
+    return getattr(family(name), "STATE_CONTROLS", {})
 
 
 def main(argv=None) -> int:
@@ -51,7 +56,8 @@ def main(argv=None) -> int:
     from repro.launch.compile_cache import configure_compile_cache
     configure_compile_cache()
     bench = Bench()
-    family = bench.config(bench.workload(args.workload)["config"])["family"]
+    controls = state_controls(
+        bench.config(bench.workload(args.workload)["config"])["family"])
     seeds = lambda s: [int(x) for x in s.split(",") if x]   # noqa: E731
     rows = []
     for precision, group in (("int8", seeds(args.seeds)),
@@ -59,8 +65,8 @@ def main(argv=None) -> int:
         for seed in group:
             r = run_cell(bench, args.workload, seed, args.seconds, False,
                          time.perf_counter(), precision=precision,
-                         controls=(STATE_CONTROLS.get(family)
-                                   if precision == "int8" else None))
+                         controls=(controls if precision == "int8"
+                                   else None))
             run = r["run"]
             row = {"precision": precision, "seed": seed,
                    "max_gap": r["checks"]["max_gap"]["value"],
@@ -78,7 +84,7 @@ def main(argv=None) -> int:
         json.dump(rows, f, indent=1)
     readings = {p: [r["max_gap"] for r in rows if r["precision"] == p]
                 for p in ("int8", CONTROL)}
-    for name in STATE_CONTROLS.get(family, {}):
+    for name in controls:
         readings[name] = [r["controls"][name] for r in rows
                           if name in r["controls"]]
     for name, gaps in readings.items():

@@ -59,3 +59,7 @@ def _conv_bias(key, sds):
 
 LEAVES = dict(_SSM.LEAVES, router=_router, conv_x_bias=_conv_bias,
               conv_B_bias=_conv_bias, conv_C_bias=_conv_bias)
+
+# the Mamba-2 layers' float32 state rounded to bfloat16: the ssm family's
+# reference control, which the hybrid reference takes too
+STATE_CONTROLS = _SSM.STATE_CONTROLS

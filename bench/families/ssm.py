@@ -50,3 +50,8 @@ LEAVES = {
     "dt_bias": _dt_bias,
     "D": lambda key, sds: _uniform(key, sds, 0.5, 1.5),
 }
+
+# the reference controls that bench/calibrate.py reads beside every
+# program run: the float32 SSM state rounded to bfloat16 after every
+# update, the step below the precision the configuration states
+STATE_CONTROLS = {"ssm_state_bf16": {"state_dtype": "bfloat16"}}

@@ -11,7 +11,8 @@ an entry and edits nothing:
   cells/<workload>.json     one cell's engine size, offered rate and limits
   metrics/<metric>.py       the reader of one per-layer metric
   reference/<family>.py     the plain float32 forward of one model family
-  families/<family>.py      one family's FLOP count and weight-drawing rules
+  families/<family>.py      one family's FLOP count, weight-drawing rules
+                            and reference controls (bench/calibrate.py)
   peaks.json                chip peaks, keyed by JAX's device_kind
 """
 from __future__ import annotations

@@ -23,17 +23,29 @@ from .spec import family
 
 
 def model_config(conf: dict):
-    """The program's ModelConfig for a benchmark config file.  Keys that
-    differ from the registered architecture must be listed in
-    `reduced`: the cell runs the registry's model and says how it was
-    cut."""
+    """The program's ModelConfig for a benchmark config file.
+
+    Each ModelConfig field is read from the file's key of the same name.
+    A key the file leaves out takes the ModelConfig dataclass's default,
+    never the registry's value, just as a key left out of a sub-config
+    group (`ssm`, `moe`) takes its dataclass's default: a file written
+    before the program added a field reads as it was written.  A field
+    with no default is required, and a file without it raises
+    ValueError.  Keys that differ from the registered architecture, left
+    out ones included, must be listed in `reduced`: the cell runs the
+    registry's model and says how it was cut."""
     from repro.configs import ARCHS
-    from repro.configs.base import ModelConfig, SSMConfig
-    m = {f.name: conf[f.name] for f in dataclasses.fields(ModelConfig)
-         if f.name != "name"}
-    if m["ssm"] is not None:
-        m["ssm"] = SSMConfig(**m["ssm"])
-    cfg = ModelConfig(name=conf["arch"], **m)
+    from repro.configs.base import ModelConfig
+    fields = [f for f in dataclasses.fields(ModelConfig) if f.name != "name"]
+    missing = [f.name for f in fields if f.name not in conf
+               and f.default is dataclasses.MISSING
+               and f.default_factory is dataclasses.MISSING]
+    if missing:
+        raise ValueError(f"config file configs/{conf['name']}.json lacks"
+                         f" {missing}, which ModelConfig requires")
+    cfg = ModelConfig(name=conf["arch"],
+                      **{f.name: conf[f.name] for f in fields
+                         if f.name in conf})
     reg = ARCHS[cfg.name]
     changed = {f.name for f in dataclasses.fields(ModelConfig)
                if getattr(cfg, f.name) != getattr(reg, f.name)}

@@ -109,16 +109,16 @@ def test_hybrid_flop_count():
 def test_leaves_draw_every_parameter_of_the_family():
     """The family's rules draw the router and the conv biases; a served
     tree of the small model holds no leaf without a rule."""
-    from repro.configs.base import ModelConfig
     conf = granite()
     small = dict(conf, n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                  d_head=16, vocab=128, attn_every=2,
                  ssm=dict(conf["ssm"], d_state=16, headdim=16),
                  moe=dict(conf["moe"], n_experts=2, router_experts=8,
                           expert_d_ff=32, shared_d_ff=32, top_k=3))
-    cfg = ModelConfig(name="small", **{
-        k: small[k] for k in ModelConfig.__dataclass_fields__
-        if k != "name"})
+    small["reduced"] = dict(conf["reduced"], **{k: "small" for k in (
+        "d_model", "n_heads", "n_kv_heads", "d_head", "vocab", "attn_every",
+        "ssm")})
+    cfg = weights.model_config(small)
     params = weights.make_params(cfg, seed=3)
     mamba = params["slots"][0]["mamba"]
     for name in ("conv_x_bias", "conv_B_bias", "conv_C_bias"):
