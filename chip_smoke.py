@@ -5,7 +5,7 @@
 
 One chip runs four phases after the device check:
 
-  planner  the What/When/Where planner over the full 223-GEMM LLM set,
+  planner  the What/When/Where planner over the full 291-GEMM LLM set,
            on the XLA (`vectorized`) and compiled Pallas (`pallas`) row
            kernels of a one-device SweepEngine, checked against the
            scalar reference;
@@ -17,7 +17,7 @@ One chip runs four phases after the device check:
            dataflows, against the jnp reference at every routed
            full-width shape, for decode and prefill row counts.
 
-`--chips 4` runs only the 223-GEMM plan on a 4-device row mesh (both row
+`--chips 4` runs only the 291-GEMM plan on a 4-device row mesh (both row
 kernels, under shard_map) against the one-device engine on the same grid.
 
 Every phase prints one line; the full results go to <out>/chip_smoke.json.

@@ -28,6 +28,10 @@ class MoEConfig:
     # (0 = n_experts: the layer holds them all)
     router_experts: int = 0
     first_expert: int = 0
+    # True: a token's top-k gates are renormalised to sum to 1; False
+    # (DeepSeek-V2's norm_topk_prob): they are the router's softmax
+    # probabilities as they stand
+    norm_topk_prob: bool = True
 
     @property
     def n_routed(self) -> int:
@@ -53,6 +57,51 @@ class SSMConfig:
 
     def n_ssm_heads(self, d_model: int) -> int:
         return self.d_inner(d_model) // self.headdim
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2).  Each token's keys and
+    values come from one latent of `kv_lora_rank` channels; a head's
+    query and key are `qk_nope_head_dim` channels without position and
+    `qk_rope_head_dim` rotated ones, the key's rotated part one vector
+    shared by every head; a head's value is `v_head_dim` channels.  The
+    decode cache holds the latent and the rotated key: `latent_dim`
+    values a token a layer."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of RoPE, under the keys of a published
+    config's `rope_scaling` group: the frequencies below the
+    `beta_fast`..`beta_slow` rotation band are divided by `factor`, a
+    ramp blends them across it, and the attention scale is multiplied by
+    (0.1 mscale_all_dim ln(factor) + 1)^2 (cos and sin are multiplied by
+    the ratio of the mscale and mscale_all_dim terms)."""
+    type: str = "yarn"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def __post_init__(self):
+        if self.type != "yarn":
+            raise NotImplementedError(
+                f"rope_scaling type {self.type!r}: only 'yarn' is built")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,11 +134,16 @@ class ModelConfig:
     # attention pattern
     attn_every: int = 1             # hybrid: attention every N layers
     sliding_window: int = 0         # 0 = full attention; >0 = local window
+    # the first k layers have a dense FFN of d_ff in place of the MoE
+    # and run ahead of the period scan (DeepSeek's first_k_dense_replace)
+    first_k_dense_replace: int = 0
     # sub-configs
     moe: MoEConfig | None = None
     ssm: SSMConfig | None = None
     vision: VisionConfig | None = None
     audio: AudioConfig | None = None
+    mla: MLAConfig | None = None    # latent attention in place of GQA
+    rope_scaling: RopeScaling | None = None
     # numerics
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
@@ -98,7 +152,9 @@ class ModelConfig:
         # sub-configs given as mappings (a JSON file's groups) become
         # their dataclasses
         for name, kind in (("moe", MoEConfig), ("ssm", SSMConfig),
-                           ("vision", VisionConfig), ("audio", AudioConfig)):
+                           ("vision", VisionConfig), ("audio", AudioConfig),
+                           ("mla", MLAConfig),
+                           ("rope_scaling", RopeScaling)):
             value = getattr(self, name)
             if isinstance(value, Mapping):
                 object.__setattr__(self, name, kind(**value))
@@ -127,11 +183,18 @@ class ModelConfig:
             n_attn = 0
         attn = (d * self.n_heads * h + 2 * d * self.n_kv_heads * h
                 + self.n_heads * h * d)
+        if self.mla:
+            m = self.mla
+            attn = (d * self.n_heads * m.qk_head_dim + d * m.latent_dim
+                    + m.kv_lora_rank * self.n_heads
+                    * (m.qk_nope_head_dim + m.v_head_dim)
+                    + self.n_heads * m.v_head_dim * d)
         per_layer += 0  # accumulated per kind below
         total = emb + n_attn * attn
         # FFN / experts
         if self.moe:
-            moe_layers = self.n_layers // self.moe.every_n_layers
+            lead = self.first_k_dense_replace
+            moe_layers = (self.n_layers - lead) // self.moe.every_n_layers
             dense_layers = self.n_layers - moe_layers
             total += moe_layers * (
                 self.moe.n_experts * 3 * d * self.moe.expert_d_ff
@@ -162,7 +225,8 @@ class ModelConfig:
         if not self.moe:
             return self.param_count()
         d = self.d_model
-        moe_layers = self.n_layers // self.moe.every_n_layers
+        moe_layers = ((self.n_layers - self.first_k_dense_replace)
+                      // self.moe.every_n_layers)
         inactive = moe_layers * (self.moe.n_experts - self.moe.top_k) \
             * 3 * d * self.moe.expert_d_ff
         return int(self.param_count() - inactive)
@@ -224,7 +288,8 @@ class RunConfig:
 
 def reduced(cfg: ModelConfig) -> ModelConfig:
     """Tiny same-family config for CPU smoke tests."""
-    n_layers = min(cfg.n_layers, 2)
+    # leading dense layers: one, ahead of two scanned layers
+    n_layers = min(cfg.first_k_dense_replace, 1) + min(cfg.n_layers, 2)
     kw: dict = dict(
         name=cfg.name + "-smoke", d_model=64, n_heads=4,
         n_kv_heads=min(cfg.n_kv_heads, 2) or 2,
@@ -249,4 +314,9 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
                                            cross_attn_every=2)
     if cfg.sliding_window:
         kw["sliding_window"] = 64
+    if cfg.first_k_dense_replace:
+        kw["first_k_dense_replace"] = 1
+    if cfg.mla:
+        kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                              qk_rope_head_dim=8, v_head_dim=16)
     return dataclasses.replace(cfg, **kw)

@@ -1,12 +1,12 @@
-"""The 10 assigned architectures (exact configs from the assignment) plus
+"""The assigned architectures (exact configs from the assignment) plus
 the paper's own evaluation workloads.
 
 Sources in brackets per the assignment table; all configs verbatim.
 """
 from __future__ import annotations
 
-from .base import (AudioConfig, ModelConfig, MoEConfig, SSMConfig,
-                   VisionConfig)
+from .base import (AudioConfig, MLAConfig, ModelConfig, MoEConfig,
+                   RopeScaling, SSMConfig, VisionConfig)
 
 # --- LM-family transformers -------------------------------------------------
 
@@ -91,10 +91,32 @@ GRANITE_4_0_H_SMALL = ModelConfig(
 # (embedding 12, residual 0.22, attention 1/128, logits 1/16) are folded
 # into the weights: granite_4_0_h_small.MULTIPLIERS, model.fold_multipliers
 
+DEEPSEEK_V2_LITE = ModelConfig(
+    name="deepseek-v2-lite", family="moe", n_layers=27, d_model=2048,
+    n_heads=16, n_kv_heads=16, d_ff=10944, vocab=102400,
+    rope_theta=10000.0, rmsnorm_eps=1e-6,
+    first_k_dense_replace=1,    # layer 0: a dense SwiGLU of d_ff
+    mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128),
+    rope_scaling=RopeScaling(type="yarn", factor=40.0,
+                             original_max_position_embeddings=4096,
+                             beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                             mscale_all_dim=0.707),
+    # 64 routed experts, top-6 of a softmax, gates not renormalised; the
+    # 2 shared experts are one SwiGLU of 2 x 1408.  The published model
+    # drops no token: a capacity factor >= 64 / 6 makes an expert's
+    # capacity at least the token count
+    moe=MoEConfig(n_experts=64, top_k=6, n_shared_experts=2,
+                  expert_d_ff=1408, shared_d_ff=2816, every_n_layers=1,
+                  capacity_factor=11.0, norm_topk_prob=False))
+# [hf:deepseek-ai/DeepSeek-V2-Lite] — 15.7B-A2.4B, MLA (no q-LoRA) with
+# YaRN RoPE over 4096 x 40, one leading dense layer, 26 MoE layers
+
 ARCHS: dict[str, ModelConfig] = {c.name: c for c in (
     QWEN2_7B, QWEN1_5_32B, MISTRAL_NEMO_12B, MINITRON_4B, MUSICGEN_LARGE,
     QWEN2_MOE_A2_7B, LLAMA4_SCOUT_17B_A16E, MAMBA2_780M,
-    LLAMA3_2_VISION_90B, JAMBA_1_5_LARGE_398B, GRANITE_4_0_H_SMALL)}
+    LLAMA3_2_VISION_90B, JAMBA_1_5_LARGE_398B, GRANITE_4_0_H_SMALL,
+    DEEPSEEK_V2_LITE)}
 
 
 def get(name: str) -> ModelConfig:

@@ -42,14 +42,41 @@ def gemms_of_model(cfg: ModelConfig, shape: ShapeConfig) -> list[GEMM]:
             out.append(GEMM(int(m), int(n), int(k), label=label,
                             count=int(count)))
 
+    # --- latent attention (MLA): the full-sequence form projects the
+    # latent up to per-head keys and values; decode absorbs W_UK into
+    # the query and W_UV into the output (per head), and every head
+    # attends over the latent row [c | k_pe] ---
+    kv_len = min(s, cfg.sliding_window) if cfg.sliding_window else s
+    if n_attn and cfg.mla:
+        m, nh = cfg.mla, cfg.n_heads
+        r = m.kv_lora_rank
+        add(M, nh * m.qk_head_dim, d, f"{cfg.name} Wq", n_attn * per_seq)
+        add(M, m.latent_dim, d, f"{cfg.name} mla-kv_a", n_attn * per_seq)
+        if decode:
+            add(b, r, m.qk_nope_head_dim, f"{cfg.name} mla-UK",
+                n_attn * nh)
+            add(b, m.v_head_dim, r, f"{cfg.name} mla-UV", n_attn * nh)
+            add(b, kv_len, m.latent_dim, f"{cfg.name} qK^T (decode)",
+                n_attn * nh)
+            add(b, r, kv_len, f"{cfg.name} pV (decode)", n_attn * nh)
+        else:
+            add(s, m.qk_nope_head_dim, r, f"{cfg.name} mla-UK",
+                n_attn * nh * per_seq)
+            add(s, m.v_head_dim, r, f"{cfg.name} mla-UV",
+                n_attn * nh * per_seq)
+            add(s, kv_len, m.qk_head_dim, f"{cfg.name} QK^T",
+                n_attn * nh * per_seq)
+            add(s, m.v_head_dim, kv_len, f"{cfg.name} QK^T.V",
+                n_attn * nh * per_seq)
+        add(M, d, nh * m.v_head_dim, f"{cfg.name} Wo", n_attn * per_seq)
+
     # --- attention projections ---
-    if n_attn:
+    elif n_attn:
         add(M, cfg.n_heads * dh, d, f"{cfg.name} Wq", n_attn * per_seq)
         add(M, cfg.n_kv_heads * dh, d, f"{cfg.name} Wk", n_attn * per_seq)
         add(M, cfg.n_kv_heads * dh, d, f"{cfg.name} Wv", n_attn * per_seq)
         add(M, d, cfg.n_heads * dh, f"{cfg.name} Wo", n_attn * per_seq)
         # score GEMMs (per head); decode: 1 x cache x dh
-        kv_len = min(s, cfg.sliding_window) if cfg.sliding_window else s
         if decode:
             add(b, kv_len, dh, f"{cfg.name} qK^T (decode)",
                 n_attn * cfg.n_heads)
@@ -63,9 +90,10 @@ def gemms_of_model(cfg: ModelConfig, shape: ShapeConfig) -> list[GEMM]:
 
     # --- FFN / experts ---
     if cfg.moe:
-        moe_layers = cfg.n_layers // cfg.moe.every_n_layers
+        lead = cfg.first_k_dense_replace        # leading dense layers
+        moe_layers = (cfg.n_layers - lead) // cfg.moe.every_n_layers
         dense_layers = (cfg.n_layers - moe_layers
-                        if cfg.family == "hybrid" else 0)
+                        if cfg.family == "hybrid" else lead)
         # the router spreads each token's top_k choices over all n_routed
         # experts; this model (or its expert-parallel share) computes the
         # n_experts it holds
@@ -127,7 +155,7 @@ def gemms_of_model(cfg: ModelConfig, shape: ShapeConfig) -> list[GEMM]:
 
 def llm_gemm_set() -> list[GEMM]:
     """The planner's full LLM workload: every registered arch at
-    train_4k and decode_32k (223 GEMMs) — the set the planner benchmark
+    train_4k and decode_32k (291 GEMMs) — the set the planner benchmark
     and the chip smoke plan end to end."""
     from ..configs import ARCHS, SHAPES
     return [g for cfg in ARCHS.values()

@@ -190,19 +190,58 @@ def rmsnorm(params, x, eps: float = 1e-5):
 
 # --- rotary embeddings --------------------------------------------------------
 
-def rope_freqs(d_head: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, d_head, 2, dtype=jnp.float32)
-                            / d_head))
+def rope_freqs(d_head: int, theta: float, scaling=None):
+    """Inverse frequencies of the d_head / 2 rotated pairs; with a YaRN
+    `scaling` (configs.base.RopeScaling) those of the pairs that turn
+    fewer than `beta_slow` times over the original context are divided
+    by `factor`, those that turn more than `beta_fast` times are kept,
+    and a linear ramp over the pair index blends the two between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, d_head, 2, dtype=jnp.float32)
+                             / d_head))
+    if scaling is None:
+        return freqs
+
+    def corr(rotations):      # the pair index that turns `rotations` times
+        return (d_head * math.log(scaling.original_max_position_embeddings
+                                  / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(corr(scaling.beta_fast)), 0)
+    hi = min(math.ceil(corr(scaling.beta_slow)), d_head - 1)
+    ramp = jnp.clip((jnp.arange(d_head // 2, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float = 1e6):
-    """x: (..., seq, heads, d_head); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude term 0.1 mscale ln(factor) + 1 (1 for no
+    extension)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float = 1e6, scaling=None,
+               interleaved: bool = False):
+    """x: (..., seq, heads, d_head); positions: (..., seq).
+
+    Rotates channel i with channel i + d_head / 2 by the pair's angle.
+    `interleaved` (DeepSeek's published order) pairs channels 2i and
+    2i + 1 instead and returns the rotated pairs in halves, first
+    members then second, as its modelling code does.  `scaling`: YaRN
+    (rope_freqs); cos and sin are multiplied by the ratio of its mscale
+    and mscale_all_dim terms."""
     d_head = x.shape[-1]
-    freqs = rope_freqs(d_head, theta)                       # (d_head/2,)
+    freqs = rope_freqs(d_head, theta, scaling)              # (d_head/2,)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (.., s, d/2)
     cos = jnp.cos(ang)[..., :, None, :]                     # (.., s, 1, d/2)
     sin = jnp.sin(ang)[..., :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1)
     return out.astype(x.dtype)

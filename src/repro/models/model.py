@@ -2,17 +2,23 @@
 
 A model is a stack of *periods*: the smallest repeating layer pattern.
 Each period is a list of *slots*, each slot = (mixer, ffn) where
-mixer ∈ {attn, mamba, cross} and ffn ∈ {dense, moe, None}.  Parameters for
-slot s are stacked over periods, so the layer stack lowers to one
+mixer ∈ {attn, mla, mamba, cross} and ffn ∈ {dense, moe, None}.  Parameters
+for slot s are stacked over periods, so the layer stack lowers to one
 lax.scan over periods (small HLO, fast compile, remat-friendly):
 
-  dense / moe / audio : period = [(attn, dense|moe)]
+  dense / moe / audio : period = [(attn, dense|moe)]; latent attention
+                        (cfg.mla) takes the mixer's place: (mla, ...)
   ssm (mamba2)        : period = [(mamba, None)]
   hybrid (jamba)      : period = [(mamba, ffn) x 4, (attn, ffn),
                                   (mamba, ffn) x 3]: attention at index
                         attn_every // 2, ffn_i = moe where
                         i % every_n_layers == every_n_layers - 1
   vlm (llama3.2-v)    : period = [(attn, dense) x 4, (cross, dense)]
+
+Leading dense layers (cfg.first_k_dense_replace, DeepSeek's layer 0) are
+a group of their own ahead of the periods, (mixer, dense) stacked over
+those layers, with their own params ("lead") and cache entries
+(`layer_groups`).
 
 Entry points:
   init(key, cfg)                       -> params
@@ -30,7 +36,9 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, RunConfig
-from .attention import attend, decode_attend
+from .attention import (attend, decode_attend, mla_absorb_query,
+                        mla_expand_output, mla_full, mla_init, mla_qkv,
+                        mla_scale)
 from .layers import (attn_init, attn_out_proj, apply_rope, dtype_of,
                      embed_init, linear, qkv_proj, rmsnorm, rmsnorm_init,
                      swiglu, swiglu_init)
@@ -45,15 +53,20 @@ def _batch_axes(rc: RunConfig):
 
 @dataclasses.dataclass(frozen=True)
 class Slot:
-    mixer: str          # "attn" | "mamba" | "cross"
+    mixer: str          # "attn" | "mla" | "mamba" | "cross"
     ffn: str | None     # "dense" | "moe" | None
 
 
+# mixers that attend over a per-token cache (paged in serving)
+CACHED_ATTENTION = ("attn", "mla")
+
+
 def period_slots(cfg: ModelConfig) -> list[Slot]:
+    attn = "mla" if cfg.mla else "attn"
     if cfg.family in ("dense", "audio"):
-        return [Slot("attn", "dense")]
+        return [Slot(attn, "dense")]
     if cfg.family == "moe":
-        return [Slot("attn", "moe")]
+        return [Slot(attn, "moe")]
     if cfg.family == "ssm":
         return [Slot("mamba", None)]
     if cfg.family == "hybrid":
@@ -74,8 +87,22 @@ def period_slots(cfg: ModelConfig) -> list[Slot]:
 
 def n_periods(cfg: ModelConfig) -> int:
     P = len(period_slots(cfg))
-    assert cfg.n_layers % P == 0, (cfg.n_layers, P)
-    return cfg.n_layers // P
+    n = cfg.n_layers - cfg.first_k_dense_replace
+    assert n % P == 0, (n, P)
+    return n // P
+
+
+def layer_groups(cfg: ModelConfig) -> list[tuple[str, list[Slot], int]]:
+    """The layer stack in order as (params key, slots, layers stacked):
+    the leading dense layers, ("lead", [(mixer, dense)],
+    first_k_dense_replace), where there are any, then the periods,
+    ("slots", period_slots, n_periods).  The cache lists the groups'
+    entries in the same order."""
+    groups = []
+    if cfg.first_k_dense_replace:
+        groups.append(("lead", [Slot(period_slots(cfg)[0].mixer, "dense")],
+                       cfg.first_k_dense_replace))
+    return groups + [("slots", period_slots(cfg), n_periods(cfg))]
 
 
 # --- init --------------------------------------------------------------------
@@ -86,6 +113,8 @@ def _slot_init(key, slot: Slot, cfg: ModelConfig, dtype):
     if slot.mixer in ("attn", "cross"):
         p["attn"] = attn_init(km, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                               cfg.head_dim(), dtype, cfg.qkv_bias)
+    elif slot.mixer == "mla":
+        p["mla"] = mla_init(km, cfg, dtype)
     else:
         p["mamba"] = mamba_init(km, cfg, dtype)
     if slot.ffn is not None:
@@ -99,8 +128,6 @@ def _slot_init(key, slot: Slot, cfg: ModelConfig, dtype):
 
 def init(key, cfg: ModelConfig):
     dtype = dtype_of(cfg.param_dtype)
-    slots = period_slots(cfg)
-    np_ = n_periods(cfg)
     k_emb, k_head, k_layers = jax.random.split(key, 3)
     params: dict[str, Any] = {}
     if cfg.family == "audio":
@@ -118,14 +145,17 @@ def init(key, cfg: ModelConfig):
                 k_head, cfg.vocab, cfg.d_model, dtype).T
     params["final_norm"] = rmsnorm_init(cfg.d_model, dtype)
 
-    # stacked per-slot params over periods
-    slot_keys = jax.random.split(k_layers, len(slots))
-    stacked = []
-    for si, slot in enumerate(slots):
-        pkeys = jax.random.split(slot_keys[si], np_)
-        per = [_slot_init(k, slot, cfg, dtype) for k in pkeys]
-        stacked.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per))
-    params["slots"] = stacked
+    # stacked per-slot params over each group's layers
+    for name, slots, n in layer_groups(cfg):
+        slot_keys = jax.random.split(
+            k_layers if name == "slots" else jax.random.fold_in(key, 1),
+            len(slots))
+        stacked = []
+        for si, slot in enumerate(slots):
+            pkeys = jax.random.split(slot_keys[si], n)
+            per = [_slot_init(k, slot, cfg, dtype) for k in pkeys]
+            stacked.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per))
+        params[name] = stacked
     return params
 
 
@@ -213,6 +243,9 @@ def _apply_mixer_full(slot: Slot, sp, x, cfg: ModelConfig, rc: RunConfig,
     if slot.mixer == "mamba":
         y, (st, cv) = mamba_apply(sp["mamba"], h, cfg, plan=plan)
         return y, ((st, cv) if return_cache else None)
+    if slot.mixer == "mla":
+        y, latent = mla_full(sp["mla"], h, cfg, rc, plan)
+        return y, (latent if return_cache else None)
     nh, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim()
     if slot.mixer == "cross":
         b, l, _ = x.shape
@@ -287,7 +320,6 @@ def forward(params, tokens, cfg: ModelConfig, rc: RunConfig,
     Returns logits (b, l, vocab) (audio: (b, l, nb, vocab)).
     `plan` (KernelPlanTable, jit-static) gates quantized projections —
     prefill and decode share the same per-label verdicts."""
-    slots = period_slots(cfg)
     if cfg.family == "audio":
         x = jnp.sum(jax.vmap(lambda e, t: e[t], in_axes=(0, 2),
                              out_axes=2)(params["embed"], tokens), axis=2)
@@ -302,7 +334,7 @@ def forward(params, tokens, cfg: ModelConfig, rc: RunConfig,
         return jax.lax.with_sharding_constraint(
             t, _P(_batch_axes(rc), "model", None))
 
-    def period_body(carry, period_params):
+    def period_body(slots, carry, period_params):
         x, aux = carry
         x = _sp(x)
         for si, slot in enumerate(slots):
@@ -324,16 +356,17 @@ def forward(params, tokens, cfg: ModelConfig, rc: RunConfig,
             aux = aux + a
         return (x, aux), None
 
-    body = period_body
-    if rc.remat:
-        policy = (jax.checkpoint_policies.dots_saveable
-                  if rc.remat_policy == "dots"
-                  else jax.checkpoint_policies.nothing_saveable)
-        body = jax.checkpoint(period_body, policy=policy)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)),
-                               params["slots"],
-                               unroll=max(1, min(rc.scan_unroll,
-                                                 n_periods(cfg))))
+    carry = (x, jnp.float32(0.0))
+    for name, slots, n in layer_groups(cfg):
+        body = partial(period_body, slots)
+        if rc.remat:
+            policy = (jax.checkpoint_policies.dots_saveable
+                      if rc.remat_policy == "dots"
+                      else jax.checkpoint_policies.nothing_saveable)
+            body = jax.checkpoint(body, policy=policy)
+        carry, _ = jax.lax.scan(body, carry, params[name],
+                                unroll=max(1, min(rc.scan_unroll, n)))
+    x, aux = carry
     x = rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
     return _lm_logits(params, x, cfg, plan), aux
 
@@ -365,16 +398,48 @@ def loss_fn(params, batch, cfg: ModelConfig, rc: RunConfig):
 
 # --- KV / state caches -----------------------------------------------------------
 
+# the TPU's lane width: a cache row of a multiple of it keeps a block
+# pool in its tiled layout (a 576-wide row made XLA relayout the whole
+# pool on every step)
+LANES = 128
+
+
+def latent_width(cfg: ModelConfig) -> int:
+    """MLA's cached row: [c | k_pe] zero-padded to a multiple of LANES
+    (576 -> 640 at DeepSeek-V2's widths)."""
+    return -(-cfg.mla.latent_dim // LANES) * LANES
+
+
+def _latent_entry(cfg: ModelConfig, rc: RunConfig, rows: tuple):
+    """MLA's cache entry: one row of `latent_width` a token in the KV
+    cache dtype, rows = (layers, ...positions)."""
+    if rc.kv_cache_dtype == "int8":
+        raise NotImplementedError(
+            "latent attention (MLA) has no int8 cache: serve its latent "
+            "cache in bfloat16 or float32")
+    return {"latent": jnp.zeros(rows + (latent_width(cfg),),
+                                dtype_of(rc.kv_cache_dtype))}
+
+
+def cache_slots(cfg: ModelConfig):
+    """(slot, layers stacked) of every cache entry, in the cache's
+    order (`layer_groups`)."""
+    return [(slot, n) for _, slots, n in layer_groups(cfg)
+            for slot in slots]
+
+
 def init_cache(cfg: ModelConfig, rc: RunConfig, batch: int, max_len: int,
                n_image_tokens: int = 0):
-    """Cache pytree: one entry per slot, stacked over periods."""
-    np_ = n_periods(cfg)
+    """Cache pytree: one entry per slot, stacked over its group's layers
+    (the periods; the leading dense layers first where there are any)."""
     kv_dtype = dtype_of(rc.kv_cache_dtype) if rc.kv_cache_dtype != "int8" \
         else jnp.int8
     dh, kvh = cfg.head_dim(), cfg.n_kv_heads
     caches = []
-    for slot in period_slots(cfg):
-        if slot.mixer == "attn":
+    for slot, np_ in cache_slots(cfg):
+        if slot.mixer == "mla":
+            caches.append(_latent_entry(cfg, rc, (np_, batch, max_len)))
+        elif slot.mixer == "attn":
             shape = (np_, batch, max_len, kvh, dh)
             caches.append({"k": jnp.zeros(shape, kv_dtype),
                            "v": jnp.zeros(shape, kv_dtype)})
@@ -400,19 +465,21 @@ def init_paged_cache(cfg: ModelConfig, rc: RunConfig, n_slots: int,
     """Block-pool KV cache for slot-scheduled continuous batching.
 
     Attention slots get a shared *pool* of `n_blocks` fixed-size blocks,
-    (periods, n_blocks, block_size, kv_heads, head_dim), instead of one
+    (periods, n_blocks, block_size, kv_heads, head_dim) (MLA: one
+    latent row, (periods, n_blocks, block_size, latent_width)), instead of one
     contiguous (batch, max_len) strip per request: each serving slot owns
     a host-managed list of physical block ids (its block table) and
     ragged request lengths share one jitted decode executable.  Mamba
     state / conv carries and cross-attn image KV stay per-slot (they are
     O(1) in sequence length, nothing to page)."""
-    np_ = n_periods(cfg)
     kv_dtype = dtype_of(rc.kv_cache_dtype) if rc.kv_cache_dtype != "int8" \
         else jnp.int8
     dh, kvh = cfg.head_dim(), cfg.n_kv_heads
     caches = []
-    for slot in period_slots(cfg):
-        if slot.mixer == "attn":
+    for slot, np_ in cache_slots(cfg):
+        if slot.mixer == "mla":
+            caches.append(_latent_entry(cfg, rc, (np_, n_blocks, block_size)))
+        elif slot.mixer == "attn":
             shape = (np_, n_blocks, block_size, kvh, dh)
             caches.append({"k": jnp.zeros(shape, kv_dtype),
                            "v": jnp.zeros(shape, kv_dtype)})
@@ -488,26 +555,23 @@ def _zero_fresh(old, fresh):
         return jnp.where(m, jnp.zeros((), old.dtype), old)
 
 
-def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
-                      rc: RunConfig, active, block_tables):
-    """RoPE (none when rope_theta is 0), this token's KV write into layer `i` of the stacked cache
-    entry and attention over the slot's cache: (o, updated stacked entry).
-    Paged when `block_tables` is given: the row is scattered into the
-    slot's current block and its logical strip is gathered back;
-    otherwise the row goes to `pos` of the contiguous cache.  Both write
-    into the stack in place: no layer is sliced out and written back."""
-    b = q.shape[0]
+def _positions(pos, b: int):
+    """(b, 1) positions of this step's token from ragged (b,) or uniform
+    () `pos`."""
+    return pos[:, None] if jnp.ndim(pos) == 1 else jnp.full((b, 1), pos,
+                                                            jnp.int32)
+
+
+def _write_and_view(cache_s, rows, i, pos, active, block_tables):
+    """Write this token's `rows` ({leaf: (b, 1, ...)}) into layer `i` of
+    the stacked cache entry and read each slot's strip back: (updated
+    stacked entry, {leaf: (b, S, ...)}, (b,) valid lengths).  Paged
+    when `block_tables` is given: the row is scattered into the slot's
+    current block and its logical strip is gathered back; otherwise the
+    row goes to `pos` of the contiguous cache.  Both write into the
+    stack in place: no layer is sliced out and written back."""
+    b = next(iter(rows.values())).shape[0]
     ragged = jnp.ndim(pos) == 1
-    pvec = pos[:, None] if ragged else jnp.full((b, 1), pos, jnp.int32)
-    if cfg.rope_theta:                  # 0: no positional embedding
-        q = apply_rope(q, pvec, cfg.rope_theta)
-        k = apply_rope(k, pvec, cfg.rope_theta)
-    int8_kv = rc.kv_cache_dtype == "int8"
-    if int8_kv:
-        (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)
-        rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-    else:
-        rows = {"k": k, "v": v}
     if block_tables is not None:
         entry = {n: _paged_write(cache_s[n], i, r[:, 0], pos, block_tables,
                                  active) for n, r in rows.items()}
@@ -522,6 +586,26 @@ def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
         view = {n: jax.lax.dynamic_index_in_dim(c, i, keepdims=False)
                 for n, c in entry.items()}
         lens = jnp.full((b,), pos + 1, jnp.int32)
+    return entry, view, lens
+
+
+def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
+                      rc: RunConfig, active, block_tables):
+    """RoPE (none when rope_theta is 0), this token's KV write into layer
+    `i` of the stacked cache entry and attention over the slot's cache
+    (`_write_and_view`): (o, updated stacked entry)."""
+    pvec = _positions(pos, q.shape[0])
+    if cfg.rope_theta:                  # 0: no positional embedding
+        q = apply_rope(q, pvec, cfg.rope_theta)
+        k = apply_rope(k, pvec, cfg.rope_theta)
+    int8_kv = rc.kv_cache_dtype == "int8"
+    if int8_kv:
+        (kq, ks), (vq, vs) = _quantize_kv(k), _quantize_kv(v)
+        rows = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    else:
+        rows = {"k": k, "v": v}
+    entry, view, lens = _write_and_view(cache_s, rows, i, pos, active,
+                                        block_tables)
     if int8_kv:
         kd = _dequantize_kv(view["k"], view["k_scale"], q.dtype)
         vd = _dequantize_kv(view["v"], view["v_scale"], q.dtype)
@@ -529,6 +613,32 @@ def _decode_attention(q, k, v, cache_s, i, pos, cfg: ModelConfig,
         kd, vd = view["k"], view["v"]
     o = decode_attend(q, kd, vd, lens, window=cfg.sliding_window)
     return o, entry
+
+
+def _decode_mla(p, h, cache_s, i, pos, cfg: ModelConfig, active,
+                block_tables, plan=None):
+    """Absorbed latent attention for one token a lane: its row [c | k_pe]
+    (zero-padded to `latent_width`) is written into layer `i` of the
+    stacked latent cache and every query head, [W_UK q_nope | q_pe]
+    (zero-padded alike), attends over the lane's latent strip as one KV
+    head whose values are the strip's first kv_lora_rank channels; W_UV
+    and Wo take the result back to d_model.  Returns (y, updated stacked
+    entry)."""
+    b = h.shape[0]
+    q_nope, q_pe, c, k_pe = mla_qkv(p, h, _positions(pos, b), cfg, plan)
+    q_lat = mla_absorb_query(p, q_nope, plan)
+    pad = latent_width(cfg) - cfg.mla.latent_dim
+    with jax.named_scope("attn_core"):
+        row = jnp.concatenate([c, k_pe, jnp.zeros(c.shape[:-1] + (pad,),
+                                                  c.dtype)], -1)
+        entry, view, lens = _write_and_view(cache_s, {"latent": row}, i,
+                                            pos, active, block_tables)
+        strip = view["latent"][:, :, None]       # (b, S, 1, latent_width)
+        q = jnp.concatenate([q_lat, q_pe, jnp.zeros(
+            q_pe.shape[:-1] + (pad,), q_pe.dtype)], -1)
+        o_lat = decode_attend(q, strip, strip[..., :cfg.mla.kv_lora_rank],
+                              lens, scale=mla_scale(cfg))
+    return attn_out_proj(p, mla_expand_output(p, o_lat, plan), plan), entry
 
 
 # --- decode -----------------------------------------------------------------------
@@ -554,11 +664,10 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
         slot's logical strip, writes scatter one row into its current
         block.  Required whenever `pos` is ragged and the arch has
         attention slots."""
-    slots = period_slots(cfg)
     b = tokens.shape[0]
     ragged = jnp.ndim(pos) == 1
-    if ragged and block_tables is None and any(s.mixer == "attn"
-                                              for s in slots):
+    if ragged and block_tables is None and any(
+            s.mixer in CACHED_ATTENTION for s in period_slots(cfg)):
         raise ValueError(
             "ragged per-slot positions need a paged KV cache: pass "
             "block_tables (see init_paged_cache) for attention archs")
@@ -577,7 +686,7 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
     # writes its own index of it in place.  Donation aliases the
     # program's input and output, not a scan's xs and ys: passed as those,
     # the cache is copied per layer and again whole after the loop.
-    def period_body(carry, scanned):
+    def period_body(slots, carry, scanned):
         x, cache = carry
         period_params, i = scanned
         cache = list(cache)
@@ -608,6 +717,9 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
                                       jnp.full((b,), kx.shape[1], jnp.int32))
                 y = attn_out_proj(sp["attn"], o.reshape(b, 1, nh * dh),
                                   plan, label="xattn-out")
+            elif slot.mixer == "mla":
+                y, cache[si] = _decode_mla(sp["mla"], h, cache_s, i, pos, cfg,
+                                           active, block_tables, plan)
             else:
                 q, k, v = qkv_proj(sp["attn"], h, nh, kvh, dh, plan)
                 with jax.named_scope("attn_core"):
@@ -620,11 +732,22 @@ def decode_step(params, cache, tokens, pos, cfg: ModelConfig,
             x, _ = _apply_ffn(slot, sp, x, cfg, plan)
         return (x, cache), None
 
-    np_ = n_periods(cfg)
+    cache, done = list(cache), []
     with jax.named_scope("layer_scan"):
-        (x, cache), _ = jax.lax.scan(
-            period_body, (x, list(cache)),
-            (params["slots"], jnp.arange(np_, dtype=jnp.int32)),
-            unroll=max(1, min(rc.scan_unroll, np_)))
+        for name, slots, np_ in layer_groups(cfg):
+            carry = (x, cache[len(done):len(done) + len(slots)])
+            if name == "lead":
+                # the leading layers run unrolled: a scan of one layer
+                # copies its carried cache in and out
+                for j in range(np_):
+                    carry, _ = period_body(slots, carry, (jax.tree.map(
+                        lambda a: a[j], params[name]), jnp.int32(j)))
+            else:
+                carry, _ = jax.lax.scan(
+                    partial(period_body, slots), carry,
+                    (params[name], jnp.arange(np_, dtype=jnp.int32)),
+                    unroll=max(1, min(rc.scan_unroll, np_)))
+            x, entries = carry
+            done += entries
     x = rmsnorm(params["final_norm"], x, cfg.rmsnorm_eps)
-    return _lm_logits(params, x, cfg, plan), cache
+    return _lm_logits(params, x, cfg, plan), done

@@ -22,8 +22,9 @@ segment-sum chain is the dominant per-step cost at decode shapes.
 Expert parallelism: the layer holds `n_experts` experts, numbered from
 `first_expert`, of the `n_routed` the router scores.  It routes every
 token over all of them (softmax over the router's logits, top-k,
-renormalised) and adds the gated outputs of those of a token's choices
-that it holds; the shared expert runs in every share.  Summed over the
+renormalised unless `norm_topk_prob` is off) and adds the gated
+outputs of those of a token's choices that it holds; the shared expert
+runs in every share.  Summed over the
 shares, with the shared expert counted once, the parts give the whole
 layer.  Capacity is reckoned from the routed count.  On one chip the
 layer runs alone: there is no exchange.
@@ -84,8 +85,9 @@ def moe_apply(params, x, cfg: ModelConfig, plan=None, *,
         logits = (xt @ params["router"]).astype(jnp.float32)  # (T, R)
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, expert_ids = jax.lax.top_k(probs, m.top_k)  # (T, k)
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
+        if m.norm_topk_prob:
+            gate_vals = gate_vals / jnp.maximum(
+                gate_vals.sum(-1, keepdims=True), 1e-9)
         # each choice's index among the experts held here; the choices
         # held elsewhere add nothing in this share
         local = expert_ids - m.first_expert

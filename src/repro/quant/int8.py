@@ -32,25 +32,32 @@ def dequantize_weight(q, scale, dtype=jnp.float32):
     return q.astype(dtype) * scale.astype(dtype)[..., None, :]
 
 
-def _epilogue_scale(spec: str, scale):
+def _scale_for(spec: str, scale, side: str):
     """Reshape/transpose a per-output-channel `scale` so it broadcasts
-    against the *output* of `einsum(spec, x, q)`.
+    against the *output* (side "out") or the *input* x (side "x") of
+    `einsum(spec, x, q)`.
 
-    The weight operand's second-to-last letter is the contracted input
-    channel (the repo-wide (..., K, N) weight convention); every other
-    weight letter carries a scale axis.  Returns None when a scale axis
-    does not survive into the output (caller falls back to materializing
-    the dequantized weight — no such spec exists in-repo today)."""
+    The weight operand's second-to-last letter is its input channel K
+    (the repo-wide (..., K, N) weight convention); every other weight
+    letter carries a scale axis.  Returns None when a scale axis is not
+    among that side's letters."""
     ins, out = spec.replace(" ", "").split("->")
-    w_spec = ins.split(",")[1]
+    x_spec, w_spec = ins.split(",")
+    target = out if side == "out" else x_spec
     k = w_spec[-2]
     s_letters = [c for c in w_spec if c != k]      # scale axis order
-    if any(c not in out for c in s_letters):
+    if any(c not in target for c in s_letters):
         return None
     s = jnp.transpose(scale, [s_letters.index(c)
-                              for c in out if c in s_letters])
+                              for c in target if c in s_letters])
     dims = iter(s.shape)
-    return s.reshape([next(dims) if c in s_letters else 1 for c in out])
+    return s.reshape([next(dims) if c in s_letters else 1 for c in target])
+
+
+def _epilogue_scale(spec: str, scale):
+    """`scale` shaped for the output of `einsum(spec, x, q)`, or None
+    when a scale axis does not survive into it."""
+    return _scale_for(spec, scale, "out")
 
 
 def dequant_contract(x, q, scale, spec: str | None = None, *,
@@ -82,8 +89,11 @@ def scaled_contract(x, q, scale, spec: str | None = None):
     and scales in f32 and rounds to x.dtype once, like the Pallas route
     it is compared with; a batched `spec` contraction (no Pallas
     counterpart, and XLA:CPU has no bf16 x bf16 -> f32 batched dot)
-    scales its x.dtype product.  A spec whose scale axis is summed out
-    of the output materializes q · scale instead."""
+    scales its x.dtype product.  A spec that contracts the scaled
+    channels themselves (a weight applied transposed, as MLA's absorbed
+    W_UK is) scales x's channels in float32 before the contraction; any
+    other spec whose scale axis is summed out of the output materializes
+    q · scale instead."""
     if spec is None:
         f32 = jnp.float32
         y = jnp.matmul(x, q, preferred_element_type=f32)
@@ -93,6 +103,10 @@ def scaled_contract(x, q, scale, spec: str | None = None):
     se = _epilogue_scale(spec, scale)
     if se is not None:
         return jnp.einsum(spec, x, q) * se.astype(x.dtype)
+    sx = _scale_for(spec, scale, "x")
+    if sx is not None:
+        xs = (x.astype(jnp.float32) * sx.astype(jnp.float32)).astype(x.dtype)
+        return jnp.einsum(spec, xs, q)
     return jnp.einsum(spec, x, q * scale.astype(x.dtype)[..., None, :])
 
 
@@ -138,6 +152,7 @@ def planned_linear(x, w_q, w_scale, use_cim_path: bool,
 # gather stay in float.
 PROJECTION_WEIGHT_NAMES = frozenset({
     "wq", "wk", "wv", "wo",                      # attention projections
+    "wkv_a", "w_uk", "w_uv",                     # latent attention (MLA)
     "w_gate", "w_up", "w_down",                  # dense MLP / MoE experts
     "w_z", "w_x", "w_B", "w_C", "w_dt",          # mamba in-projections
     "out_proj",                                  # mamba out-projection

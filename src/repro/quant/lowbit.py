@@ -91,8 +91,19 @@ def dequant_contract_int4(x, packed, scale, spec: str | None = None):
 
     Unpacks the nibbles (O(K·N) int8, transient) and contracts in x.dtype
     — exact for int4 magnitudes in every float dtype in use."""
-    q = unpack_int4(packed, x.shape[-1]).astype(x.dtype)
+    q = unpack_int4(packed, _k_rows(x, packed, spec)).astype(x.dtype)
     return scaled_contract(x, q, scale, spec)
+
+
+def _k_rows(x, packed, spec: str | None) -> int:
+    """K, the packed weight's input-channel rows: x's contracted size,
+    or twice the packed rows where x does not hold the weight's K (a
+    weight applied transposed)."""
+    if spec is None:
+        return x.shape[-1]
+    x_spec, w_spec = spec.replace(" ", "").split("->")[0].split(",")
+    k = w_spec[-2]
+    return x.shape[x_spec.index(k)] if k in x_spec else 2 * packed.shape[-2]
 
 
 def dequant_contract_fp8(x, qf, scale, spec: str | None = None):

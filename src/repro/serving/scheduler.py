@@ -67,7 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import period_slots
-from ..models.model import init_paged_cache
+from ..models.model import CACHED_ATTENTION, cache_slots, init_paged_cache
 from .core import DecodeCore, sample_token
 
 
@@ -216,7 +216,7 @@ class ContinuousBatchingEngine:
         self.block_size = block_size
         self.record_logits = record_logits
         self.clock = clock
-        self.needs_kv = any(s.mixer == "attn"
+        self.needs_kv = any(s.mixer in CACHED_ATTENTION
                             for s in period_slots(core.cfg))
         self.max_blocks = max(1, math.ceil(max_len / block_size))
         if n_kv_blocks is None:
@@ -225,6 +225,13 @@ class ContinuousBatchingEngine:
                                         else 0)
         self.cache = init_paged_cache(core.cfg, core.rc, n_slots,
                                       max(1, n_kv_blocks), block_size)
+        # bytes a token takes in one layer's paged cache, by mixer
+        # ("attn": its K and V rows, "mla": its latent row)
+        self.kv_bytes_per_token = {
+            slot.mixer: sum(leaf.dtype.itemsize * math.prod(leaf.shape[3:])
+                            for leaf in entry.values())
+            for (slot, _), entry in zip(cache_slots(core.cfg), self.cache)
+            if slot.mixer in CACHED_ATTENTION}
         self.block_tables = np.zeros((n_slots, self.max_blocks), np.int32)
         self.queue: collections.deque[Request] = collections.deque()
         self.slots: list[_Slot | None] = [None] * n_slots
@@ -658,7 +665,9 @@ class ContinuousBatchingEngine:
             "n_slots": self.n_slots,
             "kv_blocks": {"total": self.allocator.n_blocks,
                           "block_size": self.block_size,
-                          "peak_in_use": self.allocator.peak_in_use},
+                          "peak_in_use": self.allocator.peak_in_use,
+                          "bytes_per_token_per_layer":
+                              dict(self.kv_bytes_per_token)},
             "decode_executables": self.decode_executables,
             "kv_donation_ok": self.donation_ok,
             "phase_gating": {

@@ -94,10 +94,17 @@ def test_dequant_contract_matches_reference(dtype):
     ("etf,efd->etd", (3, 4, 16), (3,)),
     # multi-head readout (models/layers.py audio head)
     ("bld,ndv->blnv", (2, 5, 16), (4,)),
+    # latent attention's per-head W_UK / W_UV (models/attention.py):
+    # up from the latent, and the absorbed decode form, whose W_UK
+    # contraction sums over the scaled channels themselves
+    ("blc,hcn->blhn", (2, 5, 16), (4,)),
+    ("blhc,hcn->blhn", (2, 1, 4, 16), (4,)),
+    ("blhn,hcn->blhc", (2, 1, 4, 8), (4,)),
 ])
 def test_dequant_contract_stacked_specs(spec, x_shape, stacked):
     """Every in-repo einsum spec: the per-(expert, channel) scale applied
-    as an output epilogue equals materializing each expert's weight."""
+    as an output epilogue (on the input where the spec contracts the
+    scaled channels) equals materializing each expert's weight."""
     q, s = _quantized(jax.random.PRNGKey(2), 16, 8, stacked)
     x = jax.random.normal(jax.random.PRNGKey(3), x_shape, jnp.float32)
     got = dequant_contract(x, q, s, spec)

@@ -28,7 +28,7 @@ RC = RunConfig(remat=False, attn_impl="naive")
 # one arch per family: the coverage sweep must see every projection kind
 COVERAGE_ARCHS = ("mistral-nemo-12b", "qwen2-moe-a2.7b", "mamba2-780m",
                   "jamba-1.5-large-398b", "llama-3.2-vision-90b",
-                  "musicgen-large")
+                  "musicgen-large", "deepseek-v2-lite")
 
 
 def _plan_table(cfg, batch, max_len=32):

@@ -85,7 +85,7 @@ def test_arch_smoke_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", [
-    "qwen2-7b", "mamba2-780m",
+    "qwen2-7b", "mamba2-780m", "deepseek-v2-lite",
     pytest.param("jamba-1.5-large-398b", marks=pytest.mark.slow),
     pytest.param("qwen2-moe-a2.7b", marks=pytest.mark.slow)])
 def test_decode_matches_forward(arch):
@@ -151,7 +151,7 @@ def test_param_counts_roughly_match_names():
     approx = {
         "qwen2-7b": 7.6e9, "qwen1.5-32b": 32e9, "mistral-nemo-12b": 12e9,
         "minitron-4b": 4.2e9, "mamba2-780m": 0.78e9,
-        "jamba-1.5-large-398b": 398e9,
+        "jamba-1.5-large-398b": 398e9, "deepseek-v2-lite": 15.7e9,
     }
     for name, want in approx.items():
         got = ARCHS[name].param_count()

@@ -173,6 +173,9 @@ _COPY = re.compile(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(")
     # temp (the gathered strip repeated to 32 heads in f32), more than a
     # 2-layer cache holds
     ("mistral-nemo-12b", 10, 32, 2048),
+    # the leading dense layer and 2 scanned layers: the latent cache of
+    # both layer groups is written in place
+    ("deepseek-v2-lite", 3, 128, 1536),
 ])
 def test_batch_step_updates_stacked_cache_in_place(one_chip, arch, n_layers,
                                                    slots, max_len):
